@@ -26,7 +26,7 @@ from distkeras_tpu import utils
 # platform where a different size wins shows up as a recorded number —
 # re-promote this constant when the sweep moves.  25 MB balances transfer
 # granularity (enough batches per chunk to amortize the per-transfer
-# relay latency) against double-buffer residency (2 chunks in flight).
+# host cost) against double-buffer residency (2 chunks in flight).
 DEFAULT_CHUNK_BUDGET_BYTES = 25 * 2**20
 
 

@@ -64,10 +64,9 @@ def main(argv=None) -> None:
                         help="mesh replicas for the distributed trainers "
                              "(default: all visible devices)")
     args = parser.parse_args(argv)
-    if args.cpu:
-        from distkeras_tpu.platform import pin_cpu_devices
+    from distkeras_tpu.platform import select_platform
 
-        pin_cpu_devices(args.cpu)
+    select_platform(args.cpu)
 
     import numpy as np
 

@@ -12,7 +12,7 @@ falling + non-degenerate samples = the observable success criterion).
 
 Usage:
     python -m distkeras_tpu.examples.lm_workflow --cpu 8     # 8-dev CPU mesh
-    python -m distkeras_tpu.examples.lm_workflow             # real chip
+    python -m distkeras_tpu.examples.lm_workflow             # the chip (refuses cpu)
     distkeras-lm                                             # console script
 """
 
@@ -52,10 +52,9 @@ def main() -> None:
     if args.steps < 1:
         parser.error("--steps must be >= 1")
 
-    if args.cpu:
-        from distkeras_tpu.platform import pin_cpu_devices
+    from distkeras_tpu.platform import select_platform
 
-        pin_cpu_devices(args.cpu)
+    select_platform(args.cpu)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -76,7 +75,7 @@ def main() -> None:
     # prompt half + sample_len, and the speculative demo additionally
     # writes k + 1 lookahead rows past the end (k = 4 below)
     # head_dim as close to the v5e-recommended 128 as divisibility allows
-    # (BASELINE.md head-dim study): smallest head count that divides
+    # (see TransformerLM.num_heads): smallest head count that divides
     # model_dim with head_dim <= 128 — at the default 128-dim demo model
     # that is a single head
     num_heads = next(h for h in range(max(1, -(-args.model_dim // 128)),

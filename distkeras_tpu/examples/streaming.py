@@ -25,10 +25,9 @@ def main(argv=None) -> None:
                         help="simulate this many CPU devices instead of real chips")
     args = parser.parse_args(argv)
 
-    if args.cpu:
-        from distkeras_tpu.platform import pin_cpu_devices
+    from distkeras_tpu.platform import select_platform
 
-        pin_cpu_devices(args.cpu)
+    select_platform(args.cpu)
     import numpy as np
 
     from distkeras_tpu import Dataset, ModelSpec, SingleTrainer

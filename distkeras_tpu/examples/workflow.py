@@ -2,8 +2,9 @@
 
 Mirrors the reference's canonical pipeline: load a classification dataset
 -> feature prep with transformers -> train with one of the trainer family
--> predict -> evaluate.  Runs on whatever devices are visible; pass
-``--cpu N`` to simulate an N-chip slice on CPU.
+-> predict -> evaluate.  Without ``--cpu`` it runs on the accelerator and
+refuses to continue if JAX came up on the CPU; pass ``--cpu N`` to
+simulate an N-chip slice on CPU.
 
 Usage:
     python examples/workflow.py --trainer adag --cpu 8
@@ -32,10 +33,9 @@ def main() -> None:
     parser.add_argument("--workers", type=int, default=None)
     args = parser.parse_args()
 
-    if args.cpu:
-        from distkeras_tpu.platform import pin_cpu_devices
+    from distkeras_tpu.platform import select_platform
 
-        pin_cpu_devices(args.cpu)
+    select_platform(args.cpu)
     import jax
     import numpy as np
 

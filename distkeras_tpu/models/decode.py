@@ -302,13 +302,14 @@ class FusedStepState(NamedTuple):
 
 def make_fused_state(params: Any, config: dict) -> FusedStepState:
     from distkeras_tpu.ops.decode_step import stack_decode_weights
+    from distkeras_tpu.platform import on_tpu
 
     dtype = _cfg_dtype(config)
     return FusedStepState(
         weights=stack_decode_weights(params, config["num_layers"], dtype),
         embedding=params["embed"]["embedding"].astype(dtype),
         params=params, config=config,
-        interpret=jax.default_backend() != "tpu")
+        interpret=not on_tpu())
 
 
 def fused_token_forward(state: FusedStepState, tok: jnp.ndarray, pos,
@@ -371,8 +372,8 @@ def warn_quantized_cache_gqa(config: dict, context: str) -> None:
     traffic by the head ratio, so there is little bandwidth left to win
     and the write cost dominates: v5e b64 batched decode measured
     **94.9k -> 82.4k tok/s (-13%)** when int8 was stacked on a 4x-GQA
-    cache (BENCH_r05 gqa_b64; BASELINE.md round 5 "int8 atop GQA is a
-    measured net loss").  The combination composes silently in config, so
+    cache (BENCH_r05 gqa_b64, 2026-07-31, not re-measured).  The
+    combination composes silently in config, so
     every decode builder routes through this guard; it stays a WARNING
     (not a refusal) because the crossover may return at much longer
     cache_len — re-measure at your shape before suppressing it."""

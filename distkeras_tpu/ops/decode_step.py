@@ -64,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distkeras_tpu.ops.quantize import QTensor
+from distkeras_tpu.platform import on_tpu
 
 _NEG_INF = float("-inf")
 
@@ -96,8 +97,8 @@ def stack_decode_weights(params: Any, num_layers: int,
     scan, so XLA materializes the slabs once per call, not per token.
     int8 ``QTensor`` leaves are dequantized here (the fused kernel
     streams weights in the compute dtype; weight-only int8 decode showed
-    <3% at batch 1 — see BASELINE.md — so the fused path optimizes the
-    dominant costs instead).
+    <3% at batch 1 (v5e, 2026-07-31, not re-measured), so the fused path
+    optimizes the dominant costs instead).
     """
     def deq(w):
         return w.dequantize(dtype) if isinstance(w, QTensor) else w.astype(dtype)
@@ -211,11 +212,9 @@ def resolve_step_impl(config: dict, batch: int, cache_len: int,
     labelling: ``None`` -> fused iff on TPU and ``fused_step_auto``;
     explicit ``"fused"`` -> hard-validated against
     ``fused_step_supported``; anything else must be ``"xla"``."""
-    import jax
-
     cache_len = round_cache_len(cache_len)
     if requested is None:
-        return ("fused" if (jax.default_backend() == "tpu"
+        return ("fused" if (on_tpu()
                             and fused_step_auto(config, batch, cache_len))
                 else "xla")
     if requested == "fused":

@@ -24,8 +24,9 @@ in the forward; recompute block probabilities in the backward and
 accumulate dQ (grid streams kv blocks) and dK/dV (grid streams q blocks)
 in float32 scratch.
 
-Interpret mode (``interpret=True``, auto-enabled off-TPU) runs the same
-kernels through the Pallas interpreter so CPU tests exercise identical code.
+Interpret mode (``interpret=True``, auto-selected on the CPU backend) runs
+the same kernels through the Pallas interpreter so CPU tests exercise
+identical code.
 
 Layout note: kernels grid over (batch, head, outer block, inner block) on a
 [B, H, L, D] layout — Mosaic requires the last two block dims to be
@@ -48,6 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distkeras_tpu.platform import on_tpu
 
 _NEG_INF = float("-inf")
 _STAT_LANES = 8  # trailing lanes for per-row stats (min f32 tile lane count
@@ -616,8 +619,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``_pick_block`` shrinks every block to fit short sequences
     automatically.
 
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
-    identical kernel code runs (slowly) in CPU tests.
+    ``interpret=None`` selects Mosaic on the TPU backend and the Pallas
+    interpreter on the CPU backend, so the identical kernel code runs
+    (slowly) in CPU tests; any other backend name is an error
+    (``platform.on_tpu``).
     """
     cfg = _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
                        block_q_bwd, block_k_bwd, interpret)
@@ -657,7 +662,7 @@ def flash_attention_with_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
                  block_q_bwd, block_k_bwd, interpret) -> _Config:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     lq, lk = q.shape[1], k.shape[1]
     d = q.shape[-1]
     # forward defaults (v5e device-time sweep, 2026-07-30, fwd+bwd with all

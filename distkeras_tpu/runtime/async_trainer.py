@@ -1050,8 +1050,8 @@ class AsyncDistributedTrainer(Trainer):
                             pulled_host = client.wait_weights()
                             pull_pending = False
                             # ONE batched H2D per window (center + feed
-                            # slices) — on a relayed device every transfer
-                            # call is a host round trip, so they are fused
+                            # slices): every transfer call costs a host
+                            # dispatch, so they are fused
                             if cache_on:
                                 # sparse slots of pulled_host are [k, dim]
                                 # row blocks aligned with rows_w; pad each

@@ -401,6 +401,13 @@ def main(argv: Optional[List[str]] = None) -> None:
                              f"separated index list, got "
                              f"{args.sparse_tables!r}")
 
+    # a hub never needs an accelerator, and one process owns the chip:
+    # Model.deserialize runs a Flax init on the default backend, which on a
+    # TPU host would take the chip from the trainer started next to this
+    # daemon — pin the CPU before any JAX backend comes up
+    from distkeras_tpu.platform import pin_cpu_devices
+
+    pin_cpu_devices(1)
     from distkeras_tpu.models.base import Model
 
     with open(args.model, "rb") as f:

@@ -2,7 +2,8 @@
 
 The shared library is built on demand with ``g++`` (no pybind11 in this
 environment — plain ``extern "C"`` + ctypes) and cached next to this file;
-rebuilds happen only when the source is newer than the binary.  If no
+it is rebuilt whenever the source's content or the build flags differ from
+what the cached binary was built from (``build_shared``).  If no
 toolchain is available, callers fall back to the pure-Python hub — the two
 implementations speak the same wire protocol, so
 :class:`distkeras_tpu.runtime.parameter_server.PSClient` works against
@@ -26,6 +27,7 @@ The ONE remaining Python-hub-only surface is the row-sparse INPROC pair
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import subprocess
@@ -57,10 +59,26 @@ BUILD_FLAGS = ["-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
 def build_shared(src: str, lib: str) -> Optional[str]:
     """Compile ``src`` to the shared library ``lib`` if missing/stale.
     Returns an error string on failure, None on success.  Shared by every
-    native component (PS hub, data loader)."""
-    if not os.path.exists(src):
+    native component (PS hub, data loader).
+
+    Stale means the sha256 of the source's CONTENT plus ``BUILD_FLAGS``
+    differs from the one recorded in the ``<lib>.src-sha256`` sidecar at
+    build time — not mtime: the libraries are git-ignored, so a copied or
+    checked-out tree can carry an old binary whose mtime is newer than the
+    source it no longer matches."""
+    try:
+        with open(src, "rb") as f:
+            want = hashlib.sha256(
+                f.read() + "\0".join(BUILD_FLAGS).encode()).hexdigest()
+    except OSError:
         return f"native source not found: {src}"
-    if os.path.exists(lib) and os.path.getmtime(lib) >= os.path.getmtime(src):
+    stamp = lib + ".src-sha256"
+    try:
+        with open(stamp) as f:
+            fresh = os.path.exists(lib) and f.read().strip() == want
+    except OSError:
+        fresh = False
+    if fresh:
         return None
     # compile to a private temp path, then atomically rename into place:
     # a concurrent process either dlopens the complete old .so or the
@@ -73,7 +91,12 @@ def build_shared(src: str, lib: str) -> Optional[str]:
         return f"g++ invocation failed: {e}"
     if proc.returncode != 0:
         return f"g++ failed:\n{proc.stderr}"
+    # library first, stamp second: a crash between the two (or a torn
+    # stamp) leaves a stamp that does not match and the next load
+    # rebuilds; the other order could bless an old binary
     os.replace(tmp, lib)
+    with open(stamp, "w") as f:
+        f.write(want)
     return None
 
 

@@ -36,6 +36,12 @@ commit clock per shard (:class:`ShardedParameterServer` owns the set) —
 and :class:`ShardedPSClient` stripes every pull/commit across per-shard
 connections reusing the same pipelined/zero-copy machinery per
 connection.  ``num_shards=1`` is byte-identical to the single-hub wire.
+
+This module and :mod:`distkeras_tpu.runtime.networking` import NO JAX, and
+must stay that way: one process owns the chip, and the bench and the tests
+start hubs and wire-only workers as children of a parent that already
+holds it.  A child that imported a JAX backend here would fail or hang on
+the chip its parent has.
 """
 
 from __future__ import annotations
@@ -3468,12 +3474,18 @@ class PSClient(_HotTierCacheSurface):
         # for sparse leaves (that storage is the memory the LRU bounds);
         # the rare full pull (initial seed, explicit re-sync) lands those
         # slots in transient arrays allocated per call
+        # np.empty(shape), never empty_like: a template only carries shape
+        # and dtype, and empty_like would copy its STRIDES too — on the TPU
+        # np.asarray of a device array can come back in a non-C layout
+        # (seen on v5e for a [256, 10] leaf), and a landing buffer must be
+        # C-contiguous to take the wire's row-major bytes
         if self._cache_rows is None:
-            self._pull_bufs = ([np.empty_like(t) for t in self.templates],
-                               [np.empty_like(t) for t in self.templates])
+            self._pull_bufs = tuple(
+                [np.empty(t.shape, t.dtype) for t in self.templates]
+                for _ in range(2))
         else:
             self._pull_bufs = tuple(
-                [None if i in self._sparse_set else np.empty_like(t)
+                [None if i in self._sparse_set else np.empty(t.shape, t.dtype)
                  for i, t in enumerate(self.templates)]
                 for _ in range(2))
         self._flip = 0
@@ -4340,7 +4352,7 @@ class PSClient(_HotTierCacheSurface):
                 # sparse leaves — the rare full pull (initial seed,
                 # explicit re-sync) lands them in transient arrays that
                 # die with the caller's reference
-                out = [np.empty_like(t) if b is None else b
+                out = [np.empty(t.shape, t.dtype) if b is None else b
                        for b, t in zip(bufs, self.templates)]
             try:
                 reply = self._codec.recv_into(self.sock, out)

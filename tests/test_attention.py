@@ -4,6 +4,7 @@ attention on the full sequence (8-way sequence sharding on the CPU mesh)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from distkeras_tpu.ops.attention import dense_attention, ring_attention
@@ -125,5 +126,15 @@ def test_ring_block_impl_area_rule(monkeypatch):
     assert att.ring_block_impl(1024, 128) == "flash"  # 1.05x measured
     assert att.ring_block_impl(512, 128) == "dense"   # 0.72x measured
     assert att.ring_block_impl(2050, 64) == "dense"   # not 128-divisible
+    # the plain (non-ring) selector: length-only, 128-divisible, TPU-only
+    assert att.attention_impl(2048, 2048) == "flash"
+    assert att.attention_impl(1920, 1920) == "dense"
+    assert att.attention_impl(2048, 2000) == "dense"  # keys not 128-divisible
     monkeypatch.setattr(att.jax, "default_backend", lambda: "cpu")
     assert att.ring_block_impl(4096, 128) == "dense"  # interpret mode is slow
+    assert att.attention_impl(4096, 4096) == "dense"
+    # a backend that is neither tpu nor cpu must not quietly fall through to
+    # the interpreter / the XLA reference
+    monkeypatch.setattr(att.jax, "default_backend", lambda: "mystery")
+    with pytest.raises(RuntimeError, match="unexpected JAX backend 'mystery'"):
+        att.attention_impl(4096, 4096)

@@ -1,10 +1,11 @@
-"""bench.py contract: exactly one parseable JSON line on stdout, always.
+"""bench.py contract: exactly one parseable JSON line on stdout, always —
+and a NON-ZERO exit unless every leg ran on the TPU without an error.
 
-Round-1 failure mode (VERDICT weak #2): a transient TPU-init error aborted
-the bench with rc=1 and zero output, leaving the round with no perf
-evidence.  The contract now is: main() never raises, and always prints one
-JSON object with the headline metric keys — populated on success, zeroed
-with an ``error`` note on failure.
+The line keeps the headline metric keys on failure (zeroed, with an
+``error`` note) so a failed run is still a record; what changed with the
+chip bring-up is that a failure can no longer end in exit 0: no CPU
+fallback in ``_init_backend``, no ``None`` peak for an unknown
+``device_kind``, and a leg's ``{"error": ...}`` fails the run.
 """
 
 import json
@@ -13,6 +14,13 @@ import pytest
 
 import bench
 
+# every secondary leg main() runs after the headline; stubbed so main()'s
+# own contract can be driven on the CPU
+_LEGS = ("_bench_lm", "_bench_attn", "_bench_ring", "_bench_decode",
+         "_bench_feed", "_bench_moe", "_bench_pipeline", "_bench_async",
+         "_bench_async_recovery", "_bench_observability", "_bench_health",
+         "_bench_embedding")
+
 
 def _parse_single_json_line(capsys):
     out = capsys.readouterr().out.strip().splitlines()
@@ -20,28 +28,75 @@ def _parse_single_json_line(capsys):
     return json.loads(out[0])
 
 
-def test_main_emits_metric_line(capsys, monkeypatch):
+def _stub_chip(monkeypatch, tmp_path):
+    """Make main() believe it is on the chip with instant legs."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(bench, "_init_backend", lambda: "tpu")
     monkeypatch.setattr(bench, "_bench_mnist_cnn",
                         lambda **kw: (123.4, bench._METHODOLOGY))
-    bench.main()
+    for name in _LEGS:
+        monkeypatch.setattr(bench, name, lambda *a, **kw: {})
+
+
+def test_main_emits_metric_line_and_exits_zero(capsys, monkeypatch, tmp_path):
+    _stub_chip(monkeypatch, tmp_path)
+    bench.main()  # no SystemExit: exit code 0
     rec = _parse_single_json_line(capsys)
     assert rec["metric"] == "mnist_cnn_train_samples_per_sec_per_chip"
     assert rec["value"] == 123.4
     assert rec["unit"] == "samples/sec/chip"
     assert isinstance(rec["vs_baseline"], float)
-    assert rec["platform"] == "cpu"  # conftest pins the CPU platform
+    assert rec["platform"] == "tpu"
+    assert rec["compile_cache"] == str(tmp_path)  # the env's, untouched
+    assert "error" not in rec
 
 
-def test_main_emits_diagnostic_line_on_failure(capsys, monkeypatch):
+def test_main_refuses_the_cpu_platform(capsys, monkeypatch, tmp_path):
+    """No accelerator -> the line names the platform found and the exit
+    code is non-zero; nothing is measured on the CPU (conftest pins it)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(bench, "_bench_mnist_cnn",
+                        lambda **kw: pytest.fail("measured on the CPU"))
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code != 0
+    rec = _parse_single_json_line(capsys)
+    assert rec["value"] == 0.0 and "platform" not in rec
+    assert "no TPU" in rec["error"] and "'cpu'" in rec["error"]
+
+
+def test_main_fatal_failure_is_nonzero(capsys, monkeypatch, tmp_path):
+    _stub_chip(monkeypatch, tmp_path)
+
     def boom(**kw):
         raise RuntimeError("synthetic backend meltdown")
 
     monkeypatch.setattr(bench, "_bench_mnist_cnn", boom)
-    bench.main()
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code != 0
     rec = _parse_single_json_line(capsys)
     assert rec["value"] == 0.0 and rec["vs_baseline"] == 0.0
     assert "synthetic backend meltdown" in rec["error"]
     assert rec["metric"] == "mnist_cnn_train_samples_per_sec_per_chip"
+
+
+def test_main_leg_failure_is_recorded_and_nonzero(capsys, monkeypatch, tmp_path):
+    """One leg's exception stays that leg's {"error": ...} (the other legs
+    still run) but the run can no longer exit 0."""
+    _stub_chip(monkeypatch, tmp_path)
+
+    def boom(*a, **kw):
+        raise ValueError("leg fell over")
+
+    monkeypatch.setattr(bench, "_bench_moe", boom)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code != 0
+    rec = _parse_single_json_line(capsys)
+    assert rec["value"] == 123.4 and "error" not in rec
+    assert "leg fell over" in rec["moe"]["error"]
+    assert rec["pipeline"] == {}  # later legs still ran
 
 
 @pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
@@ -55,8 +110,8 @@ def test_mnist_bench_runs_on_cpu():
 
 def test_peak_flops_lookup():
     assert bench._peak_flops("TPU v5 lite") == 197e12
-    assert bench._peak_flops("TPU v5p chip") == 459e12
-    assert bench._peak_flops("Quantum Abacus 9000") is None
+    with pytest.raises(ValueError, match="Quantum Abacus 9000"):
+        bench._peak_flops("Quantum Abacus 9000")
 
 
 @pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
@@ -80,8 +135,6 @@ def test_decode_bench_runs_tiny_on_cpu():
 
 
 def test_ring_bench_runs_tiny_on_cpu():
-    if not hasattr(__import__("jax"), "shard_map"):
-        pytest.skip("jax.shard_map unavailable (ring attention needs it)")
     leg = bench._bench_ring(256, batch=1, heads=2, head_dim=64, steps=1)
     assert leg["l_local"] == 256
     assert leg["flash_ms"] > 0 and leg["dense_ms"] > 0
@@ -794,13 +847,13 @@ def test_health_bench_runs_tiny():
 
 
 @pytest.mark.slow  # ~10-70s of bench machinery; the full suite runs it
-def test_moe_acceptance_block_shape():
-    """The issue-2 tripwire block: booleans (or None off-TPU) with the
-    targets recorded next to them, derived from top1 + the sweep."""
+def test_moe_acceptance_block_shape(monkeypatch):
+    """The issue-2 tripwire block: booleans with the targets recorded next
+    to them, derived from top1 + the sweep.  The leg computes an MFU, and
+    the CPU has no row in the peaks table — stub the lookup the way the
+    chip would answer it (the shape of the block is the subject here)."""
     import numpy as _np
-    if not hasattr(__import__("jax"), "shard_map"):
-        import pytest
-        pytest.skip("jax.shard_map unavailable (moe perf legs need it)")
+    monkeypatch.setattr(bench, "_peak_flops", lambda kind: 197e12)
     out = bench._bench_moe(batch=1, seq_len=16, model_dim=16, num_heads=2,
                            num_layers=1, vocab=64, experts=4, reps=1,
                            sweep_layers=1, sweep_steps=8,

@@ -680,7 +680,7 @@ def test_hub_sigkill_subprocess_soak(toy_dataset, tmp_path):
         proc = subprocess.Popen(
             args, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
             cwd=repo_root,
-            env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo_root))
+            env=dict(os.environ, PYTHONPATH=repo_root))
         for _ in range(200):  # warnings may precede the banner
             line = proc.stdout.readline()
             if not line or "listening" in line:

@@ -697,7 +697,7 @@ def test_launcher_sigterm_drains_daemon_cleanly(tmp_path):
          "--save-final", final_path],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         cwd=_REPO_ROOT,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO_ROOT))
+        env=dict(os.environ, PYTHONPATH=_REPO_ROOT))
     try:
         line = ""
         for _ in range(200):

@@ -1149,7 +1149,7 @@ def test_kill_primary_sigkill_subprocess(tmp_path):
          "--port", str(port), "--idle-timeout", "0"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         cwd=repo_root,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo_root))
+        env=dict(os.environ, PYTHONPATH=repo_root))
     line = ""
     for _ in range(200):
         line = proc.stdout.readline()

@@ -350,13 +350,16 @@ def test_inline_column_named_file_survives_spool(tmp_path):
         pc.stop()
 
 
-def test_higgs_workflow_example_runs_end_to_end():
+def test_higgs_workflow_example_runs_end_to_end(monkeypatch, tmp_path):
     """The ATLAS-Higgs-analogue walkthrough (SURVEY §2.21): transformers ->
     3 trainers -> predictor -> all 4 evaluators -> checkpoint-resume ->
-    Punchcard deploy, top to bottom on the CPU mesh."""
+    Punchcard deploy, top to bottom on the CPU mesh (``--cpu``: without it
+    the example refuses the cpu platform)."""
     from distkeras_tpu.examples.higgs_workflow import main
 
-    main(["--rows", "1536", "--epochs", "4", "--workers", "4"])
+    # keep the example's compile-cache placement out of this session
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    main(["--cpu", "8", "--rows", "1536", "--epochs", "4", "--workers", "4"])
 
 
 def test_spool_lock_rejects_second_daemon_same_state_dir(tmp_path):

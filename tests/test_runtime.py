@@ -171,6 +171,24 @@ def test_delta_ps_pull_commit():
         ps.stop()
 
 
+def test_pull_lands_row_major_whatever_the_templates_layout():
+    """A template carries shape and dtype, not strides: ``np.asarray`` of a
+    TPU device array can come back in a non-C layout (first seen on a v5e
+    for a [256, 10] leaf — the landing buffer inherited it through
+    ``empty_like`` and every pull died as a ProtocolError)."""
+    center = np.arange(6, dtype=np.float32).reshape(2, 3)
+    ps = DeltaParameterServer([np.asfortranarray(center)])
+    ps.start()
+    try:
+        with PSClient("127.0.0.1", ps.port,
+                      templates=[np.asfortranarray(center)]) as c:
+            (w,) = c.pull()
+            assert w.flags.c_contiguous
+            np.testing.assert_array_equal(w, center)
+    finally:
+        ps.stop()
+
+
 def test_adag_ps_normalizes_by_num_workers():
     ps = ADAGParameterServer(_weights(), num_workers=4)
     ps.start()
