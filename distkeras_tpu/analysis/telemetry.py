@@ -5,7 +5,7 @@ fails on any name absent from :data:`~distkeras_tpu.analysis.
 telemetry_registry.TELEMETRY_NAMES`.  Two collectors:
 
 - **call sites**: the first string argument of every
-  ``counter``/``gauge``/``histogram``/``span``/``start_span``/
+  ``counter``/``gauge``/``histogram``/``span``/``phase``/``start_span``/
   ``record_span`` call in the package (and ``bench.py``) — covers every
   direct emission regardless of namespace;
 - **namespace sweep**: every string literal shaped like a project
@@ -36,7 +36,7 @@ from distkeras_tpu.analysis.telemetry_registry import TELEMETRY_NAMES
 #: ``unused-import-ok`` annotation is as inert as a typo'd rule id
 OWNED_RULES = frozenset(RULES) - {"unused-import"}
 
-_EMITTERS = {"counter", "gauge", "histogram", "span", "start_span",
+_EMITTERS = {"counter", "gauge", "histogram", "span", "phase", "start_span",
              "record_span"}
 
 #: full-match shape of a project telemetry name

@@ -76,12 +76,20 @@ TELEMETRY_NAMES = frozenset({
     "trainer_epochs_total", "trainer_epoch_seconds",
     "trainer_samples_total", "trainer_samples_per_sec_per_chip",
     "trainer_window_loss", "trainer.epoch",
-    "engine_steps_total", "engine_epoch_seconds", "engine_samples_per_sec",
-    "engine.run_epoch",
+    "engine_epoch_seconds", "engine.run_epoch",
     "async_windows_total", "async_window_wall_seconds",
     "async_window_device_seconds",
-    "async_workers_started_total", "async_workers_finished_total",
     "async.window",
+    # -- leaf phases (obs.phase: ring + jax.profiler TraceAnnotation) ----------
+    # worker loop, one set a window; seed and drain once a call
+    "async.pull_wait", "async.h2d", "async.dispatch", "async.device_wait",
+    "async.commit_d2h", "async.drain", "async.seed",
+    # PS client inside ps.commit; hub handler thread and center lock
+    "ps.commit_drain", "ps.commit_pack", "ps.commit_send",
+    "ps.recv_commit", "ps.send_weights", "ps.apply",
+    # sync plane and the trainer's feed
+    "engine.place", "engine.dispatch", "engine.device_wait", "feed.wait",
+    "feed_chunk_load_seconds", "feed_queue_depth", "feed_chunks_total",  # producer side
     "data_loads_total", "data_load_seconds", "data.load",
     "moe_steps_total",
     "punchcard_jobs_total", "punchcard.job",

@@ -45,8 +45,7 @@ def chunk_windows_for_budget(row_bytes: int, batch_size: int, window: int = 1,
 
 
 def prefetch_to_device(chunks: Iterator, place: Callable,
-                       produce_ahead: bool = True,
-                       metric_prefix: str = "feed") -> Iterator:
+                       produce_ahead: bool = True) -> Iterator:
     """Double-buffered feed: yield ``place(chunk)`` with the NEXT chunk's
     host->device transfer already issued before the caller consumes the
     current one.
@@ -61,7 +60,11 @@ def prefetch_to_device(chunks: Iterator, place: Callable,
     flight either way, so feeding stays O(chunk) memory — the out-of-core
     epoch's IO/H2D/compute overlap (SURVEY §7 step 3; round-4 verdict
     weak #6: the old loop issued synchronous per-chunk transfers with no
-    overlap)."""
+    overlap).
+
+    Telemetry: the consumer's wait for its next chunk is the leaf phase
+    ``feed.wait`` (the trainer's thread blocked on input, once a chunk;
+    the producer's side is ``feed_chunk_load_seconds``)."""
     if produce_ahead:
         import queue
         import threading
@@ -90,14 +93,10 @@ def prefetch_to_device(chunks: Iterator, place: Callable,
 
         # telemetry (no-op unless observability is enabled): producer-side
         # chunk production latency (disk page faults + shuffle copies) and
-        # the handoff queue's occupancy — the feed path's two signals.
-        # ``metric_prefix`` keeps distinct producers in distinct
-        # instruments (the async trainer's window staging uses
-        # "async_feed" so its microsecond slice walk cannot pollute the
-        # disk feed's chunk-load histogram or flap its depth gauge)
-        m_load = obs.histogram(f"{metric_prefix}_chunk_load_seconds")
-        m_depth = obs.gauge(f"{metric_prefix}_queue_depth")
-        m_chunks = obs.counter(f"{metric_prefix}_chunks_total")
+        # the handoff queue's occupancy — the feed path's two signals
+        m_load = obs.histogram("feed_chunk_load_seconds")
+        m_depth = obs.gauge("feed_queue_depth")
+        m_chunks = obs.counter("feed_chunks_total")
 
         def producer():
             try:
@@ -159,15 +158,18 @@ def prefetch_to_device(chunks: Iterator, place: Callable,
 
         chunks = produced()
     it = iter(chunks)
-    try:
-        cur = place(next(it))
-    except StopIteration:
-        return
-    for nxt in it:
-        nxt_placed = place(nxt)
+    end = cur = object()
+    while True:
+        with obs.phase("feed.wait"):
+            nxt = next(it, end)
+        if nxt is end:
+            break
+        placed = place(nxt)
+        if cur is not end:
+            yield cur
+        cur = placed
+    if cur is not end:
         yield cur
-        cur = nxt_placed
-    yield cur
 
 
 class Dataset:

@@ -7,7 +7,8 @@ async engine, feed path, MoE router, punchcard daemon):
 - :mod:`.metrics` — process-wide registry of counters / gauges /
   log-bucket histograms; thread-safe; near-zero cost while disabled.
 - :mod:`.tracing` — context-manager spans in a bounded ring buffer,
-  exportable as Chrome ``trace_event`` JSON and JSONL.
+  exportable as Chrome ``trace_event`` JSON and JSONL; leaf ``phase``
+  spans also lie on a ``jax.profiler`` trace's host lines.
 - :mod:`.sinks` — periodic JSONL flusher + Prometheus text exposition
   (label values escaped per the text-format spec).
 - :mod:`.distributed` — fleet-wide tracing (ISSUE #5): per-worker
@@ -50,7 +51,7 @@ from distkeras_tpu.observability.metrics import (
     TimeSeries,
 )
 from distkeras_tpu.observability.sinks import JsonlFlusher
-from distkeras_tpu.observability.tracing import SpanTracer
+from distkeras_tpu.observability.tracing import NULL_SPAN, SpanTracer
 
 REGISTRY = MetricsRegistry(enabled=False)
 TRACER = SpanTracer(enabled=False)
@@ -59,6 +60,7 @@ __all__ = [
     "DEFAULT_BUCKETS", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "TimeSeries", "SpanTracer", "JsonlFlusher", "REGISTRY", "TRACER",
     "enable", "disable", "enabled", "counter", "gauge", "histogram", "span",
+    "phase", "NULL_SPAN",
     "snapshot", "chrome_trace", "render_prometheus", "reset",
     "track", "untrack", "series", "tracked_snapshot",
 ]
@@ -93,6 +95,12 @@ def histogram(name: str, **labels: str) -> Histogram:
 
 def span(name: str, **attrs):
     return TRACER.span(name, **attrs)
+
+
+def phase(name: str, **attrs):
+    """A leaf span that also lands on a running ``jax.profiler`` trace
+    (:meth:`SpanTracer.phase`)."""
+    return TRACER.phase(name, **attrs)
 
 
 def track(name: str, window_s: float = 60.0, max_samples: int = 512) -> None:

@@ -22,6 +22,18 @@ a long run keeps the most recent spans and counts what it evicted
 (``dropped``) instead of growing without bound.  Like the registry,
 recording is near-zero when disabled — ``span()`` returns a shared no-op
 context manager.
+
+Two kinds of span.  ``span()`` is for ENCLOSING spans (``async.window``,
+``ps.commit``, ``engine.run_epoch``): ring only.  ``phase()`` is for LEAF
+phases — one thing the thread was doing (``async.commit_d2h``,
+``ps.apply``): the same ring record, and a ``jax.profiler.TraceAnnotation``
+of the same name entered and left with it, so that under a profiler
+session the phase lies on its thread's ``/host:`` line of the
+``xplane.pb`` beside the device's lines.  Only leaves go there: a reader
+that names a device gap by the host event that overlaps it longest would
+otherwise name every gap by the enclosing span.  A phase carries the
+attributes of the spans that enclose it on its thread (``worker``,
+``epoch``, ``window``: what caused it); every record names its ``parent``.
 """
 
 from __future__ import annotations
@@ -55,11 +67,27 @@ class _NullSpan:
         return None
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NULL_SPAN = _NullSpan()
+
+# jax.profiler.TraceAnnotation, looked up at the first phase recorded with
+# telemetry on (importing this package must not import jax); False where
+# jax cannot be imported: phases then stay ring-only
+_annotation: Any = None
+
+
+def _trace_annotation() -> Any:
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = False
+    return _annotation
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_parent")
 
     def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -69,14 +97,15 @@ class _Span:
     def __enter__(self) -> "_Span":
         stack = self._tracer._stack()
         self._depth = len(stack)
-        stack.append(self.name)
+        self._parent = stack[-1] if stack else None
+        stack.append(self)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter_ns()
         stack = self._tracer._stack()
-        if stack and stack[-1] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
         if exc_type is not None:
             # a span that ends by raising is an ERROR span, not a silent
@@ -84,7 +113,39 @@ class _Span:
             # trace viewer, error_type names the exception class
             self.attrs["error"] = 1
             self.attrs["error_type"] = exc_type.__name__
-        self._tracer._record(self.name, self._t0, t1, self._depth, self.attrs)
+        parent = self._parent
+        self._tracer._record(
+            self.name, self._t0, t1, self._depth, self.attrs,
+            parent=None if parent is None
+            else {"name": parent.name, "ts_us": parent._t0 // 1000})
+
+
+class _Phase(_Span):
+    """A leaf span that also lies on the profiler's timeline."""
+
+    __slots__ = ("_annotation",)
+
+    def __enter__(self) -> "_Phase":
+        # what caused this phase: the enclosing spans' attributes, the
+        # innermost winning, under the phase's own
+        inherited: Dict[str, Any] = {}
+        for outer in self._tracer._stack():
+            inherited.update(outer.attrs)
+        if inherited:
+            inherited.update(self.attrs)
+            self.attrs = inherited
+        cls = _trace_annotation()
+        self._annotation = cls(self.name, **{
+            k: _json_safe(v) for k, v in self.attrs.items()}) if cls else None
+        super().__enter__()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        super().__exit__(exc_type, exc, tb)
 
 
 class SpanTracer:
@@ -101,7 +162,7 @@ class SpanTracer:
         self._tls = threading.local()
         self.dropped = 0  # spans evicted by the ring since the last clear()
 
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[_Span]:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = []
@@ -116,8 +177,20 @@ class SpanTracer:
             return _NULL_SPAN
         return _Span(self, name, attrs)
 
+    def phase(self, name: str, **attrs: Any):
+        """``with tracer.phase("async.commit_d2h"): ...`` — a LEAF span:
+        the ring record of ``span()`` (with the enclosing spans' attributes
+        under its own) and a ``jax.profiler.TraceAnnotation`` of the same
+        name around the same statements, which costs half a microsecond
+        while no profiler session runs.  Open no phase inside a phase.
+        Disabled: the shared null span, no allocation, no jax import."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Phase(self, name, attrs)
+
     def _record(self, name: str, t0_ns: int, t1_ns: int, depth: int,
-                attrs: Dict[str, Any], tid: Optional[Any] = None) -> None:
+                attrs: Dict[str, Any], tid: Optional[Any] = None,
+                parent: Optional[Dict[str, Any]] = None) -> None:
         event = {
             "name": name,
             "ts_us": int(t0_ns) // 1000,     # perf_counter epoch, process-local
@@ -127,6 +200,9 @@ class SpanTracer:
                        else str(tid)),
             "depth": depth,
         }
+        if parent is not None:
+            # the enclosing span of the same thread: its name and start
+            event["parent"] = parent
         if attrs:
             event["attrs"] = {k: _json_safe(v) for k, v in attrs.items()}
         with self._lock:

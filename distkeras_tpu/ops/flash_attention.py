@@ -337,6 +337,9 @@ def _forward(q, k, v, cfg: _Config):
         ],
         interpret=cfg.interpret,
         compiler_params=_COMPILER_PARAMS,
+        # names the HLO custom call, and so its event on a profiler
+        # trace's XLA Ops line (otherwise the enclosing scope's name)
+        name="_fwd_kernel",
     )(q, k, v)
 
 
@@ -432,6 +435,7 @@ def _fused_backward_call(q, k, v, do, lse, delta, cfg: _Config, scale: float):
         ],
         interpret=cfg.interpret,
         compiler_params=_bwd_compiler_params(bq_kv, bk_kv),
+        name="_bwd_fused_kernel",
     )(q, k, v, do, lse, delta)
 
 

@@ -325,8 +325,8 @@ class WindowEngine:
         dispatch-to-completion time per compiled epoch-chunk program
         (``engine_epoch_seconds`` — the ``np.asarray`` below blocks, so
         the interval IS the program's effective duration incl. dispatch),
-        achieved throughput (``engine_samples_per_sec``) and the step
-        counter ``engine_steps_total``.
+        split by the leaf phases ``engine.place`` / ``engine.dispatch`` /
+        ``engine.device_wait``.
         """
         telemetry = obs.enabled()
         t0 = time.perf_counter() if telemetry else 0.0
@@ -340,22 +340,22 @@ class WindowEngine:
                     raise ValueError("this engine's spec needs per-batch dropout "
                                      "keys; pass keys=[num_windows, window, 2]")
                 keys = np.zeros(xs.shape[:2] + (2,), np.uint32)
-            keys_d = self._place_keys(np.asarray(keys))
-            state, losses = self._epoch_fns[1](state, xs_d, ys_d, keys_d)
-            losses = np.asarray(losses)
+            # leaf phases: the host's time between chunk programs (the
+            # chunk's own transfer is engine.place inside place_data, on
+            # whichever thread issues it)
+            with obs.phase("engine.place", what="keys"):
+                keys_d = self._place_keys(np.asarray(keys))
+            with obs.phase("engine.dispatch"):
+                state, losses = self._epoch_fns[1](state, xs_d, ys_d, keys_d)
+            with obs.phase("engine.device_wait"):
+                losses = np.asarray(losses)
         if telemetry:
-            dt = time.perf_counter() - t0
-            num_windows, window, global_batch = (int(d) for d in np.shape(xs)[:3])
             # identity as labels (ARCHITECTURE.md convention): a process
             # with several engines (bench legs, elastic rebuilds) must not
-            # overwrite one unlabeled gauge or merge differently-shaped
-            # programs into one histogram
-            ident = {"model": self.spec.name,
-                     "replicas": str(self.num_replicas)}
-            obs.histogram("engine_epoch_seconds", **ident).observe(dt)
-            obs.counter("engine_steps_total", **ident).inc(num_windows * window)
-            obs.gauge("engine_samples_per_sec", **ident).set(
-                num_windows * window * global_batch / max(dt, 1e-9))
+            # merge differently-shaped programs into one histogram
+            obs.histogram("engine_epoch_seconds", model=self.spec.name,
+                          replicas=str(self.num_replicas)).observe(
+                time.perf_counter() - t0)
         return state, losses
 
     def lower_epoch(self, state: ReplicaState, xs: np.ndarray, ys: np.ndarray):
@@ -387,11 +387,14 @@ class WindowEngine:
         if isinstance(xs, jax.Array) and isinstance(ys, jax.Array):
             return xs, ys
         sharding = self.data_sharding()
-        if jax.process_count() > 1:
-            lo, hi = self._local_batch_range(xs.shape[2])
-            return (jax.make_array_from_process_local_data(sharding, xs[:, :, lo:hi]),
-                    jax.make_array_from_process_local_data(sharding, ys[:, :, lo:hi]))
-        return jax.device_put(xs, sharding), jax.device_put(ys, sharding)
+        with obs.phase("engine.place", what="data"):
+            if jax.process_count() > 1:
+                lo, hi = self._local_batch_range(xs.shape[2])
+                return (jax.make_array_from_process_local_data(
+                            sharding, xs[:, :, lo:hi]),
+                        jax.make_array_from_process_local_data(
+                            sharding, ys[:, :, lo:hi]))
+            return jax.device_put(xs, sharding), jax.device_put(ys, sharding)
 
     _place_data = place_data  # backward-compatible alias
 

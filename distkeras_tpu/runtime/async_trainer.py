@@ -57,7 +57,7 @@ import numpy as np
 
 from distkeras_tpu import observability as obs
 from distkeras_tpu.observability import distributed as dtrace
-from distkeras_tpu.data.dataset import Dataset, prefetch_to_device
+from distkeras_tpu.data.dataset import Dataset
 from distkeras_tpu.models.base import Model
 from distkeras_tpu.parallel.engine import make_minibatch_step
 from distkeras_tpu.runtime.parameter_server import (
@@ -776,8 +776,6 @@ class AsyncDistributedTrainer(Trainer):
         m_wall = obs.histogram("async_window_wall_seconds")
         m_dev = obs.histogram("async_window_device_seconds")
         m_windows = obs.counter("async_windows_total")
-        m_started = obs.counter("async_workers_started_total")
-        m_finished = obs.counter("async_workers_finished_total")
 
         restart_counts = [0] * self.num_workers
 
@@ -961,8 +959,9 @@ class AsyncDistributedTrainer(Trainer):
                 # by later prefetches, and params must own its storage.
                 # On a restart this pull IS the recovery point: the
                 # worker resumes from the hub's current center
-                seed_host = [np.array(w) for w in client.pull()]
-                params = jax.device_put(unflatten(seed_host), device)
+                with obs.phase("async.seed", worker=idx):
+                    seed_host = [np.array(w) for w in client.pull()]
+                    params = jax.device_put(unflatten(seed_host), device)
                 # hot-tier mode (ISSUE 15): one full-shape DEVICE-resident
                 # mirror per sparse table, seeded from the initial full
                 # pull and scatter-refreshed each window with the [k, dim]
@@ -1004,23 +1003,15 @@ class AsyncDistributedTrainer(Trainer):
                         row_caps = [min(int(flat0[i].shape[0]),
                                         per_window * nc)
                                     for i, nc in zip(sparse_idx, ncols)]
-                    # with telemetry ON, window slices ride the shared
-                    # feed machinery with a no-op place: the producer
-                    # thread stages (wx, wy) views one window ahead and
-                    # records the feed queue gauges, while the device
-                    # transfer itself STAYS fused with the pull below —
-                    # one batched H2D per window.  With telemetry off the
-                    # loop is the plain zero-thread slice walk (no queue
-                    # handoff on the hot path)
-                    slices = ((xs[w], ys[w]) for w in range(xs.shape[0]))
-                    feed = (prefetch_to_device(slices, lambda s: s,
-                                               metric_prefix="async_feed")
-                            if obs.enabled() else slices)
                     # rows the pending prefetched pull was issued with
                     # (sparse only): the commit for window w must carry
                     # the SAME id set its pull asked for
                     next_rows: Optional[List[np.ndarray]] = None
-                    for w, (wx_h, wy_h) in enumerate(feed):
+                    # the plain slice walk, telemetry on or off: the
+                    # transfer of a window's rows is fused with the pull's
+                    # below (one batched H2D per window)
+                    for w in range(n_windows):
+                        wx_h, wy_h = xs[w], ys[w]
                         if controller is not None:
                             with fleet_lock:
                                 wants_drain = idx in drain_requests
@@ -1040,14 +1031,18 @@ class AsyncDistributedTrainer(Trainer):
                             rows_w = (next_rows if next_rows is not None
                                       else rows_of(xs[w]))
                             next_rows = None
+                        # the window's leaf phases (obs.phase) follow one
+                        # another on this thread and cover the window but
+                        # for bookkeeping; they inherit worker/epoch/window
                         with obs.span("async.window", worker=idx,
                                       epoch=epoch, window=w):
-                            if not pull_pending:
-                                if sparse_on:
-                                    client.pull_nowait(sparse_rows=rows_w)
-                                else:
-                                    client.pull_nowait()
-                            pulled_host = client.wait_weights()
+                            with obs.phase("async.pull_wait"):
+                                if not pull_pending:
+                                    if sparse_on:
+                                        client.pull_nowait(sparse_rows=rows_w)
+                                    else:
+                                        client.pull_nowait()
+                                pulled_host = client.wait_weights()
                             pull_pending = False
                             # ONE batched H2D per window (center + feed
                             # slices): every transfer call costs a host
@@ -1085,8 +1080,11 @@ class AsyncDistributedTrainer(Trainer):
                                 dense_host = [pulled_host[j]
                                               for j in range(len(pulled_host))
                                               if j not in sset]
-                                dense_dev, pad_dev, wx, wy = jax.device_put(
-                                    (dense_host, pads, wx_h, wy_h), device)
+                                with obs.phase("async.h2d"):
+                                    dense_dev, pad_dev, wx, wy = \
+                                        jax.device_put(
+                                            (dense_host, pads, wx_h, wy_h),
+                                            device)
                                 flat_dev: List[Any] = []
                                 di = si = 0
                                 for j in range(len(pulled_host)):
@@ -1102,60 +1100,72 @@ class AsyncDistributedTrainer(Trainer):
                                         di += 1
                                 pulled = unflatten(flat_dev)
                             else:
-                                pulled, wx, wy = jax.device_put(
-                                    (unflatten(pulled_host), wx_h, wy_h),
-                                    device)
+                                # host time in the call: the transfer is
+                                # only issued here, its tail is waited for
+                                # under async.device_wait
+                                with obs.phase("async.h2d"):
+                                    pulled, wx, wy = jax.device_put(
+                                        (unflatten(pulled_host), wx_h, wy_h),
+                                        device)
                             t_dev = time.perf_counter() if telemetry else 0.0
-                            params, opt_state, commit, mloss = window_fn(
-                                params, opt_state, pulled, wx, wy)
-                            # prefetch the NEXT window's pull while this
-                            # window's program runs: the request leaves
-                            # now (jax dispatch is async) and the weights
-                            # stream into the other landing buffer under
-                            # the compute — the center it snapshots
-                            # predates this window's commit below
-                            # (self-staleness 1; ARCHITECTURE.md)
-                            last_window = (w == n_windows - 1
-                                           and epoch == self.num_epoch - 1)
-                            if pipeline and not last_window:
-                                if sparse_on:
-                                    # sparse prefetch needs the NEXT
-                                    # window's ids, so it stops at the
-                                    # epoch tail (the next epoch's
-                                    # reshuffled slices don't exist yet);
-                                    # window 0 then issues its own pull —
-                                    # one pipeline bubble per epoch
-                                    if w + 1 < n_windows:
-                                        next_rows = rows_of(xs[w + 1])
-                                        client.pull_nowait(
-                                            sparse_rows=next_rows)
+                            # host dispatch: the window program's, and
+                            # the next window's pull request
+                            with obs.phase("async.dispatch"):
+                                params, opt_state, commit, mloss = window_fn(
+                                    params, opt_state, pulled, wx, wy)
+                                # prefetch the NEXT window's pull while this
+                                # window's program runs: the request leaves
+                                # now (jax dispatch is async) and the
+                                # weights stream into the other landing
+                                # buffer under the compute — the center it
+                                # snapshots predates this window's commit
+                                # below (self-staleness 1; ARCHITECTURE.md)
+                                last_window = (w == n_windows - 1
+                                               and epoch == self.num_epoch - 1)
+                                if pipeline and not last_window:
+                                    if sparse_on:
+                                        # sparse prefetch needs the NEXT
+                                        # window's ids, so it stops at the
+                                        # epoch tail (the next epoch's
+                                        # reshuffled slices don't exist
+                                        # yet); window 0 then issues its
+                                        # own pull — one pipeline bubble
+                                        # per epoch
+                                        if w + 1 < n_windows:
+                                            next_rows = rows_of(xs[w + 1])
+                                            client.pull_nowait(
+                                                sparse_rows=next_rows)
+                                            pull_pending = True
+                                    else:
+                                        client.pull_nowait()
                                         pull_pending = True
-                                else:
-                                    client.pull_nowait()
-                                    pull_pending = True
                             if telemetry:
-                                # block on the window program ONLY when
-                                # measuring: dispatch-to-completion is
-                                # the device leg of the wall/device
-                                # decomposition (the commit d2h below
-                                # would serialize on it anyway)
-                                jax.block_until_ready(mloss)
+                                # the one statement only telemetry runs:
+                                # it splits the wait for the window program
+                                # (async_window_device_seconds, which the
+                                # benchmark's async_exchange_share reads)
+                                # from the commit's copy-out.  It moves no
+                                # time: the device_get below serialises on
+                                # the program anyway
+                                with obs.phase("async.device_wait"):
+                                    jax.block_until_ready(mloss)
                                 m_dev.observe(time.perf_counter() - t_dev)
                             # one batched D2H for the payload; leaf order is
                             # the same tree.flatten order as the templates
-                            payload = jax.tree.leaves(jax.device_get(commit))
-                            if pipeline:
-                                # fire-and-forget: the ack coalesces into
-                                # the next window's weights receive
-                                if sparse_on:
-                                    client.commit_nowait(payload,
-                                                         sparse_rows=rows_w)
-                                else:
-                                    client.commit_nowait(payload)
-                            elif sparse_on:
-                                client.commit(payload, sparse_rows=rows_w)
+                            with obs.phase("async.commit_d2h"):
+                                payload = jax.tree.leaves(
+                                    jax.device_get(commit))
+                            # fire-and-forget when pipelined: the ack
+                            # coalesces into the next window's weights
+                            # receive; else it is waited for here
+                            if sparse_on:
+                                client.commit_nowait(payload,
+                                                     sparse_rows=rows_w)
                             else:
-                                client.commit(payload)
+                                client.commit_nowait(payload)
+                            if not pipeline:
+                                with obs.phase("async.drain"):
+                                    client.drain()
                         if sparse_on:
                             h_rows += int(sum(ids.size for ids in rows_w))
                         if telemetry:
@@ -1186,7 +1196,8 @@ class AsyncDistributedTrainer(Trainer):
                 # trailing acks (and nothing else: the last window never
                 # prefetches) — commits must be APPLIED before the run's
                 # final center read, not just queued on the wire
-                client.drain()
+                with obs.phase("async.drain", worker=idx):
+                    client.drain()
             except (WorkerPreempted, _DrainRequested) as stop_ev:
                 # graceful drain (ISSUE 19): finish the in-flight
                 # exchange — pipelined commit acks plus the unused
@@ -1234,9 +1245,6 @@ class AsyncDistributedTrainer(Trainer):
                 client.close()
         def run_worker(idx: int) -> None:
             losses: List[Any] = []
-            start_counted = obs.enabled()
-            if start_counted:
-                m_started.inc()
             if controller is not None:
                 controller.notify_worker_started(idx)
             progress = [0, 0]  # [resume epoch, losses length at its start]
@@ -1293,8 +1301,6 @@ class AsyncDistributedTrainer(Trainer):
                         # just drained would undo the retire
                         if idx not in drained:
                             exited_workers.add(idx)
-                if start_counted:
-                    m_finished.inc()
                 # flush even on a mid-run crash: windows whose commits
                 # already reached the center must stay in history / the
                 # samples metric (the 'continue' failure policy counts on

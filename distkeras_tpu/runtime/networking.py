@@ -323,14 +323,24 @@ def recv_frame(sock: socket.socket, limit: int = MAX_FRAME) -> bytes:
 
 
 def recv_frame_into(sock: socket.socket, buf: bytearray,
-                    limit: int = MAX_FRAME) -> memoryview:
+                    limit: int = MAX_FRAME, body_span=None) -> memoryview:
     """Receive one frame into the reusable ``buf`` (grown once to the
     largest frame seen, then steady-state zero-allocation), returning a
     memoryview of exactly the payload bytes.  The view aliases ``buf`` —
     it is valid only until the next call.  This is the long-lived-
     connection receive: the PS hub's handler loop reads every request
-    through one of these per connection."""
-    (n,) = struct.unpack(">Q", _recv_exact(sock, 8))
+    through one of these per connection.
+
+    ``body_span(action, n)`` (tensor frames only: the payload starts with
+    its action byte) returns the context manager the rest of the payload
+    is read under — how the hub times a commit's receive apart from the
+    idle wait for the next request.  The length prefix and the action byte
+    then arrive in ONE 9-byte read, so the syscalls are the same as
+    without it."""
+    head = _recv_exact(sock, 8 if body_span is None else 9)
+    (n,) = struct.unpack(">Q", head[:8])
+    if n < len(head) - 8:
+        raise ProtocolError("empty frame where a tensor frame was due")
     if n > limit:
         raise ProtocolError(f"frame of {n} bytes exceeds limit={limit}")
     if len(buf) < n:
@@ -343,7 +353,12 @@ def recv_frame_into(sock: socket.socket, buf: bytearray,
             # caller's steady-state buffer is simply not grown this time
             buf = bytearray(n)
     mv = memoryview(buf)[:n]
-    _recv_exact_into(sock, mv)
+    if body_span is None:
+        _recv_exact_into(sock, mv)
+    else:
+        mv[:1] = head[8:]
+        with body_span(head[8:], n):
+            _recv_exact_into(sock, mv[1:])
     if obs.enabled():
         obs.counter("net_rx_frames_total").inc()
         obs.counter("net_rx_bytes_total").inc(8 + n)

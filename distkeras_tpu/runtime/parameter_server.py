@@ -422,6 +422,14 @@ class ReplicationFeed:
 # share, so dense and sparse-row commits compose under the same math.
 
 
+def _apply_phase(t_request_ns: int, **attrs: Any):
+    """The leaf phase ``ps.apply``, opened just inside ``with hub._lock:``
+    around the apply: the time since ``t_request_ns`` (taken before the
+    ``with``) is how long the caller waited for the center lock."""
+    return obs.phase("ps.apply", lock_wait_us=(
+        time.perf_counter_ns() - t_request_ns) // 1000, **attrs)
+
+
 def _adasum_dot(a_parts: Sequence[Any], b_parts: Sequence[Any]) -> float:
     """Inner product of two commits in the center's flat vector space.
     Sparse x sparse pairs contribute only their intersecting rows."""
@@ -715,81 +723,88 @@ class _AdaptiveCombiner:
         telemetry = obs.enabled()
         t0_ns = time.perf_counter_ns() if telemetry else 0
         with hub._lock:
-            # the replicate decision is made UNDER the center lock, like
-            # _apply_commit_locked's: a replica attaching concurrently
-            # registers BEFORE snapshotting the center under this same
-            # lock, so either its sync includes this batch or active()
-            # is already True here and the batch is published — deciding
-            # earlier could lose the batch delta to a replica whose sync
-            # predates the apply
-            feed = hub._feed
-            replicate = feed is not None and feed.active()
-            clock0 = hub._clock
-            fence = hub._clock_fence
-            scaled_all: List[List[Any]] = []
-            for entry in batch:
-                lpc = entry["clock"]
-                if lpc < fence:
-                    lpc = fence
-                    entry["fenced"] = True
-                    entry["fence"] = fence
-                staleness = clock0 - lpc
-                wscale = (self.rate.scale_for(entry["worker"])
-                          if self.rate is not None else 1.0)
-                scale = float(hub.commit_scale(staleness)) * wscale
-                entry["staleness"] = staleness
-                entry["scale"] = scale
-                entry["rate_scale"] = wscale
-                entry["batch"] = len(batch)
-                scaled_all.append(
-                    _scale_parts(entry["parts"], np.float32(scale)))
-                if telemetry:
-                    hub._touch_rows_locked(
-                        (i, p[0]) for i, p in enumerate(entry["parts"])
-                        if isinstance(p, tuple))
-            if len(scaled_all) > 1 and not _mixed_repr(scaled_all):
-                applied = [adasum_merge(scaled_all)]
-            else:
-                # batch of one — or the RARE mixed dense/sparse batch,
-                # applied sequentially (plain queue-order semantics):
-                # merging it would densify sparse sides under this lock
-                applied = scaled_all
-            if replicate and len(applied) > 1:
-                # the RARE sequential (mixed dense/sparse) batch keeps the
-                # pre-ISSUE-15 replica contract: ONE center-shaped delta
-                # for the whole batch, applied exactly as published, so
-                # primary and replica perform IDENTICAL float additions
-                dense = [np.zeros_like(c) for c in hub.center]
-                for parts in applied:
-                    for full, p in zip(dense, parts):
-                        if isinstance(p, tuple):
-                            ids, g = p
-                            if ids.size:
-                                full[ids] += g
-                        else:
-                            full += p
-                for c, full in zip(hub.center, dense):
-                    c += full
-                publish_parts = dense
-            else:
-                # ONE commit (uncontended, or the whole batch Adasum-
-                # merged): apply in its native representation — sparse
-                # leaves touch only their merged ROW UNION — and hand the
-                # same parts to the feed, which frames them sparse for
-                # capable replicas (cost ∝ touched rows) and densifies
-                # only for legacy ones (_scale_parts/adasum own storage)
-                publish_parts = applied[0] if replicate else None
-                for parts in applied:
-                    for c, p in zip(hub.center, parts):
-                        if isinstance(p, tuple):
-                            ids, g = p
-                            if ids.size:
-                                c[ids] += g
-                        else:
-                            c += p
-            hub.num_updates += len(batch)
-            hub._clock += len(batch)
-            commit_clock = hub._clock
+            # leaf phase: the batch's apply under the center lock, named
+            # for the one worker it serves or for all of the batch's
+            with (_apply_phase(t0_ns, batch=len(batch), clock=hub._clock,
+                               worker=(batch[0]["worker"] if len(batch) == 1
+                                       else ",".join(str(e["worker"])
+                                                     for e in batch)))
+                  if telemetry else obs.NULL_SPAN):
+                # the replicate decision is made UNDER the center lock, like
+                # _apply_commit_locked's: a replica attaching concurrently
+                # registers BEFORE snapshotting the center under this same
+                # lock, so either its sync includes this batch or active()
+                # is already True here and the batch is published — deciding
+                # earlier could lose the batch delta to a replica whose sync
+                # predates the apply
+                feed = hub._feed
+                replicate = feed is not None and feed.active()
+                clock0 = hub._clock
+                fence = hub._clock_fence
+                scaled_all: List[List[Any]] = []
+                for entry in batch:
+                    lpc = entry["clock"]
+                    if lpc < fence:
+                        lpc = fence
+                        entry["fenced"] = True
+                        entry["fence"] = fence
+                    staleness = clock0 - lpc
+                    wscale = (self.rate.scale_for(entry["worker"])
+                              if self.rate is not None else 1.0)
+                    scale = float(hub.commit_scale(staleness)) * wscale
+                    entry["staleness"] = staleness
+                    entry["scale"] = scale
+                    entry["rate_scale"] = wscale
+                    entry["batch"] = len(batch)
+                    scaled_all.append(
+                        _scale_parts(entry["parts"], np.float32(scale)))
+                    if telemetry:
+                        hub._touch_rows_locked(
+                            (i, p[0]) for i, p in enumerate(entry["parts"])
+                            if isinstance(p, tuple))
+                if len(scaled_all) > 1 and not _mixed_repr(scaled_all):
+                    applied = [adasum_merge(scaled_all)]
+                else:
+                    # batch of one — or the RARE mixed dense/sparse batch,
+                    # applied sequentially (plain queue-order semantics):
+                    # merging it would densify sparse sides under this lock
+                    applied = scaled_all
+                if replicate and len(applied) > 1:
+                    # the RARE sequential (mixed dense/sparse) batch keeps the
+                    # pre-ISSUE-15 replica contract: ONE center-shaped delta
+                    # for the whole batch, applied exactly as published, so
+                    # primary and replica perform IDENTICAL float additions
+                    dense = [np.zeros_like(c) for c in hub.center]
+                    for parts in applied:
+                        for full, p in zip(dense, parts):
+                            if isinstance(p, tuple):
+                                ids, g = p
+                                if ids.size:
+                                    full[ids] += g
+                            else:
+                                full += p
+                    for c, full in zip(hub.center, dense):
+                        c += full
+                    publish_parts = dense
+                else:
+                    # ONE commit (uncontended, or the whole batch Adasum-
+                    # merged): apply in its native representation — sparse
+                    # leaves touch only their merged ROW UNION — and hand the
+                    # same parts to the feed, which frames them sparse for
+                    # capable replicas (cost ∝ touched rows) and densifies
+                    # only for legacy ones (_scale_parts/adasum own storage)
+                    publish_parts = applied[0] if replicate else None
+                    for parts in applied:
+                        for c, p in zip(hub.center, parts):
+                            if isinstance(p, tuple):
+                                ids, g = p
+                                if ids.size:
+                                    c[ids] += g
+                            else:
+                                c += p
+                hub.num_updates += len(batch)
+                hub._clock += len(batch)
+                commit_clock = hub._clock
         if replicate:
             feed.publish(commit_clock, publish_parts)
         size = len(batch)
@@ -827,6 +842,12 @@ class _AdaptiveCombiner:
             "merge_queue_depth", size, any_shard=True)
         for entry in batch:
             entry["done"] = True
+
+
+#: wire actions whose frame body is a commit (ps.recv_commit times them)
+_COMMIT_ACTIONS = frozenset((net.ACTION_COMMIT, net.ACTION_QCOMMIT,
+                             net.ACTION_SPARSE_COMMIT,
+                             net.ACTION_SPARSE_QCOMMIT))
 
 
 class JobAdmissionError(net.ProtocolError):
@@ -1683,19 +1704,22 @@ class SocketParameterServer:
             if entry["fenced"]:
                 last_pull_clock = entry["fence"]
             return entry["staleness"], last_pull_clock
+        t_req = time.perf_counter_ns() if telemetry else 0
         with self._lock:
-            if last_pull_clock < self._clock_fence:
-                last_pull_clock = self._clock_fence
-                if telemetry:
-                    obs.counter("ps_fenced_commits_total",
-                                **self._mlabels).inc()
-            staleness = self._clock - last_pull_clock
-            scaled = (self._apply_sparse_commit_locked(parts, staleness)
-                      if sparse else
-                      self._apply_commit_locked(parts, staleness))
-            self.num_updates += 1
-            self._clock += 1
-            commit_clock = self._clock
+            with (_apply_phase(t_req, batch=1, clock=self._clock)
+                  if telemetry else obs.NULL_SPAN):
+                if last_pull_clock < self._clock_fence:
+                    last_pull_clock = self._clock_fence
+                    if telemetry:
+                        obs.counter("ps_fenced_commits_total",
+                                    **self._mlabels).inc()
+                staleness = self._clock - last_pull_clock
+                scaled = (self._apply_sparse_commit_locked(parts, staleness)
+                          if sparse else
+                          self._apply_commit_locked(parts, staleness))
+                self.num_updates += 1
+                self._clock += 1
+                commit_clock = self._clock
         if scaled is not None:
             self._feed.publish(commit_clock, scaled)
         return staleness, last_pull_clock
@@ -1767,16 +1791,20 @@ class SocketParameterServer:
         to the JOB's center under the SAME center lock.  No adaptive
         combiner, replication or snapshot participation — isolation is
         the contract (see :class:`_JobState`)."""
+        telemetry = obs.enabled()
+        t_req = time.perf_counter_ns() if telemetry else 0
         with self._lock:
-            staleness = state.clock - last_pull_clock
-            scale = self.commit_scale(staleness)
-            for c, d in zip(state.center, delta):
-                if scale == 1.0:
-                    c += d
-                else:
-                    c += d * scale
-            state.num_updates += 1
-            state.clock += 1
+            with (_apply_phase(t_req, batch=1, clock=state.clock)
+                  if telemetry else obs.NULL_SPAN):
+                staleness = state.clock - last_pull_clock
+                scale = self.commit_scale(staleness)
+                for c, d in zip(state.center, delta):
+                    if scale == 1.0:
+                        c += d
+                    else:
+                        c += d * scale
+                state.num_updates += 1
+                state.clock += 1
         return staleness, last_pull_clock
 
     def fleet_info(self) -> Dict[str, Any]:
@@ -2103,6 +2131,17 @@ class SocketParameterServer:
             # per-recv liveness bound: a peer that dies without FIN (host
             # crash, cable pull) no longer parks this handler forever
             conn.settimeout(self.idle_timeout)
+
+        def recv_phase(action: bytes, n: int):
+            # leaf phase over a commit frame's body coming off the
+            # connection, the idle wait for the request left out (reads
+            # ctx_attrs as the latest T announce left them; the batched
+            # receiver parses frames out of one buffer and has no such
+            # moment)
+            if action in _COMMIT_ACTIONS:
+                return obs.phase("ps.recv_commit", conn=conn_idx, bytes=n,
+                                 **self._shard_attrs, **ctx_attrs)
+            return obs.NULL_SPAN
         try:
             while True:
                 # raw receive: pull/bye carry zero tensors, commit carries
@@ -2116,7 +2155,8 @@ class SocketParameterServer:
                             limit=self._max_payload)
                     else:
                         payload = net.recv_frame_into(conn, rx,
-                                                      limit=self._max_payload)
+                                                      limit=self._max_payload,
+                                                      body_span=recv_phase)
                 except socket.timeout:
                     # silent past the liveness window (no heartbeat, no
                     # traffic): evict — half-open peers must not hold a
@@ -2145,7 +2185,9 @@ class SocketParameterServer:
                                 reply.pack(net.ACTION_WEIGHTS,
                                            job_state.center)
                                 job_pull_clock = job_state.clock
-                            reply.send_packed(conn)
+                            with obs.phase("ps.send_weights",
+                                           clock=job_pull_clock):
+                                reply.send_packed(conn)
                         if telemetry:
                             obs.counter("ps_pulls_total",
                                         **self._mlabels).inc()
@@ -2173,7 +2215,10 @@ class SocketParameterServer:
                             # can't hold the center
                             reply.pack(net.ACTION_WEIGHTS, self.center)
                             last_pull_clock = self._clock
-                        reply.send_packed(conn)
+                        # leaf phase: the reply going out, after the lock
+                        with obs.phase("ps.send_weights",
+                                       clock=last_pull_clock):
+                            reply.send_packed(conn)
                     if telemetry:
                         obs.counter("ps_pulls_total", **self._mlabels).inc()
                         obs.counter("ps_pull_bytes_total",
@@ -2313,7 +2358,9 @@ class SocketParameterServer:
                             if telemetry:
                                 self._touch_rows_locked(
                                     zip(self.sparse_leaves, ids_list))
-                        net.send_raw_frame(conn, frame)
+                        with obs.phase("ps.send_weights",
+                                       clock=last_pull_clock):
+                            net.send_raw_frame(conn, frame)
                     if telemetry:
                         obs.counter("ps_pulls_total", **self._mlabels).inc()
                         # raw tensor bytes, the same basis the dense pull
@@ -4098,17 +4145,20 @@ class PSClient(_HotTierCacheSurface):
         # hands it out later); the hub is then parked in recv when the
         # commit bytes arrive.  This receive time is pull wire-wait,
         # so it lands in ps.pull_stall_ms like any other pull block.
-        if self._has_pending(net.ACTION_WEIGHTS) \
-                or self._has_pending(net.ACTION_SPARSE_WEIGHTS):
-            t_drain = time.perf_counter() if obs.enabled() else 0.0
-            while (self._has_pending(net.ACTION_WEIGHTS)
-                   or self._has_pending(net.ACTION_SPARSE_WEIGHTS)):
+        # The three leaf phases below (drain, pack, send) split ps.commit.
+        with obs.phase("ps.commit_drain"):
+            if self._has_pending(net.ACTION_WEIGHTS) \
+                    or self._has_pending(net.ACTION_SPARSE_WEIGHTS):
+                t_drain = time.perf_counter() if obs.enabled() else 0.0
+                while (self._has_pending(net.ACTION_WEIGHTS)
+                       or self._has_pending(net.ACTION_SPARSE_WEIGHTS)):
+                    self._consume_one()
+                if t_drain:
+                    obs.histogram("ps.pull_stall_ms",
+                                  **self._mlabels).observe(
+                        (time.perf_counter() - t_drain) * 1e3)
+            while self._unacked() >= self.max_inflight:
                 self._consume_one()
-            if t_drain:
-                obs.histogram("ps.pull_stall_ms", **self._mlabels).observe(
-                    (time.perf_counter() - t_drain) * 1e3)
-        while self._unacked() >= self.max_inflight:
-            self._consume_one()
         telemetry = obs.enabled()
         t0 = time.perf_counter() if telemetry else 0.0
         if sparse_rows is not None:
@@ -4119,48 +4169,56 @@ class PSClient(_HotTierCacheSurface):
                 # checked BEFORE the zip below, which would truncate
                 raise ValueError(f"got {len(sparse_rows)} id arrays, client "
                                  f"has {len(self._sparse)} sparse tables")
-            ids_list = [net.normalize_row_ids(ids, self.templates[i].shape[0])
-                        for ids, i in zip(sparse_rows, self._sparse)]
-            if self._cache_rows is None:
-                arrays = _sparse_commit_arrays(
-                    delta, self.templates, self._sparse_set, ids_list,
-                    self._residual, self.compress)
-            else:
-                arrays = self._cached_commit_arrays(delta, ids_list)
-            action = (net.ACTION_SPARSE_QCOMMIT if self.compress == "int8"
-                      else net.ACTION_SPARSE_COMMIT)
-            frame = self._sp_enc.pack(action, arrays)
+            with obs.phase("ps.commit_pack"):
+                ids_list = [
+                    net.normalize_row_ids(ids, self.templates[i].shape[0])
+                    for ids, i in zip(sparse_rows, self._sparse)]
+                if self._cache_rows is None:
+                    arrays = _sparse_commit_arrays(
+                        delta, self.templates, self._sparse_set, ids_list,
+                        self._residual, self.compress)
+                else:
+                    arrays = self._cached_commit_arrays(delta, ids_list)
+                action = (net.ACTION_SPARSE_QCOMMIT
+                          if self.compress == "int8"
+                          else net.ACTION_SPARSE_COMMIT)
+                frame = self._sp_enc.pack(action, arrays)
             if telemetry:
                 obs.histogram("ps.serialize_ms", **self._mlabels).observe(
                     (time.perf_counter() - t0) * 1e3)
                 obs.counter("ps.commit_bytes",
                             **self._mlabels).inc(self._sp_enc.frame_len)
-            with self._io_lock:
-                net.send_raw_frame(self.sock, frame)
-                self._pending.append((net.ACTION_ACK, time.perf_counter()))
-                self._last_io = time.monotonic()
+            with obs.phase("ps.commit_send"):
+                with self._io_lock:
+                    net.send_raw_frame(self.sock, frame)
+                    self._pending.append((net.ACTION_ACK,
+                                          time.perf_counter()))
+                    self._last_io = time.monotonic()
             if telemetry:
                 obs.gauge("ps.inflight_depth",
                           **self._mlabels).set(self._unacked())
             return
-        if self.compress == "int8":
-            codec, action = self._q_codec, net.ACTION_QCOMMIT
-            # safe across a reconnect retry: the residual chain carries
-            # only ROUNDING error, so re-quantizing the same delta after
-            # a failed (never-applied) send still lands the delta once
-            arrays = _quantize_commit(delta, self._residual)
-        else:
-            codec, action = self._codec, net.ACTION_COMMIT
-            arrays = [np.asarray(d, np.float32) for d in delta]
-        codec.pack(action, arrays)
+        with obs.phase("ps.commit_pack"):
+            if self.compress == "int8":
+                codec, action = self._q_codec, net.ACTION_QCOMMIT
+                # safe across a reconnect retry: the residual chain carries
+                # only ROUNDING error, so re-quantizing the same delta
+                # after a failed (never-applied) send still lands the
+                # delta once
+                arrays = _quantize_commit(delta, self._residual)
+            else:
+                codec, action = self._codec, net.ACTION_COMMIT
+                arrays = [np.asarray(d, np.float32) for d in delta]
+            codec.pack(action, arrays)
         if telemetry:
             obs.histogram("ps.serialize_ms", **self._mlabels).observe(
                 (time.perf_counter() - t0) * 1e3)
             obs.counter("ps.commit_bytes", **self._mlabels).inc(codec.frame_len)
-        with self._io_lock:
-            codec.send_packed(self.sock)
-            self._pending.append((net.ACTION_ACK, time.perf_counter()))
-            self._last_io = time.monotonic()
+        with obs.phase("ps.commit_send"):
+            with self._io_lock:
+                codec.send_packed(self.sock)
+                self._pending.append((net.ACTION_ACK, time.perf_counter()))
+                self._last_io = time.monotonic()
         if telemetry:
             obs.gauge("ps.inflight_depth", **self._mlabels).set(self._unacked())
 

@@ -10,6 +10,8 @@ device histograms, and the prefetch queue-depth gauge.
 """
 
 import json
+import os
+import re
 import threading
 import time
 
@@ -169,6 +171,67 @@ def test_disabled_tracer_records_nothing():
     assert len(tr) == 0
 
 
+def test_phase_inherits_cause_and_records_parent():
+    """A leaf phase carries its enclosing spans' attributes under its own
+    (what caused it) and every record names the enclosing span of its
+    thread; a plain span inherits nothing."""
+    tr = SpanTracer(capacity=16, enabled=True)
+    with tr.span("async.window", worker=1, epoch=0, window=7):
+        with tr.span("ps.commit", compress="none", window=8):
+            with tr.phase("ps.commit_send", bytes=5):
+                pass
+        with tr.phase("async.h2d", window=9):
+            pass
+    send, commit, h2d, window = tr.events()
+    assert send["attrs"] == {"worker": 1, "epoch": 0, "window": 8,
+                             "compress": "none", "bytes": 5}
+    assert send["depth"] == 2 and send["parent"] == {
+        "name": "ps.commit", "ts_us": commit["ts_us"]}
+    assert commit["attrs"] == {"compress": "none", "window": 8}
+    assert commit["parent"]["name"] == "async.window"
+    assert h2d["attrs"]["window"] == 9 and h2d["attrs"]["worker"] == 1
+    assert h2d["parent"] == {"name": "async.window", "ts_us": window["ts_us"]}
+    assert "parent" not in window
+    # another thread's stack is its own: no parent, nothing inherited
+    with tr.span("outer", worker=3):
+        t = threading.Thread(target=lambda: tr.phase("ps.apply").__enter__()
+                             .__exit__(None, None, None))
+        t.start()
+        t.join(10)
+    apply_ev = [e for e in tr.events() if e["name"] == "ps.apply"][0]
+    assert "parent" not in apply_ev and "attrs" not in apply_ev
+
+
+def test_phase_disabled_is_the_shared_null_span_and_imports_no_jax():
+    """Telemetry off: ``phase()`` hands back the one shared null span, and
+    ``observability`` alone never imports jax (the hub's modules must stay
+    importable beside a chip-holding parent); on, the first phase looks the
+    profiler's annotation up."""
+    import subprocess
+    import sys
+
+    tr = SpanTracer(enabled=False)
+    assert tr.phase("async.h2d", worker=0) is obs.NULL_SPAN
+    assert tr.span("async.window") is obs.NULL_SPAN and len(tr) == 0
+    code = (
+        "import sys\n"
+        "from distkeras_tpu import observability as obs\n"
+        "from distkeras_tpu.observability import tracing\n"
+        "p = obs.phase('async.h2d', worker=0)\n"
+        "assert p is obs.NULL_SPAN is tracing._NULL_SPAN\n"
+        "with p: pass\n"
+        "assert tracing._annotation is None\n"
+        "assert not [m for m in sys.modules if m == 'jax' or m.startswith('jax.')]\n"
+        "obs.enable()\n"
+        "with obs.phase('async.h2d', worker=0): pass\n"
+        "assert 'jax.profiler' in sys.modules and tracing._annotation\n"
+        "assert obs.TRACER.events()[0]['name'] == 'async.h2d'\n")
+    env = {k: v for k, v in os.environ.items() if k != "DKT_TELEMETRY"}
+    done = subprocess.run([sys.executable, "-c", code], env=env, timeout=120,
+                          capture_output=True, text=True)
+    assert done.returncode == 0, done.stderr
+
+
 def test_chrome_trace_export_is_valid_trace_event_json(tmp_path):
     tr = SpanTracer(capacity=16, enabled=True)
     with tr.span("a", worker=0):
@@ -309,12 +372,60 @@ def test_punchcard_telemetry_action(telemetry, tmp_path):
 
 # -- end-to-end acceptance: AsyncADAG smoke run -------------------------------
 
+WINDOW_PHASES = ("async.pull_wait", "async.h2d", "async.dispatch",
+                 "async.device_wait", "async.commit_d2h", "ps.commit_drain",
+                 "ps.commit_pack", "ps.commit_send")
+
+
+def _check_window_phases(events, n_windows):
+    """Every ``async.window``'s worker-thread leaf phases lie inside it, in
+    order, without overlap, carry its worker/epoch/window, and cover it but
+    for bookkeeping; the hub's ``ps.apply`` names the worker it served."""
+    windows = [e for e in events if e["name"] == "async.window"]
+    assert len(windows) == n_windows
+    cover = []
+    for win in windows:
+        lo, hi = win["ts_us"], win["ts_us"] + win["dur_us"]
+        mine = sorted((e for e in events if e["tid"] == win["tid"]
+                       and e["name"] in WINDOW_PHASES and lo <= e["ts_us"] <= hi),
+                      key=lambda e: e["ts_us"])
+        assert [e["name"] for e in mine] == list(WINDOW_PHASES)
+        for e in mine:
+            assert e["ts_us"] + e["dur_us"] <= hi + 2     # whole microseconds
+            assert {k: e["attrs"][k] for k in ("worker", "epoch", "window")} \
+                == win["attrs"]
+            assert e["parent"]["name"] in ("async.window", "ps.commit")
+        for a, b in zip(mine, mine[1:]):
+            assert a["ts_us"] + a["dur_us"] <= b["ts_us"] + 2
+        cover.append(sum(e["dur_us"] for e in mine) / max(win["dur_us"], 1))
+    # bookkeeping between phases is microseconds; a window is milliseconds.
+    # The median and the total, not each window: a thread descheduled
+    # between two phases on a loaded CI host must not fail the test
+    assert sorted(cover)[len(cover) // 2] >= 0.9, cover
+    assert sum(e["dur_us"] for e in events if e["name"] in WINDOW_PHASES) \
+        >= 0.9 * sum(w["dur_us"] for w in windows)
+    applies = [e for e in events if e["name"] == "ps.apply"]
+    assert len(applies) == n_windows
+    workers = {w["attrs"]["worker"] for w in windows}
+    for e in applies:
+        assert e["attrs"]["worker"] in workers and e["attrs"]["batch"] == 1
+        assert e["attrs"]["lock_wait_us"] >= 0 and "clock" in e["attrs"]
+        assert e["parent"]["name"] == "ps.handle_commit"
+    assert sorted(e["attrs"]["clock"] for e in applies) == list(range(n_windows))
+    for name in ("ps.recv_commit", "ps.send_weights"):
+        assert {e["attrs"]["worker"] for e in events if e["name"] == name} == workers
+    for name in ("async.seed", "async.drain"):
+        assert len([e for e in events if e["name"] == name]) == len(workers)
+
+
 def test_async_adag_smoke_exports_metrics_and_chrome_trace(telemetry, toy_dataset,
                                                            tmp_path):
     import distkeras_tpu as dk
     from distkeras_tpu.models.base import Model, ModelSpec
 
-    spec = ModelSpec(name="mlp", config={"hidden_sizes": (16,), "num_outputs": 2},
+    # wide enough (1 MB of weights) that a window is milliseconds: the
+    # phase coverage below is then not a measure of the spans' own cost
+    spec = ModelSpec(name="mlp", config={"hidden_sizes": (512, 512), "num_outputs": 2},
                      input_shape=(8,))
     trainer = dk.AsyncADAG(Model.init(spec, seed=0),
                            loss="categorical_crossentropy", batch_size=16,
@@ -343,12 +454,11 @@ def test_async_adag_smoke_exports_metrics_and_chrome_trace(telemetry, toy_datase
     assert wall["count"] >= 3 and dev["count"] >= 3
     assert wall["sum"] >= dev["sum"]  # the wall leg contains the device leg
     assert any(k.startswith("ps_staleness{") for k in snap["gauges"])
-    # the async worker feed rides the shared prefetch machinery under its
-    # own metric prefix (so window staging cannot pollute the disk feed's
-    # instruments), and the prefetch queue-depth gauge populates in an
-    # async-only run too
-    assert "async_feed_queue_depth" in snap["gauges"]
-    assert snap["counters"]["async_feed_chunks_total"] > 0
+    # the worker loop is the plain slice walk: no feed thread, no feed
+    # instruments in an async-only run
+    assert not [k for k in list(snap["gauges"]) + list(snap["counters"])
+                if k.startswith("async_feed")]
+    _check_window_phases(obs.TRACER.events(), len(trainer.history))
     assert snap["counters"]['trainer_epochs_total{trainer="AsyncADAG"}'] == 1.0
     assert snap["histograms"]['trainer_window_loss{trainer="AsyncADAG"}']["count"] \
         == len(trainer.history)
@@ -863,3 +973,207 @@ def test_telemetry_disabled_leaves_async_run_unrecorded(toy_dataset):
     snap = obs.snapshot()
     assert snap["counters"].get("ps_commits_total", 0.0) == 0.0
     assert len(obs.TRACER.events()) == 0
+
+
+# -- leaf phases: where the program's time goes (ISSUE 25) --------------------
+
+def _tiny_async(toy_dataset, windows=3, **kw):
+    import distkeras_tpu as dk
+    from distkeras_tpu.data.dataset import Dataset
+    from distkeras_tpu.models.base import Model, ModelSpec
+
+    spec = ModelSpec(name="mlp", config={"hidden_sizes": (16,), "num_outputs": 2},
+                     input_shape=(8,))
+    rows = 16 * 4 * windows
+    ds = Dataset({c: toy_dataset[c][:rows] for c in ("features", "label")})
+    trainer = dk.AsyncADAG(Model.init(spec, seed=0),
+                           loss="categorical_crossentropy", batch_size=16,
+                           num_epoch=1, num_workers=1, communication_window=4,
+                           learning_rate=0.05, seed=0, **kw)
+    trainer.train(ds)
+    assert len(trainer.history) == windows
+    return trainer
+
+
+def test_worker_loop_runs_in_the_same_threads_with_telemetry_on_and_off(toy_dataset):
+    """Switching telemetry on must not change the program it measures: no
+    feed thread (or any other) exists only because telemetry is on."""
+    seen = {}
+
+    def census(on):
+        def hook(worker, window):
+            if window == 2:
+                seen[on] = sorted(re.sub("[0-9]+", "", t.name)
+                                  for t in threading.enumerate())
+        return hook
+
+    _tiny_async(toy_dataset, fault_hook=census(False))
+    obs.reset()
+    obs.enable()
+    try:
+        _tiny_async(toy_dataset, fault_hook=census(True))
+        assert [e for e in obs.TRACER.events() if e["name"] == "async.h2d"]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert seen[True] == seen[False] and seen[True]
+
+
+def _within(seconds, fn):
+    """``fn()`` on a thread, given up on after ``seconds`` (there is no
+    pytest-timeout here): a profiler that hangs fails this test alone."""
+    box = {}
+
+    def run():
+        try:
+            box["value"] = fn()
+        except BaseException as e:  # handed to the test's thread below
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still running after {seconds} s"
+    if "error" in box:
+        raise box["error"]
+    return box["value"]
+
+
+def test_phases_lie_on_the_profiler_host_plane_and_enclosing_spans_do_not(
+        telemetry, toy_dataset, tmp_path):
+    """Under ``jax.profiler.start_trace`` the leaf phases are TraceMe events
+    on the worker's and the hub handler's host lines (read back through
+    ``jax.profiler.ProfileData``, as the benchmark reads them); enclosing
+    spans stay in the ring only."""
+    import glob
+
+    import jax
+
+    def traced():
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            _tiny_async(toy_dataset)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        lines = {}
+        for plane in data.planes:
+            if plane.name.startswith("/host:"):
+                for k, line in enumerate(plane.lines):     # one a thread
+                    for ev in line.events:
+                        if ev.name.startswith(("async.", "ps.")):
+                            lines.setdefault(ev.name, []).append(
+                                (k, dict(ev.stats)))
+        return lines
+
+    lines = _within(120, traced)
+    assert len(lines["async.commit_d2h"]) == 3 and len(lines["ps.apply"]) == 3
+    assert not {"async.window", "ps.commit", "ps.handle_commit", "ps.pull",
+                "ps.handle_pull"} & set(lines)
+    # the worker's and the hub handler's phases lie on different lines
+    assert {l for l, _ in lines["async.commit_d2h"]} \
+        .isdisjoint({l for l, _ in lines["ps.apply"]})
+    assert sorted(int(s["window"]) for _, s in lines["async.commit_d2h"]) == [0, 1, 2]
+    assert all("lock_wait_us" in s and int(s["worker"]) == 0
+               for _, s in lines["ps.apply"])
+    ring = {e["name"] for e in obs.TRACER.events()}
+    assert {"async.window", "ps.commit", "ps.handle_commit"} <= ring
+
+
+def test_sync_plane_phases_and_feed_wait(telemetry, toy_dataset):
+    """The sync plane's host time between chunk programs: the chunk's
+    transfer where ``Trainer.train``'s prefetch issues it, the keys', the
+    dispatch and the loss read in ``run_epoch``; and the trainer's thread
+    waiting for its next chunk."""
+    import distkeras_tpu as dk
+    from distkeras_tpu.models.base import Model, ModelSpec
+
+    spec = ModelSpec(name="mlp", config={"hidden_sizes": (16,), "num_outputs": 2},
+                     input_shape=(8,))
+    trainer = dk.ADAG(Model.init(spec, seed=0), loss="categorical_crossentropy",
+                      batch_size=16, num_epoch=1, num_workers=2,
+                      communication_window=4, learning_rate=0.05, seed=0,
+                      chunk_windows=2)
+    trainer.train(toy_dataset)
+    events = obs.TRACER.events()
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    chunks = len(by["engine.run_epoch"])     # 1024 / (32 * 4) / 2 = 4
+    assert chunks == 4
+    assert len(by["engine.dispatch"]) == len(by["engine.device_wait"]) == chunks
+    place = by["engine.place"]
+    assert sorted(e["attrs"]["what"] for e in place) == ["data"] * chunks + ["keys"] * chunks
+    assert len(by["feed.wait"]) == chunks + 1          # the last finds the end
+    epoch = by["trainer.epoch"][0]
+    for e in by["engine.dispatch"] + by["engine.device_wait"]:
+        assert e["parent"]["name"] == "engine.run_epoch"
+        assert e["attrs"]["epoch"] == 0 and e["tid"] == epoch["tid"]
+    # the chunk's transfer is issued from the prefetch, on the trainer's
+    # thread, outside run_epoch
+    assert all(e["parent"]["name"] == "trainer.epoch" and e["tid"] == epoch["tid"]
+               for e in place if e["attrs"]["what"] == "data")
+    assert all(e["tid"] == epoch["tid"] for e in by["feed.wait"])
+
+
+def test_recv_frame_into_times_the_body_under_the_action(telemetry):
+    """``body_span`` gets the frame's action byte and length before the body
+    is read, and the payload comes out whole (the hub's ``ps.recv_commit``)."""
+    import socket
+
+    from distkeras_tpu.runtime import networking as net
+
+    a, b = socket.socketpair()
+    seen = []
+
+    def body_span(action, n):
+        seen.append((action, n))
+        return obs.phase("ps.recv_commit", bytes=n)
+
+    try:
+        arrays = [np.arange(6, dtype=np.float32), np.ones(3, np.float32)]
+        net.send_tensors(a, net.ACTION_COMMIT, arrays)
+        net.send_raw_frame(a, net.empty_tensor_frame(net.ACTION_PULL))
+        buf = bytearray(16)
+        action, blobs = net.decode_tensor_views(
+            net.recv_frame_into(b, buf, body_span=body_span))
+        assert action == net.ACTION_COMMIT
+        assert np.array_equal(np.frombuffer(blobs[0], np.float32), arrays[0])
+        action, blobs = net.decode_tensor_views(
+            net.recv_frame_into(b, buf, body_span=body_span))
+        assert action == net.ACTION_PULL and not blobs
+        a.sendall(b"\x00" * 9)
+        with pytest.raises(net.ProtocolError):
+            net.recv_frame_into(b, buf, body_span=body_span)
+    finally:
+        a.close()
+        b.close()
+    assert seen == [(net.ACTION_COMMIT, 5 + 8 + 24 + 8 + 12), (net.ACTION_PULL, 5)]
+    assert [e["attrs"]["bytes"] for e in obs.TRACER.events()] == [57, 5]
+
+
+@pytest.mark.parametrize("kw", [{"adaptive": True}, {"pipeline": False},
+                                {"transport": "inproc"}],
+                         ids=lambda kw: next(iter(kw)))
+def test_apply_phase_on_the_other_commit_paths(telemetry, toy_dataset, kw):
+    """``ps.apply`` under the center lock whichever way a commit reaches it:
+    the adaptive combiner's batch, the blocking exchange (whose ack wait is
+    each window's ``async.drain``), the in-process transport (the apply runs
+    on the worker's own thread, inside its window)."""
+    _tiny_async(toy_dataset, **kw)
+    events = obs.TRACER.events()
+    applies = [e for e in events if e["name"] == "ps.apply"]
+    assert len(applies) == 3
+    for e in applies:
+        assert e["attrs"]["worker"] == 0 and e["attrs"]["batch"] == 1
+        assert e["attrs"]["lock_wait_us"] >= 0
+        assert e["parent"]["name"] == "ps.handle_commit"
+    assert sorted(e["attrs"]["clock"] for e in applies) == [0, 1, 2]
+    drains = [e for e in events if e["name"] == "async.drain"]
+    assert len(drains) == (4 if kw.get("pipeline") is False else 1)
+    windows = {e["tid"] for e in events if e["name"] == "async.window"}
+    assert ({e["tid"] for e in applies} == windows) == (kw.get("transport") == "inproc")
