@@ -430,6 +430,44 @@ def _apply_phase(t_request_ns: int, **attrs: Any):
         time.perf_counter_ns() - t_request_ns) // 1000, **attrs)
 
 
+# -- the dense commit's scaled add ---------------------------------------------
+# float32 elements of the hub's reusable scratch: 256 KiB, small enough to
+# stay in L2 between the multiply that fills it and the add that drains it
+_APPLY_BLOCK = 1 << 16
+
+
+def _add_scaled_commit(center: Sequence[np.ndarray],
+                       delta: Sequence[np.ndarray], scale: float,
+                       scratch: np.ndarray) -> None:
+    """``c += d * scale`` for every leaf, in place and allocation-free: the
+    same two float32 roundings (multiply, then add), block by block through
+    ``scratch`` (``_APPLY_BLOCK`` float32, the hub's, used under its center
+    lock), so the center is bit for bit what the expression gives — and
+    what the replication branch of ``_apply_commit_locked`` and the C++
+    hub compute — without a temporary the size of the leaf.  ``scale ==
+    1.0`` skips the multiply (``x * float32(1.0)`` is exact).  A delta
+    leaf that is not C-contiguous float32 (``commit_direct`` alone can
+    hand one in) is converted first, as the replication branch does."""
+    scale = np.float32(scale)
+    one = scale == np.float32(1.0)
+    block = scratch.size
+    for c, d in zip(center, delta):
+        if d.dtype != np.float32 or not d.flags.c_contiguous:
+            d = np.asarray(d, np.float32, order="C")
+        if one:
+            c += d
+        elif not c.flags.c_contiguous:
+            # a center built from Fortran-ordered weights has no flat view
+            c += d * scale
+        else:
+            cf, df = c.reshape(-1), d.reshape(-1)
+            for i in range(0, cf.size, block):
+                cb = cf[i:i + block]
+                s = scratch[:cb.size]
+                np.multiply(df[i:i + block], scale, out=s)
+                np.add(cb, s, out=cb)
+
+
 def _adasum_dot(a_parts: Sequence[Any], b_parts: Sequence[Any]) -> float:
     """Inner product of two commits in the center's flat vector space.
     Sparse x sparse pairs contribute only their intersecting rows."""
@@ -957,6 +995,8 @@ class SocketParameterServer:
         # negative staleness
         self._clock_fence = 0
         self._lock = threading.Lock()
+        # the dense apply's block scratch, used only under ``_lock``
+        self._apply_scratch = np.empty(_APPLY_BLOCK, np.float32)
         self._listener: Optional[socket.socket] = None
         self._accept_thread: Optional[threading.Thread] = None
         self._handlers: List[threading.Thread] = []
@@ -1797,12 +1837,9 @@ class SocketParameterServer:
             with (_apply_phase(t_req, batch=1, clock=state.clock)
                   if telemetry else obs.NULL_SPAN):
                 staleness = state.clock - last_pull_clock
-                scale = self.commit_scale(staleness)
-                for c, d in zip(state.center, delta):
-                    if scale == 1.0:
-                        c += d
-                    else:
-                        c += d * scale
+                _add_scaled_commit(state.center, delta,
+                                   self.commit_scale(staleness),
+                                   self._apply_scratch)
                 state.num_updates += 1
                 state.clock += 1
         return staleness, last_pull_clock
@@ -2844,9 +2881,12 @@ class SocketParameterServer:
                              staleness: int) -> Optional[List[np.ndarray]]:
         """Apply one commit (caller holds the center lock) and return the
         scaled applied arrays for the replication feed, or ``None`` when no
-        replica is attached — the pre-HA in-place path, bit-identical
-        (``x * float32(1.0)`` is exact, so a replicated primary's center
-        trajectory matches an unreplicated one bit for bit)."""
+        replica is attached — then ``apply_commit`` runs, which for the
+        scaling hubs is in place and allocation-free
+        (:func:`_add_scaled_commit`).  The replicated branch below performs
+        the identical two float32 roundings, multiply then add (and ``x *
+        float32(1.0)`` is exact), so a replicated primary's center
+        trajectory matches an unreplicated one bit for bit."""
         feed = self._feed
         if feed is None or not feed.active():
             self.apply_commit(list(delta), staleness)
@@ -2876,7 +2916,8 @@ class DeltaParameterServer(SocketParameterServer):
 
 class ADAGParameterServer(SocketParameterServer):
     """ADAG normalization: ``center += delta / num_workers`` (reference
-    ``ADAGParameterServer.handle_commit``, SURVEY §2.6).
+    ``ADAGParameterServer.handle_commit``, SURVEY §2.6), applied in place
+    with no temporary (:func:`_add_scaled_commit`).
 
     ``elastic=True`` replaces the static configured denominator with the
     LIVE worker count from hub membership (join on first commit, leave on
@@ -2914,9 +2955,8 @@ class ADAGParameterServer(SocketParameterServer):
         return 1.0 / n
 
     def apply_commit(self, delta: List[np.ndarray], staleness: int) -> None:
-        inv = self.commit_scale(staleness)
-        for c, d in zip(self.center, delta):
-            c += d * inv
+        _add_scaled_commit(self.center, delta, self.commit_scale(staleness),
+                           self._apply_scratch)
 
 
 class DynSGDParameterServer(SocketParameterServer):
@@ -2928,9 +2968,8 @@ class DynSGDParameterServer(SocketParameterServer):
         return 1.0 / (staleness + 1.0)
 
     def apply_commit(self, delta: List[np.ndarray], staleness: int) -> None:
-        inv = self.commit_scale(staleness)
-        for c, d in zip(self.center, delta):
-            c += d * inv
+        _add_scaled_commit(self.center, delta, self.commit_scale(staleness),
+                           self._apply_scratch)
 
 
 def _normalize_failover(entry) -> List[Tuple[str, int]]:

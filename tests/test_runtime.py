@@ -5,6 +5,7 @@ with the genuinely-asynchronous trainer family on top."""
 
 import socket
 import threading
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -15,6 +16,8 @@ from distkeras_tpu.runtime.parameter_server import (
     DeltaParameterServer,
     DynSGDParameterServer,
     PSClient,
+    _APPLY_BLOCK,
+    _add_scaled_commit,
 )
 
 
@@ -221,6 +224,103 @@ def test_dynsgd_staleness_scaling():
         b.close()
     finally:
         ps.stop()
+
+
+def _delta_as(form, values):
+    """``values`` (float32, 1-D) as the delta leaf a commit path can hand
+    the hub: the array itself, the misaligned view the socket path decodes
+    out of its receive buffer, ``commit_direct``'s float64 or a strided
+    slice."""
+    if form == "aligned":
+        return values
+    if form == "misaligned":
+        buf = bytearray(13 + values.nbytes)
+        buf[13:] = values.tobytes()
+        d = np.frombuffer(buf, np.float32, values.size, 13)
+        assert values.size == 0 or not d.flags.aligned
+        return d
+    if form == "float64":
+        return values.astype(np.float64)
+    wide = np.zeros((values.size, 2), np.float32)
+    wide[:, 0] = values
+    d = wide[:, 0]
+    assert values.size < 2 or not d.flags.c_contiguous
+    return d
+
+
+@pytest.mark.parametrize("form", ["aligned", "misaligned", "float64",
+                                  "noncontiguous"])
+@pytest.mark.parametrize("scale", [1.0, 0.25, 1.0 / 3.0])
+def test_add_scaled_commit_bit_identical_to_expression(scale, form):
+    """The in-place blocked apply performs the expression's own two float32
+    roundings: every leaf equals ``c + d * float32(scale)`` bit for bit,
+    whatever its size against the block and however the delta is laid
+    out."""
+    rng = np.random.default_rng(26)
+    sizes = [0, 1, _APPLY_BLOCK - 1, _APPLY_BLOCK, _APPLY_BLOCK + 1,
+             3 * _APPLY_BLOCK + 7]
+    center = [rng.standard_normal(n).astype(np.float32) for n in sizes]
+    values = [(rng.standard_normal(n) * 1e-3).astype(np.float32)
+              for n in sizes]
+    expected = [c + v * np.float32(scale) for c, v in zip(center, values)]
+    delta = [_delta_as(form, v) for v in values]
+    kept = [np.array(d) for d in delta]
+    _add_scaled_commit(center, delta, scale,
+                       np.empty(_APPLY_BLOCK, np.float32))
+    for c, e, d, k in zip(center, expected, delta, kept):
+        assert c.dtype == np.float32 and e.dtype == np.float32
+        assert np.array_equal(c.view(np.uint32), e.view(np.uint32))
+        assert np.array_equal(d, k)  # the delta is read, never written
+
+
+def test_add_scaled_commit_keeps_leaf_shapes_and_fortran_center():
+    """Leaves keep their shapes (the flat blocks are views), a center leaf
+    with no flat view (Fortran order) still gets the same bits, and a
+    scalar leaf stays a scalar."""
+    for scale in (1.0, 0.25):
+        scalar = [np.array(2.0, np.float32)]
+        _add_scaled_commit(scalar, [np.array(4.0, np.float64)], scale,
+                           np.empty(_APPLY_BLOCK, np.float32))
+        assert scalar[0].shape == () and scalar[0] == 2.0 + 4.0 * scale
+    rng = np.random.default_rng(27)
+    shape = (3, _APPLY_BLOCK // 2 + 5)
+    c0 = rng.standard_normal(shape).astype(np.float32)
+    d = rng.standard_normal(shape).astype(np.float32)
+    expected = c0 + d * np.float32(0.25)
+    for center in ([c0.copy()], [np.asfortranarray(c0)]):
+        _add_scaled_commit(center, [d], 0.25,
+                           np.empty(_APPLY_BLOCK, np.float32))
+        assert center[0].shape == shape
+        assert np.array_equal(center[0].view(np.uint32),
+                              expected.view(np.uint32))
+
+
+def test_adag_socket_commit_applies_without_a_leaf_temporary():
+    """The dense apply is in place: over a socket commit at a leaf of 8
+    blocks (scale 1/4, the general branch) the hub's ``apply_commit``
+    allocates less than one leaf — ``d * scale`` would be a whole one."""
+    leaf = np.zeros(8 * _APPLY_BLOCK, np.float32)
+    ps = ADAGParameterServer([leaf], num_workers=4)
+    apply_commit, grown = ps.apply_commit, []
+
+    def traced_apply(delta, staleness):
+        tracemalloc.reset_peak()
+        before = tracemalloc.get_traced_memory()[0]
+        apply_commit(delta, staleness)
+        grown.append(tracemalloc.get_traced_memory()[1] - before)
+
+    ps.apply_commit = traced_apply
+    ps.start()
+    tracemalloc.start()
+    try:
+        with PSClient("127.0.0.1", ps.port, templates=[leaf]) as c:
+            c.commit([np.full(leaf.shape, 4.0, np.float32)])
+            w = c.pull()
+    finally:
+        tracemalloc.stop()
+        ps.stop()
+    assert np.array_equal(w[0], np.ones(leaf.shape, np.float32))
+    assert len(grown) == 1 and grown[0] < leaf.nbytes // 8
 
 
 def test_concurrent_commits_all_land():
