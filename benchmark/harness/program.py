@@ -1,7 +1,9 @@
 """The one place that touches the system under test.
 
 Builds the program's model from a configuration file and the reference's
-seeded weights, looks the trainer class up by name, and hands it a
+seeded weights (what the program's model and its parameter tree look like
+is the family's to say: ``families/<family>.py``, see ``harness/spec.py``),
+looks the trainer class up by name, and hands it a
 ``Dataset`` that stamps the host clock where the trainer touches it — the
 benchmark's own spans around the calls into the input layer.  Nothing here
 reaches below the public entry ``Trainer(...).train(Dataset)``.
@@ -19,60 +21,7 @@ import numpy as np
 from distkeras_tpu.data.dataset import Dataset
 
 
-def model_spec(cfg: Dict[str, Any]):
-    """The program's ``ModelSpec`` for a GPT-family configuration file."""
-    from distkeras_tpu.models.transformer import small_lm_spec
-
-    if int(cfg["n_inner"]) != 4 * int(cfg["n_embd"]):
-        raise ValueError("the program's block has d_ffn = 4 * d_model only")
-    return small_lm_spec(vocab_size=int(cfg["vocab_size"]),
-                         model_dim=int(cfg["n_embd"]),
-                         num_heads=int(cfg["n_head"]),
-                         num_layers=int(cfg["n_layer"]),
-                         max_seq_len=int(cfg["n_positions"]),
-                         positional="learned")
-
-
-def to_program_tree(ref: Dict[str, Any], n_layer: int) -> Dict[str, Any]:
-    """Reference leaves (blocks stacked on a layer axis) -> the parameter
-    tree ``TransformerLM`` builds.  Pure indexing: no arithmetic."""
-    tree = {"embed": {"embedding": ref["wte"]}, "pos_embed": ref["wpe"],
-            "final_norm": {"scale": ref["lnf_g"], "bias": ref["lnf_b"]}}
-    for i in range(n_layer):
-        tree[f"block_{i}"] = {
-            "LayerNorm_0": {"scale": ref["blocks.ln1_g"][i], "bias": ref["blocks.ln1_b"][i]},
-            "qkv": {"kernel": ref["blocks.w_qkv"][i]},
-            "proj": {"kernel": ref["blocks.w_o"][i]},
-            "LayerNorm_1": {"scale": ref["blocks.ln2_g"][i], "bias": ref["blocks.ln2_b"][i]},
-            "up": {"kernel": ref["blocks.w_up"][i]},
-            "down": {"kernel": ref["blocks.w_down"][i]},
-        }
-    return tree
-
-
-def from_program_tree(tree: Dict[str, Any], n_layer: int) -> Dict[str, Any]:
-    """The inverse, traceable (stacks the per-layer leaves)."""
-    import jax.numpy as jnp
-
-    def stack(*path):
-        def get(b):
-            x = tree[f"block_{b}"]
-            for k in path:
-                x = x[k]
-            return x
-        return jnp.stack([get(b) for b in range(n_layer)])
-
-    return {"wte": tree["embed"]["embedding"], "wpe": tree["pos_embed"],
-            "lnf_g": tree["final_norm"]["scale"], "lnf_b": tree["final_norm"]["bias"],
-            "blocks.ln1_g": stack("LayerNorm_0", "scale"),
-            "blocks.ln1_b": stack("LayerNorm_0", "bias"),
-            "blocks.w_qkv": stack("qkv", "kernel"), "blocks.w_o": stack("proj", "kernel"),
-            "blocks.ln2_g": stack("LayerNorm_1", "scale"),
-            "blocks.ln2_b": stack("LayerNorm_1", "bias"),
-            "blocks.w_up": stack("up", "kernel"), "blocks.w_down": stack("down", "kernel")}
-
-
-def build_model(cfg: Dict[str, Any], reference, seed: int):
+def build_model(cfg: Dict[str, Any], family, reference, seed: int):
     """The program's ``Model`` on the reference's seeded weights: made on the
     device in one jitted call, handed over as host arrays so that no
     parameter-sized device array of the benchmark's outlives set-up."""
@@ -81,23 +30,21 @@ def build_model(cfg: Dict[str, Any], reference, seed: int):
 
     from distkeras_tpu.models.base import Model
 
-    n = int(cfg["n_layer"])
-    make = jax.jit(lambda s: to_program_tree(reference.init_params(cfg, s), n))
+    make = jax.jit(lambda s: family.to_program_tree(reference.init_params(cfg, s), cfg))
     host = jax.tree.map(np.array, make(jnp.uint32(seed % 2**32)))
-    return Model(spec=model_spec(cfg), params=host)
+    return Model(spec=family.model_spec(cfg), params=host)
 
 
-def change_norms(cfg: Dict[str, Any], reference, params, seed: int,
+def change_norms(cfg: Dict[str, Any], family, reference, params, seed: int,
                  rare_rows=None) -> Dict[str, Any]:
     """Per-leaf norms of (the program's center - the seed's weights), in the
     reference's leaf naming, reduced on the device in one jitted call."""
     import jax
     import jax.numpy as jnp
 
-    n = int(cfg["n_layer"])
     fn = jax.jit(lambda t, s, rare: reference._leaf_norms({
         k: v - reference.init_params(cfg, s)[k]
-        for k, v in from_program_tree(t, n).items()}, rare))
+        for k, v in family.from_program_tree(t, cfg).items()}, rare))
     return jax.device_get(fn(params, jnp.uint32(seed % 2**32), rare_rows))
 
 

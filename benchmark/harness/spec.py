@@ -4,8 +4,28 @@
 one of them sits in a file of its own under ``benchmark/``:
 ``workloads/<cell>.json``, ``configs/<config>.json`` (or the ``file`` the
 entry gives), ``traffic/<traffic>.json``, ``metrics/<metric>.json`` and
-``readers/<reader>.py``.  A later PR adds files and entries; no file here
-needs an edit for them.
+``readers/<reader>.py``.  What belongs to a model FAMILY, the ``"family"``
+a configuration file names, sits in two files: ``reference/<family>.py``,
+the plain reference, and ``families/<family>.py``, the only code that reads
+that family's ``config.json`` keys on the harness's side:
+
+``model_spec(cfg)``, ``to_program_tree(ref_leaves, cfg)`` /
+``from_program_tree(tree, cfg)`` (the program's model and pure, traceable
+indexing between the reference's leaves and the program's tree),
+``shapes(cfg, traffic)`` -> ``{"seq_len", "vocab"}`` (the traffic file's
+``data.seq_len`` where it gives one, never over the configuration's
+positions: ``job_seq_len`` below), ``train_flops_per_token(cfg, seq_len)`` -> ``{"dense",
+"attention", "total"}`` (what a trained token requires) and
+``kernel_work(cfg, kernel, batch, seq_len)`` -> ``{"flops", "bytes"}`` (one
+mean call of the kernel of that HLO name).  ``families/gpt_lm.py`` states
+the contract in full.
+
+A later PR adds files and entries; no file here needs an edit for them.  A
+new family is ``configs/<name>.json`` with ``"family": "<f>"``,
+``reference/<f>.py``, ``families/<f>.py``, its traffic, cell and metric
+files (a kernel's roofline is a metric file ``{"reader": "trace_kernel",
+"args": {"kernel": "<name>"}}`` and a branch of the family's
+``kernel_work``), and the entries in ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -65,6 +85,25 @@ def load_reference(config_file: Dict[str, Any], root: str = ROOT):
     return _import(os.path.join(root, "benchmark", "reference",
                                 config_file["family"] + ".py"),
                    "benchmark_reference_" + config_file["family"])
+
+
+def load_family(config_file: Dict[str, Any], root: str = ROOT):
+    """The configuration's glue to the program and its required-work
+    counts: ``families/<family>.py`` (contract: this module's docstring)."""
+    return _import(os.path.join(root, "benchmark", "families",
+                                config_file["family"] + ".py"),
+                   "benchmark_family_" + config_file["family"])
+
+
+def job_seq_len(traffic: Dict[str, Any], positions: int) -> int:
+    """The job's sequence length, for a family's ``shapes``: the traffic
+    file's ``data.seq_len`` where it gives one, else the configuration's own
+    positions; a length over what the configuration declares raises."""
+    seq_len = int(traffic.get("data", {}).get("seq_len", positions))
+    if seq_len > positions:
+        raise ValueError(f"the traffic's seq_len {seq_len} is over the "
+                         f"configuration's {positions} positions")
+    return seq_len
 
 
 def load_reader(metric_name: str, root: str = ROOT):

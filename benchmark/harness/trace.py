@@ -12,8 +12,9 @@ instruction run, named by the instruction's text; a ``%while`` contains its
 body's events) and ``Async XLA Ops`` (DMA in flight); plane ``/host:CPU``
 with one line per host thread of runtime and JAX TraceMe events.  A Pallas
 kernel is an ``XLA Ops`` event whose text has
-``custom_call_target="tpu_custom_call"``; the trace does not carry the
-kernel function's name, so kernels are told apart by their operand count.
+``custom_call_target="tpu_custom_call"``; the instruction is named after
+the ``pallas_call``'s ``name=`` (``%_fwd_kernel.<n> = ...``, PR 25), so
+kernels are told apart by that name.
 """
 
 from __future__ import annotations
@@ -106,16 +107,11 @@ def module_runs(rows: Iterable[Row], prefix: str) -> List[Tuple[str, float, floa
             if r[1] == "XLA Modules" and r[2].startswith(prefix)]
 
 
-def mosaic_calls(rows: Iterable[Row]) -> List[Tuple[int, float]]:
-    """``(operand count, duration_ns)`` of every Pallas kernel run."""
-    out = []
-    for r in rows:
-        if r[1] == "XLA Ops" and MOSAIC in r[2]:
-            text = r[2]
-            a = text.index("custom-call(") + len("custom-call(")
-            b = text.index("), custom_call_target")
-            out.append((text[a:b].count(" %"), r[4]))
-    return out
+def mosaic_calls(rows: Iterable[Row]) -> List[Tuple[str, float]]:
+    """``(HLO name, duration_ns)`` of every Pallas kernel run; the name is
+    the instruction's, without its ``%``: ``_fwd_kernel.7``."""
+    return [(r[2].split(" = ", 1)[0].lstrip("%"), r[4])
+            for r in rows if r[1] == "XLA Ops" and MOSAIC in r[2]]
 
 
 def op_label(text: str) -> str:

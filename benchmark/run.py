@@ -98,14 +98,16 @@ def to_host(model):
 
 
 def shapes(cell: dict) -> dict:
-    """The sizes a cell's calls have, from its configuration and traffic."""
+    """The sizes a cell's calls have, from its traffic and, for what only
+    the model's family can say (sequence length, vocabulary), its family."""
     cfg, traffic = cell["config_file"], cell["traffic_file"]
     c = traffic["constructor"]
+    job = spec.load_family(cfg, cell["root"]).shapes(cfg, traffic)
     chips = int(cell["chips"])
     workers = chips if c["num_workers"] == "chips" else int(c["num_workers"])
     steps, batch = int(c["communication_window"]), int(c["batch_size"])
     return {"chips": chips, "workers": workers, "steps": steps, "batch": batch,
-            "seq_len": int(cfg["n_positions"]), "vocab": int(cfg["vocab_size"]),
+            "seq_len": int(job["seq_len"]), "vocab": int(job["vocab"]),
             "rows_per_window": steps * batch * workers,
             "data_kw": {k: v for k, v in traffic["data"].items()
                         if k in ("zipf_exponent", "repeat_prob", "repeat_span")}}
@@ -117,7 +119,7 @@ def rare_rows(cell: dict):
     rank = cell["check"].get("rare_min_rank")
     if rank is None:
         return None
-    return tokens.rare_token_ids(int(cell["config_file"]["vocab_size"]), int(rank))
+    return tokens.rare_token_ids(shapes(cell)["vocab"], int(rank))
 
 
 def drive_setup(cell: dict, seed: int, reference) -> dict:
@@ -130,7 +132,8 @@ def drive_setup(cell: dict, seed: int, reference) -> dict:
     from benchmark.harness import program
 
     cfg, traffic, sh = cell["config_file"], cell["traffic_file"], shapes(cell)
-    model = program.build_model(cfg, reference, seed)
+    family = spec.load_family(cfg, cell["root"])
+    model = program.build_model(cfg, family, reference, seed)
     trainer = program.make_trainer(traffic, model, sh["chips"])
     del model
     log("weights made, trainer built")
@@ -146,7 +149,7 @@ def drive_setup(cell: dict, seed: int, reference) -> dict:
         rec = program.run_call(trainer, traffic, part)
         followed.append({"losses": [float(x) for x in rec["losses"]],
                          "norms": program.change_norms(
-                             cfg, reference, rec.pop("model").params, seed, rare)})
+                             cfg, family, reference, rec.pop("model").params, seed, rare)})
         trainer.model = to_host(trainer.model)
         call_data.append(tuple(
             part[n].reshape(k, sh["steps"], sh["batch"] * sh["workers"], sh["seq_len"])
@@ -219,9 +222,11 @@ def layer_metrics(ctx: dict, tracing: dict, rec: dict, device: dict, result: dic
     if mark is not None and trace.device_planes(rows):
         # the traced stretch of the window, on the trace's clock
         lo = mark[0] + 1e9 * (rec["t_open"] - tracing["t_mark"])
-        hi = min(mark[0] + 1e9 * (rec["t_close"] - tracing["t_mark"]),
-                 max(r[3] + r[4] for r in rows))
-        ctx["trace"] = {"rows": rows, "lo": lo, "hi": hi}
+        close = mark[0] + 1e9 * (rec["t_close"] - tracing["t_mark"])
+        end = max(r[3] + r[4] for r in rows)
+        hi = min(close, end)
+        # cut: the profiler stopped inside the window (trace.max_seconds)
+        ctx["trace"] = {"rows": rows, "lo": lo, "hi": hi, "cut": end < close}
         bw = trace.busy_and_window(rows, lo, hi)
         device["busy_s"], device["window_s"] = bw["busy_s"], bw["window_s"]
         result["breakdown"] = {"device_ops": trace.top_device_ops(rows),
@@ -243,6 +248,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, devices) -> dict:
     cfg, traffic, sh = cell["config_file"], cell["traffic_file"], shapes(cell)
     chips, workers, seq_len, batch = sh["chips"], sh["workers"], sh["seq_len"], sh["batch"]
     reference = spec.load_reference(cfg, cell["root"])
+    family = spec.load_family(cfg, cell["root"])
     rows_per_window = sh["rows_per_window"]
     tok_per_window = window.tokens_per_window(traffic, seq_len, workers)
 
@@ -295,15 +301,15 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, devices) -> dict:
               "memory_peak_bytes": max(int(s.get("peak_bytes_in_use", 0)) for s in stats)}
     result = {"attempted": n_windows, "failed": failed}
     if traced:
-        ctx = {"cell": cell, "cfg": cfg, "traffic": traffic, "chips": chips,
+        ctx = {"cell": cell, "cfg": cfg, "family": family, "traffic": traffic,
+               "chips": chips, "batch": batch, "seq_len": seq_len,
                "peaks": peaks.device_peaks(devices[0].device_kind), "notes": [],
                "steps_per_program": int(traffic["steps_per_program"]),
                "spans": {"compile_s": compile_s, "train_call_fixed_s": fixed_s,
                          "feed_load_ms_per_window": 1e3 * rec["feed_s"] / n_windows,
                          "loss_at_mark": loss_mark if math.isfinite(loss_mark) else None},
-               "flops_per_step": batch * seq_len * peaks.train_flops_per_token(
-                   reference.matmul_params(cfg), int(cfg["n_layer"]), seq_len,
-                   int(cfg["n_embd"]))["total"]}
+               "flops_per_step": batch * seq_len * family.train_flops_per_token(
+                   cfg, seq_len)["total"]}
         result["metrics"] = layer_metrics(ctx, tracing, rec, device, result)
     else:
         units = {m["name"]: m["unit"] for m in cell["end_to_end"]}

@@ -18,7 +18,8 @@ def tiny_root(dst: str) -> str:
     two tiny cells in place of the real ones — made only by ADDING files
     and entries beside copies of the committed ones."""
     os.makedirs(os.path.join(dst, "benchmark"))
-    for d in ("readers", "reference", "metrics", "traffic", "configs", "workloads"):
+    for d in ("readers", "reference", "families", "metrics", "traffic", "configs",
+              "workloads"):
         shutil.copytree(os.path.join(ROOT, "benchmark", d),
                         os.path.join(dst, "benchmark", d))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:

@@ -17,21 +17,47 @@ def _cfg(name):
 
 def test_flops_per_token_of_cerebras_gpt_590m():
     cfg = _cfg("cerebras-gpt-590m")
-    ref = spec.load_reference(cfg)
+    ref, fam = spec.load_reference(cfg), spec.load_family(cfg)
     # by hand: 18 layers x 12 d^2 (d = 1536) + the tied unembedding 50257 x d
     by_hand = 18 * 12 * 1536 ** 2 + 50257 * 1536
     assert ref.matmul_params(cfg) == by_hand == 586_802_688
-    f = peaks.train_flops_per_token(by_hand, 18, 2048, 1536)
+    f = fam.train_flops_per_token(cfg, 2048)
+    assert f == peaks.train_flops_per_token(by_hand, 18, 2048, 1536)
     assert f["dense"] / 1e9 == pytest.approx(3.52, abs=0.005)
     assert f["attention"] / 1e9 == pytest.approx(0.34, abs=0.005)
     assert f["total"] == f["dense"] + f["attention"]
+    assert f["total"] / 1e9 == pytest.approx(3.86, abs=0.005)
+    # a shorter job needs fewer score pairs a token, the same parameters
+    short = fam.train_flops_per_token(cfg, 1024)
+    assert short["dense"] == f["dense"] and short["attention"] == f["attention"] / 2
 
 
 def test_flops_per_token_of_the_1b3_cut():
     cfg = _cfg("cerebras-gpt-1.3b")
-    ref = spec.load_reference(cfg)
+    ref, fam = spec.load_reference(cfg), spec.load_family(cfg)
     assert ref.matmul_params(cfg) == cfg["n_layer"] * 12 * 2048 ** 2 + 50257 * 2048
     assert cfg["published"]["n_layer"] == 24 and cfg["reduced"] == ["n_layer"]
+    assert fam.train_flops_per_token(cfg, 2048)["total"] / 1e9 == pytest.approx(3.89, abs=0.005)
+
+
+@pytest.mark.parametrize("config,heads", [("cerebras-gpt-590m", 12), ("cerebras-gpt-1.3b", 16)])
+def test_family_kernel_work_and_shapes_of_the_gpt_configurations(config, heads):
+    cfg = _cfg(config)
+    fam = spec.load_family(cfg)
+    for kernel, direction in (("_fwd_kernel", "fwd"), ("_bwd_fused_kernel", "bwd")):
+        assert fam.kernel_work(cfg, kernel, 4, 2048) == peaks.flash_counts(
+            direction, 4, heads, 2048, 128)
+    with pytest.raises(KeyError, match="_grouped_matmul"):
+        fam.kernel_work(cfg, "_grouped_matmul", 4, 2048)
+    # no traffic file of the benchmark names a length: the configuration's own
+    assert fam.shapes(cfg, {"data": {}}) == {"seq_len": 2048, "vocab": 50257}
+    assert fam.shapes(cfg, {"data": {"seq_len": 512}})["seq_len"] == 512
+    with pytest.raises(ValueError):
+        fam.shapes(cfg, {"data": {"seq_len": 4096}})
+    spec_ = fam.model_spec(cfg)
+    assert spec_.config["num_heads"] == heads and spec_.config["positional"] == "learned"
+    with pytest.raises(ValueError, match="4 \\* d_model"):
+        fam.model_spec(dict(cfg, n_inner=3 * cfg["n_embd"]))
 
 
 @pytest.mark.parametrize("direction,flops,nbytes", [

@@ -17,6 +17,7 @@ from benchmark.harness import check, program, spec, tokens  # noqa: E402
 
 CFG = dict(TINY, family="gpt_lm")
 REF = spec.load_reference(CFG)
+FAM = spec.load_family(CFG)
 SEED = 3_000_000_007          # more than 32 signed bits hold
 RARE = tokens.rare_token_ids(512, 40)
 
@@ -36,7 +37,7 @@ def followed():
 def test_reference_matches_transformer_lm_loss_and_gradients():
     from distkeras_tpu.ops.losses import get_loss
 
-    model = program.build_model(CFG, REF, SEED)
+    model = program.build_model(CFG, FAM, REF, SEED)
     apply, loss = model.spec.apply_fn(), get_loss("sparse_categorical_crossentropy")
     xs, ys = _rows(1)
     x, y = jnp.asarray(xs[0, 0]), jnp.asarray(ys[0, 0])
@@ -45,7 +46,7 @@ def test_reference_matches_transformer_lm_loss_and_gradients():
     with jax.default_matmul_precision("highest"):
         r_loss, r_grads = jax.value_and_grad(REF.batch_loss)(params, x, y)
     assert float(p_loss) == pytest.approx(float(r_loss), abs=0.05)   # bf16 logits
-    mapped = program.from_program_tree(p_grads, CFG["n_layer"])
+    mapped = FAM.from_program_tree(p_grads, CFG)
     assert set(mapped) == set(r_grads) == set(REF.param_shapes(CFG))
     for name, g in r_grads.items():
         got = np.asarray(mapped[name], np.float32)
@@ -56,8 +57,7 @@ def test_reference_matches_transformer_lm_loss_and_gradients():
 
 def test_program_tree_round_trip_is_pure_indexing():
     params = REF.init_params(CFG, 7)
-    back = program.from_program_tree(
-        program.to_program_tree(params, CFG["n_layer"]), CFG["n_layer"])
+    back = FAM.from_program_tree(FAM.to_program_tree(params, CFG), CFG)
     for name, v in params.items():
         assert np.array_equal(np.asarray(back[name]), np.asarray(v)), name
     assert not np.array_equal(np.asarray(REF.init_params(CFG, 8)["wte"]),
