@@ -91,6 +91,14 @@ TELEMETRY_NAMES = frozenset({
     "engine.place", "engine.dispatch", "engine.device_wait", "feed.wait",
     "feed_chunk_load_seconds", "feed_queue_depth", "feed_chunks_total",  # producer side
     "data_loads_total", "data_load_seconds", "data.load",
-    "moe_steps_total",
+    # the routed expert layer, from the counts the window program hands
+    # back (models/transformer.py::routed_step_hook.publish)
+    "moe_assignments_total", "moe_assignments_held_total",
+    "moe_expert_load_max_over_mean",
+    # device-side jax.named_scope names (models/transformer.py,
+    # parallel/moe.py): op_name metadata of the compiled program, mapped
+    # back to a trace's device events by obs.device_scopes
+    "attn.sliding", "attn.full", "moe.route", "moe.dispatch", "moe.experts",
+    "moe.shared", "moe.combine", "moe.bias",
     "punchcard_jobs_total", "punchcard.job",
 })

@@ -13,7 +13,7 @@ because SPMD replicas share one traced program.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,19 @@ def register_model(name: str):
         return cls
 
     return wrap
+
+
+class StepHook(NamedTuple):
+    """What a training step needs beyond ``apply_fn`` for an architecture
+    whose step, not its optimizer, moves a leaf (``ModelSpec.step_hook``).
+
+    ``apply(params, x) -> (out, stats)``; ``update(params, stats) -> params``
+    after the optimizer's update; ``publish(stats)`` on the host with the
+    window program's summed stats, when telemetry is on."""
+
+    apply: Callable
+    update: Callable
+    publish: Callable
 
 
 def build_module(name: str, config: Dict[str, Any]):
@@ -188,13 +201,38 @@ class ModelSpec:
 
         return apply
 
+    def sown_collections(self) -> Tuple[str, ...]:
+        """The collections a forward of this spec sows into, as its
+        registered class declares them (``sown_collections(config)``;
+        none declared = none sown)."""
+        declare = getattr(_MODEL_REGISTRY.get(self.name), "sown_collections", None)
+        return tuple(declare(self.config)) if declare else ()
+
+    def step_hook(self):
+        """``None``, or the :class:`StepHook` of an architecture whose
+        training step itself moves a leaf (``transformer_lm`` with the
+        routed expert layer: the selection bias, from the assignment counts
+        the forward sows)."""
+        make = getattr(_MODEL_REGISTRY.get(self.name), "step_hook", None)
+        return make(self) if make else None
+
+    def reject_step_hook(self, where: str) -> None:
+        """Raise where a step builder has no place for such a leaf: run
+        through a plain ``apply_fn`` step it would never move."""
+        if self.step_hook() is not None:
+            raise ValueError(
+                f"{where} has no place for a leaf the step itself moves (the "
+                "routed expert layer's selection bias); train this spec "
+                "through a synchronous distributed trainer (ADAG and its "
+                "siblings: parallel/engine.py::WindowEngine)")
+
     def reject_silent_aux(self, where: str) -> None:
         """Raise if training this spec through a plain ``apply_fn`` step
-        would silently drop sown aux losses (``sow`` into an immutable
-        collection is a no-op): currently MoE load-balance losses —
-        ``moe_experts`` on transformer_lm specs, ``num_experts`` on
-        moe_mlp_classifier specs."""
-        if self.config.get("moe_experts") or self.config.get("num_experts"):
+        would silently drop a sown LOSS term (``sow`` into an immutable
+        collection is a no-op): the Switch layer's load-balance loss.  The
+        question is what the spec sows, not whether it has experts: the
+        sigmoid-routed layer balances by a bias and sows counts only."""
+        if "aux_loss" in self.sown_collections():
             raise ValueError(
                 f"{where} would silently drop the MoE load-balance aux losses "
                 "(sow into an immutable collection is a no-op); train MoE "

@@ -134,6 +134,10 @@ def validate_decode_spec(spec: ModelSpec, what: str = "decoding") -> dict:
     if spec.name != "transformer_lm":
         raise ValueError(f"{what} is defined for transformer_lm specs, "
                          f"got {spec.name!r}")
+    # the cache path has its own forward of the GPT-2-style block
+    from distkeras_tpu.models.transformer import reject_block_features
+
+    reject_block_features(config, f"KV-cache {what}")
     return config
 
 

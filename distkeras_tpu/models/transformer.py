@@ -26,9 +26,11 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from distkeras_tpu.models.base import register_model
+from distkeras_tpu import observability as obs
+from distkeras_tpu.models.base import ModelSpec, StepHook, register_model
 from distkeras_tpu.ops.attention import attention
 
 
@@ -38,6 +40,33 @@ def _maybe_psum(x: jnp.ndarray, axis_name: Optional[str]) -> jnp.ndarray:
     if axis_name is None or axis_name not in jax.typeof(x).vma:
         return x
     return lax.psum(x, axis_name)
+
+
+# what a spec may set beyond the GPT-2-style block, with the value that
+# leaves the block as it was: the paths that know that block only (tensor,
+# sequence and pipeline parallelism, cached decoding) refuse the rest by name
+_BLOCK_DEFAULTS = {
+    "norm": "layernorm", "head_dim": None, "qk_norm": False, "attn_gate": False,
+    "post_norm": False, "mlp": "gelu", "layer_types": None, "routed_experts": 0,
+    "tie_word_embeddings": True, "embed_scale": 1.0,
+}
+LAYER_KINDS = {"full": "full", "full_attention": "full",
+               "sliding": "sliding", "sliding_attention": "sliding"}
+
+
+def unsupported_features(config) -> list:
+    """The names of the config-driven block's features ``config`` (a spec's
+    config or any mapping with those keys) sets away from their defaults."""
+    return sorted(k for k, default in _BLOCK_DEFAULTS.items()
+                  if config.get(k, default) != default)
+
+
+def reject_block_features(config, where: str) -> None:
+    new = unsupported_features(config)
+    if new:
+        raise ValueError(f"{where} runs the GPT-2-style block only (LayerNorm, "
+                         f"one attention kind, a tied head, a dense GELU FFN or the "
+                         f"Switch layer); this spec sets {new}")
 
 
 class TransformerBlock(nn.Module):
@@ -56,7 +85,9 @@ class TransformerBlock(nn.Module):
     positional: str = "learned"  # "learned" (table added at embed) | "rope"
                                  # (q/k rotated here by ABSOLUTE position —
                                  # pos_offset carries the caller's global
-                                 # offset, e.g. rank * L_local under sp)
+                                 # offset, e.g. rank * L_local under sp) |
+                                 # "none" (this layer gets no positional
+                                 # signal: TransformerLM.rope_layers)
     seq_axis: Optional[str] = None  # mesh axis name for ring attention
     tp_axis: Optional[str] = None   # mesh axis name for tensor parallelism
     tp_size: int = 1
@@ -70,14 +101,71 @@ class TransformerBlock(nn.Module):
     ep_size: int = 1
     moe_dispatch: str = "auto"  # "dense" | "sorted" | "auto" dispatch path
                                 # (parallel/moe.py resolve_dispatch_impl)
+    # -- the block's shape, from configuration.  The defaults are the
+    # GPT-2-style block above (pre-LayerNorm, fused qkv, tanh-GELU at
+    # mlp_ratio * model_dim): a spec that sets none of these builds the
+    # same parameter tree and the same program as before they existed.
+    norm: str = "layernorm"    # "layernorm" | "rmsnorm" (scale only)
+    norm_eps: float = 1e-6
+    head_dim: Optional[int] = None  # None = model_dim // num_heads; set
+                               # apart from the width, heads x head_dim
+                               # need not equal model_dim
+    qk_norm: bool = False      # RMSNorm over each q and k head vector
+    attn_gate: bool = False    # o = attention(...) * sigmoid(x @ W_gate)
+    post_norm: bool = False    # a norm AFTER each sublayer too, before the
+                               # residual add
+    mlp: str = "gelu"          # "gelu" (mlp_ratio * model_dim) | "swiglu"
+    mlp_dim: Optional[int] = None   # the SwiGLU's stated width
+    attn_kind: str = "full"    # "full" | "sliding" (causal, sliding_window)
+    sliding_window: Optional[int] = None
+    rope_theta: float = 10000.0
+    ffn_kind: str = "dense"    # "dense" | "moe": the sigmoid-routed expert
+                               # layer with a shared expert
+                               # (parallel/moe.py::HeldExpertsMLP); the
+                               # Switch layer above is moe_experts
+    routed_experts: int = 0    # the router's outputs (all experts of the
+                               # deployment), of which this replica holds
+    experts_held: Optional[tuple] = None  # ... [lo, hi); None = all
+    routed_top_k: int = 8
+    routed_dim: int = 0        # a routed (and the shared) expert's width
+    route_scale: float = 1.0
     compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.nowrap
+    def _norm(self, name: str):
+        """A norm module: LayerNorm keeps flax's automatic names
+        (``LayerNorm_<n>``, the tree the GPT-2-style block always had),
+        RMSNorm is named by its place."""
+        if self.norm == "layernorm":
+            return nn.LayerNorm(epsilon=self.norm_eps, dtype=self.compute_dtype)
+        if self.norm == "rmsnorm":
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype, name=name)
+        raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {self.norm!r}")
+
+    @nn.nowrap
+    def _check_parallel(self) -> None:
+        """Tensor and sequence parallelism know the GPT-2-style block only."""
+        if self.tp_size == 1 and self.seq_axis is None:
+            return
+        new = unsupported_features({
+            "head_dim": self.head_dim, "attn_gate": self.attn_gate,
+            "post_norm": self.post_norm, "qk_norm": self.qk_norm,
+            "mlp": self.mlp, "norm": self.norm,
+            "layer_types": ("sliding",) if self.attn_kind != "full" else None,
+            "routed_experts": self.routed_experts if self.ffn_kind == "moe" else 0})
+        if new:
+            raise ValueError(
+                f"tensor / sequence parallelism (tp_size {self.tp_size}, seq_axis "
+                f"{self.seq_axis!r}) runs the GPT-2-style block only; this block sets "
+                f"{new}")
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
+        self._check_parallel()
         if self.num_heads % self.tp_size:
             raise ValueError(f"num_heads {self.num_heads} not divisible by tp_size {self.tp_size}")
-        if self.positional not in ("learned", "rope"):
-            raise ValueError(f"positional must be 'learned' or 'rope', "
+        if self.positional not in ("learned", "rope", "none"):
+            raise ValueError(f"positional must be 'learned', 'rope' or 'none', "
                              f"got {self.positional!r}")
         if self.moe_experts and self.tp_size > 1:
             raise ValueError("MoE FFN does not compose with tensor parallelism (v1); "
@@ -85,15 +173,29 @@ class TransformerBlock(nn.Module):
         if self.moe_experts and self.seq_axis is not None:
             raise ValueError("MoE FFN does not compose with sequence parallelism "
                              "(v1); train MoE LMs with make_moe_lm_train_step")
+        if self.attn_kind not in ("full", "sliding"):
+            raise ValueError(f"attn_kind must be 'full' or 'sliding', got {self.attn_kind!r}")
+        if self.attn_kind == "sliding" and not self.sliding_window:
+            raise ValueError("attn_kind 'sliding' needs sliding_window")
+        if self.ffn_kind == "moe" and (self.ep_size != 1 or self.ep_axis is not None):
+            raise NotImplementedError(
+                "the routed expert layer runs one replica's share without its exchange "
+                f"(ep_size {self.ep_size}, ep_axis {self.ep_axis!r}); expert layers "
+                "across chips are not built")
         heads_local = self.num_heads // self.tp_size
-        head_dim = self.model_dim // self.num_heads
+        head_dim = self.head_dim or self.model_dim // self.num_heads
         ffn_local = self.mlp_ratio * self.model_dim // self.tp_size
         kv_heads = self.num_kv_heads or self.num_heads
         if self.num_heads % kv_heads:
             raise ValueError(f"num_heads {self.num_heads} not a multiple of "
                              f"num_kv_heads {kv_heads}")
+        with jax.named_scope(f"attn.{self.attn_kind}"):
+            x = x + self._attention(x, pos_offset, heads_local, head_dim, kv_heads)
+        return x + self._ffn(x, ffn_local)
 
-        y = nn.LayerNorm(dtype=self.compute_dtype)(x)
+    @nn.nowrap
+    def _attention(self, x, pos_offset, heads_local: int, head_dim: int, kv_heads: int):
+        y = self._norm("attn_norm")(x)
         if kv_heads == self.num_heads:
             qkv = nn.DenseGeneral((3, heads_local, head_dim), use_bias=False,
                                   dtype=self.compute_dtype, name="qkv")(y)  # [B, L, 3, Hl, Dh]
@@ -108,6 +210,9 @@ class TransformerBlock(nn.Module):
                                  use_bias=False, dtype=self.compute_dtype,
                                  name="kv")(y)
             k, v = kv[:, :, 0], kv[:, :, 1]
+        if self.qk_norm:
+            q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype, name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype, name="k_norm")(k)
         if self.positional == "rope":
             from distkeras_tpu.ops.rotary import rope_rotate
 
@@ -116,13 +221,37 @@ class TransformerBlock(nn.Module):
             # offset contract the learned table's slicing uses), decoding
             # rotates inside its own cache path, and plain training passes 0
             pos = pos_offset + jnp.arange(x.shape[1])
-            q, k = rope_rotate(q, pos), rope_rotate(k, pos)
-        o = attention(q, k, v, causal=True, axis_name=self.seq_axis, impl=self.attn_impl)
+            q = rope_rotate(q, pos, base=self.rope_theta)
+            k = rope_rotate(k, pos, base=self.rope_theta)
+        window = self.sliding_window if self.attn_kind == "sliding" else None
+        o = attention(q, k, v, causal=True, axis_name=self.seq_axis, impl=self.attn_impl,
+                      window=window)
+        if self.attn_gate:
+            gate = nn.DenseGeneral((heads_local, head_dim), use_bias=False,
+                                   dtype=self.compute_dtype, name="gate")(y)
+            o = o * jax.nn.sigmoid(gate)
         o = nn.DenseGeneral(self.model_dim, axis=(-2, -1), use_bias=False,
                             dtype=self.compute_dtype, name="proj")(o)  # [B, L, E] partial
-        x = x + _maybe_psum(o, self.tp_axis)
+        o = _maybe_psum(o, self.tp_axis)
+        return self._norm("attn_post_norm")(o) if self.post_norm else o
 
-        y = nn.LayerNorm(dtype=self.compute_dtype)(x)
+    @nn.nowrap
+    def _ffn(self, x, ffn_local: int):
+        y = self._norm("ffn_norm")(x)
+        if self.ffn_kind == "moe":
+            from distkeras_tpu.parallel.moe import HeldExpertsMLP
+
+            b, l, e = y.shape
+            held = tuple(self.experts_held) if self.experts_held else (0, self.routed_experts)
+            y = HeldExpertsMLP(
+                num_experts=self.routed_experts, experts_held=held,
+                model_dim=self.model_dim, hidden_dim=self.routed_dim,
+                top_k=self.routed_top_k, route_scale=self.route_scale,
+                compute_dtype=self.compute_dtype, name="experts")(y.reshape(b * l, e))
+            y = y.reshape(b, l, e)
+            return self._norm("ffn_post_norm")(y) if self.post_norm else y
+        if self.ffn_kind != "dense":
+            raise ValueError(f"ffn_kind must be 'dense' or 'moe', got {self.ffn_kind!r}")
         if self.moe_experts:
             from distkeras_tpu.parallel.moe import MoEMLP
 
@@ -139,11 +268,22 @@ class TransformerBlock(nn.Module):
                 dispatch_impl=self.moe_dispatch,
                 compute_dtype=self.compute_dtype, name="moe")(y.reshape(b * l, e))
             self.sow("aux_loss", "load_balance", aux)
-            return x + moe_out.reshape(b, l, e)
-        y = nn.Dense(ffn_local, use_bias=False, dtype=self.compute_dtype, name="up")(y)
-        y = nn.gelu(y)
+            return moe_out.reshape(b, l, e)
+        if self.mlp == "swiglu":
+            if not self.mlp_dim:
+                raise ValueError("mlp 'swiglu' needs its width, mlp_dim")
+            gate = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.compute_dtype,
+                            name="gate_proj")(y)
+            up = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.compute_dtype, name="up")(y)
+            y = nn.silu(gate) * up
+        elif self.mlp == "gelu":
+            y = nn.Dense(ffn_local, use_bias=False, dtype=self.compute_dtype, name="up")(y)
+            y = nn.gelu(y)
+        else:
+            raise ValueError(f"mlp must be 'gelu' or 'swiglu', got {self.mlp!r}")
         y = nn.Dense(self.model_dim, use_bias=False, dtype=self.compute_dtype, name="down")(y)
-        return x + _maybe_psum(y, self.tp_axis)
+        y = _maybe_psum(y, self.tp_axis)
+        return self._norm("ffn_post_norm")(y) if self.post_norm else y
 
 
 @register_model("transformer_lm")
@@ -188,7 +328,69 @@ class TransformerLM(nn.Module):
     moe_dispatch: str = "auto"  # dispatch path: "dense" | "sorted" | "auto"
     ep_axis: Optional[str] = None
     ep_size: int = 1
+    # -- the config-driven block (see TransformerBlock): every default is
+    # the GPT-2-style model above
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    head_dim: Optional[int] = None
+    qk_norm: bool = False
+    attn_gate: bool = False
+    post_norm: bool = False
+    mlp: str = "gelu"
+    mlp_dim: Optional[int] = None
+    layer_types: Optional[tuple] = None  # per layer "full" | "sliding"
+                               # (or the published "full_attention" /
+                               # "sliding_attention"); None = all full
+    sliding_window: Optional[int] = None
+    rope_layers: str = "all"   # under positional="rope": "all" | "sliding"
+                               # (full-attention layers get no positional
+                               # signal at all)
+    rope_theta: float = 10000.0
+    routed_experts: int = 0    # > 0: layers from num_dense_layers on carry
+                               # the sigmoid-routed expert layer
+                               # (parallel/moe.py::HeldExpertsMLP)
+    num_dense_layers: int = 0
+    experts_held: Optional[tuple] = None
+    routed_top_k: int = 8
+    routed_dim: int = 0
+    route_scale: float = 1.0
+    route_balance_coeff: float = 0.0  # the selection bias's step; read by
+                               # the training step (``step_hook``), not
+                               # by the forward
+    tie_word_embeddings: bool = True
+    embed_scale: float = 1.0   # the embedding's output times this
     compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @staticmethod
+    def sown_collections(config) -> tuple:
+        """What a forward of this configuration sows: the Switch layer a
+        loss term (``aux_loss``) that a plain ``apply`` would lose, the
+        routed expert layer only its assignment counts (its balancing adds
+        no loss term)."""
+        out = ()
+        if config.get("moe_experts"):
+            out += ("aux_loss", "router_stats")
+        if config.get("routed_experts"):
+            out += ("moe_counts",)
+        return out
+
+    @staticmethod
+    def step_hook(spec):
+        if not spec.config.get("routed_experts"):
+            return None
+        return routed_step_hook(spec)
+
+    def _layer_kinds(self) -> list:
+        if self.layer_types is None:
+            return ["full"] * self.num_layers
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
+                             f"num_layers is {self.num_layers}")
+        try:
+            return [LAYER_KINDS[t] for t in self.layer_types]
+        except KeyError as e:
+            raise ValueError(f"layer_types: unknown kind {e.args[0]!r}; "
+                             f"known: {sorted(LAYER_KINDS)}") from None
 
     def setup(self):
         # attribute names ARE the param-tree keys: "embed", "pos_embed",
@@ -217,12 +419,34 @@ class TransformerLM(nn.Module):
                 moe_dispatch=self.moe_dispatch,
                 ep_axis=self.ep_axis,
                 ep_size=self.ep_size,
-                positional=self.positional,
+                # under rope_layers "sliding" a full-attention layer gets no
+                # positional signal at all
+                positional=("none" if self.rope_layers == "sliding" and kind != "sliding"
+                            else self.positional),
+                norm=self.norm, norm_eps=self.norm_eps, head_dim=self.head_dim,
+                qk_norm=self.qk_norm, attn_gate=self.attn_gate,
+                post_norm=self.post_norm, mlp=self.mlp, mlp_dim=self.mlp_dim,
+                attn_kind=kind, sliding_window=self.sliding_window,
+                rope_theta=self.rope_theta,
+                ffn_kind=("moe" if self.routed_experts and i >= self.num_dense_layers
+                          else "dense"),
+                routed_experts=self.routed_experts, experts_held=self.experts_held,
+                routed_top_k=self.routed_top_k, routed_dim=self.routed_dim,
+                route_scale=self.route_scale,
                 compute_dtype=self.compute_dtype,
             )
-            for _ in range(self.num_layers)
+            for i, kind in enumerate(self._layer_kinds())
         ]
-        self.final_norm = nn.LayerNorm(dtype=self.compute_dtype)
+        if self.rope_layers not in ("all", "sliding"):
+            raise ValueError(f"rope_layers must be 'all' or 'sliding', got "
+                             f"{self.rope_layers!r}")
+        if self.norm == "rmsnorm":
+            self.final_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype)
+        else:
+            self.final_norm = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.compute_dtype)
+        if not self.tie_word_embeddings:
+            self.lm_head = nn.Dense(self.vocab_size, use_bias=False,
+                                    dtype=self.compute_dtype)
 
     def embed_tokens(self, tokens: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
         """Token (+ learned positional) embedding: [B, L] int32 -> [B, L, E].
@@ -234,14 +458,21 @@ class TransformerLM(nn.Module):
         through the per-block q/k rotation instead.
         """
         x = self.embed(tokens)
+        if self.embed_scale != 1.0:
+            x = x * jnp.asarray(self.embed_scale, x.dtype)
         if self.positional != "learned":
             return x
         pos = jnp.arange(tokens.shape[1]) + pos_offset
         return x + self.pos_embed[pos].astype(self.compute_dtype)
 
     def head(self, x: jnp.ndarray) -> jnp.ndarray:
-        """Final norm + tied unembedding: [B, L, E] -> [B, L, vocab] logits."""
+        """Final norm + unembedding (tied to the embedding unless
+        ``tie_word_embeddings`` is off): [B, L, E] -> [B, L, vocab] logits."""
         x = self.final_norm(x)
+        if not self.tie_word_embeddings:
+            # float32 for the loss: a softmax and a mean taken in bfloat16
+            # hand back a loss near 10 in steps of 0.0625
+            return self.lm_head(x).astype(jnp.float32)
         return self.embed.attend(x.astype(jnp.float32))
 
     def _trunk(self, tokens: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
@@ -249,7 +480,7 @@ class TransformerLM(nn.Module):
         x = self.embed_tokens(tokens, pos_offset)
         # pos_offset rides as a DYNAMIC remat arg: under sequence
         # parallelism it is a traced axis_index expression, not a constant
-        run = (nn.remat(lambda m, y, po: m(y, po), prevent_cse=False)
+        run = (nn.remat(lambda m, y, po: m(y, po), prevent_cse=True)
                if self.remat else (lambda m, y, po: m(y, po)))
         for blk in self.block:
             x = run(blk, x, pos_offset)
@@ -268,6 +499,59 @@ class TransformerLM(nn.Module):
 
     def __call__(self, tokens: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
         return self.head(self._trunk(tokens, pos_offset))
+
+
+def _expert_blocks(tree) -> list:
+    """Names of the blocks that carry the routed expert layer, in layer order."""
+    return sorted((k for k, v in tree.items()
+                   if k.startswith("block_") and "experts" in v),
+                  key=lambda name: int(name.split("_", 1)[1]))
+
+
+def routed_step_hook(spec: ModelSpec) -> StepHook:
+    """The step hook of a ``transformer_lm`` spec with ``routed_experts``:
+    the forward hands back every expert layer's assignment counts
+    ([expert layers, routed_experts] int32), and after each optimizer step
+    the selection bias of each such layer moves by
+    ``parallel/moe.py::bias_update`` (``route_balance_coeff``).  The bias is
+    a leaf of the parameter tree, so it rides pull and commit like any
+    weight."""
+    from distkeras_tpu.parallel.moe import bias_update
+
+    module = spec.build()
+    coeff = float(spec.config.get("route_balance_coeff", 0.0))
+    e = int(spec.config["routed_experts"])
+    lo, hi = spec.config.get("experts_held") or (0, e)
+
+    def apply(params, x):
+        out, sown = module.apply({"params": params}, x, mutable=["moe_counts"])
+        counts = sown["moe_counts"]
+        return out, jnp.stack([counts[b]["experts"]["assignments"][0]
+                               for b in _expert_blocks(counts)])
+
+    def update(params, counts):
+        if not coeff:
+            return params
+        params = dict(params)
+        with jax.named_scope("moe.bias"):
+            for n, name in enumerate(_expert_blocks(params)):
+                block = dict(params[name])
+                experts = dict(block["experts"])
+                experts["router_bias"] = bias_update(experts["router_bias"], counts[n], coeff)
+                block["experts"] = experts
+                params[name] = block
+        return params
+
+    def publish(counts) -> None:
+        counts = np.asarray(counts, dtype=np.int64).reshape(-1, e)   # a row a layer a window
+        held = counts[:, lo:hi]
+        obs.counter("moe_assignments_total").inc(int(counts.sum()))
+        obs.counter("moe_assignments_held_total").inc(int(held.sum()))
+        load = held.sum(axis=0)
+        if load.sum():
+            obs.gauge("moe_expert_load_max_over_mean").set(float(load.max() / load.mean()))
+
+    return StepHook(apply, update, publish)
 
 
 def small_lm_spec(vocab_size: int = 1024, model_dim: int = 256, num_heads: int = 2,

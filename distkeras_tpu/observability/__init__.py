@@ -41,6 +41,7 @@ disabled).
 from __future__ import annotations
 
 import os
+import re
 
 from distkeras_tpu.observability.metrics import (
     DEFAULT_BUCKETS,
@@ -63,6 +64,7 @@ __all__ = [
     "phase", "NULL_SPAN",
     "snapshot", "chrome_trace", "render_prometheus", "reset",
     "track", "untrack", "series", "tracked_snapshot",
+    "note_program", "device_scopes",
 ]
 
 
@@ -140,6 +142,34 @@ def reset() -> None:
     """Drop all recorded metrics and spans (enabled flags unchanged)."""
     REGISTRY.reset()
     TRACER.clear()
+
+
+# -- device-side scopes -------------------------------------------------------
+# A ``jax.named_scope`` survives into the compiled program as each HLO
+# instruction's ``op_name`` metadata, but a profiler trace's device events
+# carry only the instruction's NAME (``%fusion.12``).  A layer that runs a
+# window program notes, while telemetry is on, how to get that program's
+# compiled text; ``device_scopes`` turns it into {instruction: op_name}, so
+# that a reader of the trace can tell which scope a device event ran under.
+_PROGRAMS: dict = {}
+_OP_NAME = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"', re.M)
+
+
+def note_program(name: str, compiled_text) -> None:
+    """``compiled_text()`` -> the compiled HLO text of the program whose XLA
+    module is called ``name`` (``jit_<fn>``); kept lazily, the last one wins."""
+    _PROGRAMS[name] = compiled_text
+
+
+def device_scopes(name: str):
+    """{HLO instruction name: its ``op_name``} of the program noted under
+    ``name``, or ``None`` where none was (telemetry off, another plane)."""
+    text = _PROGRAMS.get(name)
+    if text is None:
+        return None
+    if callable(text):
+        text = _PROGRAMS[name] = text()    # compiled once, on first asking
+    return dict(_OP_NAME.findall(text))
 
 
 # lazy access to the distributed-tracing layer (PEP 562): obs.TraceContext,

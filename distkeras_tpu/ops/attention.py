@@ -41,13 +41,18 @@ def repeat_kv_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray):
 
 
 def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool = True,
-                    q_offset: int = 0, k_offset: int = 0) -> jnp.ndarray:
+                    q_offset: int = 0, k_offset: int = 0,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Plain softmax attention. Shapes: q [B, Lq, H, D], k/v [B, Lk, H, D]
     (or [B, Lk, Hkv, D] with grouped KV heads — broadcast up internally).
 
     ``q_offset``/``k_offset`` are the global positions of the first query /
     key element — needed when the caller holds only a shard of the sequence.
+    ``window`` (causal only): query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window``.
     """
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
     k, v = repeat_kv_heads(q, k, v)
     depth = q.shape[-1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(depth).astype(q.dtype)
@@ -55,6 +60,8 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         logits = jnp.where(mask[None, None, :, :], logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -236,7 +243,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, axis_name: st
 
 
 def attention(q, k, v, causal: bool = True, axis_name: Optional[str] = None,
-              impl: Optional[str] = None):
+              impl: Optional[str] = None, window: Optional[int] = None):
     """Dispatch: ring attention when a sequence mesh axis is given, else dense.
 
     A sequence-parallel model traced outside ``shard_map`` (e.g. parameter
@@ -254,6 +261,10 @@ def attention(q, k, v, causal: bool = True, axis_name: Optional[str] = None,
     the schedule is always ring attention and ``impl`` selects its
     per-block compute (``ring_attention``'s own crossover applies when
     ``None``).
+
+    ``window``: sliding-window causal attention (``0 <= i - j < window``),
+    in both the flash kernels and ``dense_attention``; the ring schedule
+    has no window and refuses one.
     """
     if axis_name is not None and not jax.typeof(q).vma:
         axis_name = None  # traced outside any shard_map: dense is exact
@@ -268,10 +279,13 @@ def attention(q, k, v, causal: bool = True, axis_name: Optional[str] = None,
             # anyway — GQA's memory win is the decode cache and the ring's
             # ICI traffic, both handled elsewhere)
             k, v = repeat_kv_heads(q, k, v)
-            return flash_attention(q, k, v, causal=causal)
+            return flash_attention(q, k, v, causal=causal, window=window)
         if impl != "dense":
             raise ValueError(f"unknown attention impl {impl!r}: expected 'flash' or 'dense'")
-        return dense_attention(q, k, v, causal=causal)
+        return dense_attention(q, k, v, causal=causal, window=window)
+    if window is not None:
+        raise ValueError("ring attention (sequence parallelism) has no sliding "
+                         "window; run window layers without a sequence axis")
     try:
         lax.axis_size(axis_name)
     except NameError:

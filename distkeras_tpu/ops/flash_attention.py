@@ -86,6 +86,7 @@ class _Config(NamedTuple):
     block_q_bwd: int
     block_k_bwd: int
     interpret: bool
+    window: int = 0  # > 0: sliding window, key j visible iff 0 <= i - j < window
 
 
 def _block_visible(cfg: _Config, qi, kj, bq, bk):
@@ -96,7 +97,12 @@ def _block_visible(cfg: _Config, qi, kj, bq, bk):
         return True
     last_q_pos = cfg.q_offset + (qi + 1) * bq - 1
     first_k_pos = cfg.k_offset + kj * bk
-    return last_q_pos >= first_k_pos
+    if not cfg.window:
+        return last_q_pos >= first_k_pos
+    # sliding window: also not entirely behind the window's far edge
+    first_q_pos = cfg.q_offset + qi * bq
+    last_k_pos = cfg.k_offset + (kj + 1) * bk - 1
+    return (last_q_pos >= first_k_pos) & (first_q_pos - last_k_pos < cfg.window)
 
 
 def _apply_causal_mask(s, cfg: _Config, qi, kj, bq, bk):
@@ -106,26 +112,69 @@ def _apply_causal_mask(s, cfg: _Config, qi, kj, bq, bk):
     def masked(s):
         q_pos = cfg.q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = cfg.k_offset + kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        if cfg.window:
+            return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < cfg.window), s, _NEG_INF)
         return jnp.where(q_pos >= k_pos, s, _NEG_INF)
 
     first_q_pos = cfg.q_offset + qi * bq
     last_k_pos = cfg.k_offset + (kj + 1) * bk - 1
-    return jax.lax.cond(first_q_pos >= last_k_pos, lambda s: s, masked, s)
+    inside = first_q_pos >= last_k_pos
+    if cfg.window:
+        # ... and the block's farthest pair (last query, first key) is
+        # still inside the window: only the diagonal and the window's edge
+        # pay for the mask
+        inside &= (first_q_pos + bq - 1) - (cfg.k_offset + kj * bk) < cfg.window
+    return jax.lax.cond(inside, lambda s: s, masked, s)
+
+
+def _k_span(cfg: _Config, qi, bq, bk, nkb):
+    """(first, last) key block a query block can see under the window;
+    ``qi`` a Python int or a traced scalar.  ``last < first`` where it sees
+    none.  A windowed grid visits only these (``_forward``)."""
+    first = (cfg.q_offset + qi * bq - (cfg.window - 1) - cfg.k_offset) // bk
+    last = (cfg.q_offset + (qi + 1) * bq - 1 - cfg.k_offset) // bk
+    if isinstance(qi, int):
+        return max(first, 0), min(last, nkb - 1)
+    return jnp.maximum(first, 0), jnp.minimum(last, nkb - 1)
+
+
+def _q_span(cfg: _Config, kj, bq, bk, nqb):
+    """(first, last) query block that can see key block ``kj`` under the
+    window (``_fused_backward_call``'s inner grid)."""
+    first = (cfg.k_offset + kj * bk - cfg.q_offset) // bq
+    last = (cfg.k_offset + (kj + 1) * bk - 1 + cfg.window - 1 - cfg.q_offset) // bq
+    if isinstance(kj, int):
+        return max(first, 0), min(last, nqb - 1)
+    return jnp.maximum(first, 0), jnp.minimum(last, nqb - 1)
+
+
+def _span_steps(span, cfg: _Config, n_outer: int, bq, bk, n_inner: int) -> int:
+    """The inner grid's length: the widest span over the outer blocks."""
+    return max(1, max(hi - lo + 1 for lo, hi in
+                      (span(cfg, o, bq, bk, n_inner) for o in range(n_outer))))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                cfg: _Config, scale: float):
-    qi, kj = pl.program_id(2), pl.program_id(3)
+                cfg: _Config, scale: float, k_blocks: int):
+    qi, step = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     bq, bk = cfg.block_q, cfg.block_k
+    kj, visible = step, None
+    if cfg.window:
+        # the inner grid walks the window's key blocks only: step 0 is the
+        # first block this query block sees (``_forward``'s index map)
+        first, last = _k_span(cfg, qi, bq, bk, k_blocks)
+        kj = first + step
+        visible = kj <= last
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_block_visible(cfg, qi, kj, bq, bk))
+    @pl.when(_block_visible(cfg, qi, kj, bq, bk) if visible is None
+             else visible & _block_visible(cfg, qi, kj, bq, bk))
     def _compute():
         q = q_ref[0, 0]  # [bq, d] — native dtype: bf16 x bf16 at full MXU rate
         k_blk = k_ref[0, 0]
@@ -147,7 +196,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             jnp.sum(p, axis=-1)[:, None], l_scr.shape)
         acc_scr[...] = acc_scr[...] * corr[:, None] + pv
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _flush():
         m = m_scr[:, 0]
         l_sum = l_scr[:, 0]
@@ -236,7 +285,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr, *,
-                      cfg: _Config, scale: float):
+                      cfg: _Config, scale: float, q_blocks: int):
     """One-pass backward: dK, dV and dQ from a single s/p recomputation.
 
     The separate dq kernel re-derives the identical [bq, bk] score and
@@ -253,20 +302,29 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     scratch makes VMEM O(Lq * D) rather than O(block) — ``_backward``
     falls back to the two-kernel path when that does not fit.
     """
-    kj, qi = pl.program_id(2), pl.program_id(3)
+    kj, step = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
     bq, bk = cfg.block_q_bwd, cfg.block_k_bwd
+    qi, visible = step, None
+    if cfg.window:
+        # the inner grid walks only the query blocks that see this key
+        # block (``_fused_backward_call``'s index map); past the last one
+        # the step stays on it with its compute predicated off
+        first, last = _q_span(cfg, kj, bq, bk, q_blocks)
+        visible = first + step <= last
+        qi = jnp.maximum(jnp.minimum(first + step, last), 0)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_kv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when((kj == 0) & (qi == 0))
+    @pl.when((kj == 0) & (step == 0))
     def _init_q():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_block_visible(cfg, qi, kj, bq, bk))
+    @pl.when(_block_visible(cfg, qi, kj, bq, bk) if visible is None
+             else visible & _block_visible(cfg, qi, kj, bq, bk))
     def _compute():
         p, ds, q, do, k_blk, _ = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, cfg, scale, qi, kj, bq, bk)
@@ -279,7 +337,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _flush_kv():
         dk_ref[0, 0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -313,14 +371,27 @@ def _forward(q, k, v, cfg: _Config):
     lk = k.shape[2]
     bq, bk = cfg.block_q, cfg.block_k
     scale = 1.0 / (d ** 0.5)
-    kernel = functools.partial(_fwd_kernel, cfg=cfg, scale=scale)
+    nkb = lk // bk
+    kernel = functools.partial(_fwd_kernel, cfg=cfg, scale=scale, k_blocks=nkb)
+    steps, kv_index = nkb, lambda b, h, i, j: (b, h, j, 0)
+    if cfg.window:
+        # key blocks wholly outside the window are not visited: the inner
+        # grid is as long as the widest span, and a step past a query
+        # block's last visible key block stays on that block (no new DMA;
+        # its compute is predicated off in the kernel)
+        steps = _span_steps(_k_span, cfg, lq // bq, bq, bk, nkb)
+
+        def kv_index(b, h, i, j):
+            first, last = _k_span(cfg, i, bq, bk, nkb)
+            return (b, h, jnp.clip(first + j, 0, jnp.maximum(last, 0)), 0)
+
     return pl.pallas_call(
         kernel,
-        grid=(b, h, lq // bq, lk // bk),
+        grid=(b, h, lq // bq, steps),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, d), kv_index),
+            pl.BlockSpec((1, 1, bk, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
@@ -407,21 +478,32 @@ def _fused_backward_call(q, k, v, do, lse, delta, cfg: _Config, scale: float):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     bq_kv, bk_kv = cfg.block_q_bwd, cfg.block_k_bwd
+    nqb = lq // bq_kv
+    steps, q_index = nqb, lambda b, h, j, i: (b, h, i, 0)
+    if cfg.window:
+        # query blocks that cannot see a key block are not visited (the
+        # forward's scheme, transposed)
+        steps = _span_steps(_q_span, cfg, lk // bk_kv, bq_kv, bk_kv, nqb)
+
+        def q_index(b, h, j, i):
+            first, last = _q_span(cfg, j, bq_kv, bk_kv, nqb)
+            return (b, h, jnp.clip(first + i, 0, jnp.maximum(last, 0)), 0)
+
     return pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, cfg=cfg, scale=scale),
-        grid=(b, h, lk // bk_kv, lq // bq_kv),
+        functools.partial(_bwd_fused_kernel, cfg=cfg, scale=scale, q_blocks=nqb),
+        grid=(b, h, lk // bk_kv, steps),
         in_specs=[
-            pl.BlockSpec((1, 1, bq_kv, d), lambda b, h, j, i: (b, h, i, 0)),   # q
+            pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # q
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # k
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # v
-            pl.BlockSpec((1, 1, bq_kv, d), lambda b, h, j, i: (b, h, i, 0)),   # do
-            pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), lambda b, h, j, i: (b, h, i, 0)),  # lse
-            pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), lambda b, h, j, i: (b, h, i, 0)),  # delta
+            pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # do
+            pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), q_index),                 # lse
+            pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), q_index),                 # delta
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # dk
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # dv
-            pl.BlockSpec((1, 1, bq_kv, d), lambda b, h, j, i: (b, h, i, 0)),   # dq
+            pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # dq
         ],
         out_shape=[
             _out_struct((b, h, lk, d), k.dtype, q, k, v, do),
@@ -598,9 +680,16 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Flash attention over [B, L, H, D] tensors (same layout/semantics as
     ``ops.attention.dense_attention``, including the shard offsets).
+
+    ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``.  The forward and the fused backward do not visit blocks
+    wholly outside the window (their inner grid is the widest span of
+    visible blocks, not the whole sequence); the diagonal and the window's
+    edge are masked.
 
     Kernel structure and block defaults (v5e device-time sweeps,
     2026-07-30): the forward uses one full-length block when the [Lq, Lk]
@@ -629,7 +718,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     (``platform.on_tpu``).
     """
     cfg = _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
-                       block_q_bwd, block_k_bwd, interpret)
+                       block_q_bwd, block_k_bwd, interpret, window)
     # [B, L, H, D] -> [B, H, L, D] for the kernels; the transposes sit outside
     # the custom_vjp so their adjoints are handled by XLA
     o = _flash(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), cfg)
@@ -664,10 +753,16 @@ def flash_attention_with_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
-                 block_q_bwd, block_k_bwd, interpret) -> _Config:
+                 block_q_bwd, block_k_bwd, interpret, window=None) -> _Config:
     if interpret is None:
         interpret = not on_tpu()
     lq, lk = q.shape[1], k.shape[1]
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+    # a window that reaches past every key this call holds is plain causal
+    window = 0 if (window is None or window >= q_offset + lq - k_offset) else int(window)
     d = q.shape[-1]
     # forward defaults (v5e device-time sweep, 2026-07-30, fwd+bwd with all
     # grads live): one full-length block when the whole [Lq, Lk] score tile
@@ -723,4 +818,4 @@ def _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
     return _Config(causal=bool(causal), q_offset=int(q_offset), k_offset=int(k_offset),
                    block_q=bq, block_k=bk, block_q_dq=bq_dq, block_k_dq=bk_dq,
                    block_q_bwd=bq_kv, block_k_bwd=bk_kv,
-                   interpret=bool(interpret))
+                   interpret=bool(interpret), window=window)

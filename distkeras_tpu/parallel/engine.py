@@ -56,14 +56,37 @@ def _ensure_varying(x, axis_name: str):
 
 def make_minibatch_step(apply_fn: Callable, loss: Callable,
                         optimizer: optax.GradientTransformation,
-                        with_rng: bool = False) -> Callable:
+                        with_rng: bool = False, hook=None) -> Callable:
     """One ``train_on_batch`` equivalent: value_and_grad + optax update.
 
     ``with_rng=True``: ``apply_fn`` is a train-mode forward taking a PRNG
     key (``ModelSpec.train_apply_fn``) and each scanned batch is
     ``(x, y, key)`` — the key rides the batch stream, NOT the carry, so
     state layouts (and checkpoint formats) are identical either way.
+
+    ``hook`` (``ModelSpec.step_hook()``): the place for a leaf that the
+    step, not the optimizer, updates.  The forward is ``hook.apply`` and
+    hands back stats beside the output; after the optimizer's update
+    ``hook.update(params, stats)`` moves its leaves, and the step's output
+    is ``(loss, stats)`` instead of the loss.
     """
+    if hook is not None:
+        if with_rng:
+            raise ValueError("a step hook and a dropout key stream do not compose (v1)")
+
+        def hooked_loss(params, batch):
+            out, stats = hook.apply(params, batch[0])
+            return loss(out, batch[1]), stats
+
+        def hooked_step(carry, batch):
+            params, opt_state = carry
+            (loss_val, stats), grads = jax.value_and_grad(
+                hooked_loss, has_aux=True)(params, batch)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = hook.update(optax.apply_updates(params, updates), stats)
+            return (params, opt_state), (loss_val, stats)
+
+        return hooked_step
     if with_rng:
         def loss_of(params, batch):
             return loss(apply_fn(params, batch[0], batch[2]), batch[1])
@@ -128,6 +151,10 @@ class WindowEngine:
         # therefore checkpoints — identical either way)
         self.needs_rng = spec.needs_rng
         self._apply = spec.train_apply_fn() if self.needs_rng else spec.apply_fn()
+        # a leaf the step itself moves (the routed expert layer's selection
+        # bias): its forward hands back stats, which the window program
+        # sums and returns beside the losses
+        self._hook = spec.step_hook()
         self._epoch_fns: Dict[int, Callable] = {1: self._build_epoch_fn()}
 
     # -- state ----------------------------------------------------------------
@@ -215,8 +242,9 @@ class WindowEngine:
         algo = self.algorithm
         axis = self.axis_name
         needs_rng = self.needs_rng
+        hook = self._hook
         mini = make_minibatch_step(self._apply, self.loss, self.optimizer,
-                                   with_rng=needs_rng)
+                                   with_rng=needs_rng, hook=hook)
 
         def shard_fn(state: ReplicaState, xs, ys, keys):
             # per-shard views: strip the leading (sharded) replica axis
@@ -238,12 +266,18 @@ class WindowEngine:
                     wx, wy = window_batches
                     batches = (wx, wy)
                 (local, opt_state), losses = lax.scan(mini, (local, opt_state), batches)
+                if hook is not None:
+                    # the window's stats: summed over its steps and replicas
+                    losses, stats = losses
+                    stats = jax.tree.map(lambda a: lax.psum(jnp.sum(a, axis=0), axis), stats)
                 center, local, extra = algo.window_commit(center, local, extra, axis)
                 # commit rules that reset local to the (mesh-invariant) center
                 # change the carry's varying-axes type; cast it back
                 local = jax.tree.map(lambda x: _ensure_varying(x, axis), local)
                 extra = jax.tree.map(lambda x: _ensure_varying(x, axis), extra)
                 mean_loss = lax.pmean(jnp.mean(losses), axis)
+                if hook is not None:
+                    return (center, local, opt_state, extra), (mean_loss, stats)
                 return (center, local, opt_state, extra), mean_loss
 
             data = (xs, ys, keys) if needs_rng else (xs, ys)
@@ -257,7 +291,8 @@ class WindowEngine:
 
                 (center, local, opt_state, extra), window_losses = lax.scan(
                     one_pass, (center, local, opt_state, extra), None, length=reps)
-                window_losses = window_losses[-1]  # last pass's per-window losses
+                # last pass's per-window losses
+                window_losses = jax.tree.map(lambda a: a[-1], window_losses)
             num_steps = xs.shape[0] * xs.shape[1] * reps
             new_state = ReplicaState(
                 center=center,
@@ -274,7 +309,7 @@ class WindowEngine:
             shard_fn,
             mesh=self.mesh,
             in_specs=(specs, data_spec, data_spec, P()),  # keys replicated
-            out_specs=(specs, P()),
+            out_specs=(specs, P()),   # P(): the losses, and a hook's stats beside them
         )
         return jax.jit(sharded, donate_argnums=(0,))
 
@@ -303,13 +338,13 @@ class WindowEngine:
             return jax.tree.map(jnp.array, state)
 
         _, losses = fn(fresh(), xs_d, ys_d, keys)
-        np.asarray(losses)  # compile + completion barrier
+        jax.block_until_ready(losses)  # compile + completion barrier
         rates = []
         for _ in range(repeat):
             s = fresh()
             t0 = _time.perf_counter()
             _, losses = fn(s, xs_d, ys_d, keys)
-            np.asarray(losses)
+            jax.block_until_ready(losses)
             rates.append(samples / (_time.perf_counter() - t0))
         return sorted(rates)[len(rates) // 2] / self.num_replicas
 
@@ -345,10 +380,23 @@ class WindowEngine:
             # whichever thread issues it)
             with obs.phase("engine.place", what="keys"):
                 keys_d = self._place_keys(np.asarray(keys))
+            if telemetry:
+                # the program's compiled text, for whoever asks which scope
+                # a device event ran under (obs.device_scopes): shapes only
+                # are kept, the compile happens on asking and hits the cache
+                fn, avals = self._epoch_fns[1], jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+                    (state, xs_d, ys_d, keys_d))
+                obs.note_program("jit_shard_fn",
+                                 lambda: fn.lower(*avals).compile().as_text())
             with obs.phase("engine.dispatch"):
                 state, losses = self._epoch_fns[1](state, xs_d, ys_d, keys_d)
             with obs.phase("engine.device_wait"):
+                if self._hook is not None:
+                    losses, stats = losses
                 losses = np.asarray(losses)
+        if telemetry and self._hook is not None:
+            self._hook.publish(np.asarray(stats))
         if telemetry:
             # identity as labels (ARCHITECTURE.md convention): a process
             # with several engines (bench legs, elastic rebuilds) must not

@@ -70,9 +70,21 @@ def _tp_leaf_spec(path, leaf, tp_axis: Optional[str]) -> P:
     return P()
 
 
+# leaves only the config-driven block builds: no Megatron split is defined
+_UNSHARDABLE = frozenset({"gate", "q_norm", "k_norm", "attn_post_norm", "ffn_post_norm",
+                          "gate_proj", "experts", "lm_head"})
+
+
 def lm_param_specs(params: Any, tp_axis: Optional[str]) -> Any:
     """PartitionSpec pytree for a TransformerLM param (or optimizer-state,
     or gradient) tree under Megatron tensor parallelism."""
+    if tp_axis is not None:
+        found = sorted({getattr(k, "key", None) for path, _ in
+                        jax.tree_util.tree_flatten_with_path(params)[0] for k in path}
+                       & _UNSHARDABLE)
+        if found:
+            raise ValueError(f"tensor parallelism splits the GPT-2-style block's leaves "
+                             f"only; this tree has {found}")
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: _tp_leaf_spec(path, leaf, tp_axis), params)
 
@@ -109,6 +121,10 @@ def make_lm_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
     ``seq_axis`` must agree.
     """
     spec.reject_silent_aux("make_lm_train_step")
+    # the tp/sp step shards the GPT-2-style block's leaves and ties the head
+    from distkeras_tpu.models.transformer import reject_block_features
+
+    reject_block_features(spec.config, "make_lm_train_step (tensor / sequence parallelism)")
     sp_active = sp_axis is not None and sp_axis in mesh.shape and mesh.shape[sp_axis] > 1
     if sp_active and spec.config.get("seq_axis") != sp_axis:
         raise ValueError(

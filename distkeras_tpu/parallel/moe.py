@@ -20,8 +20,11 @@ built for the MXU and ICI:
   moves rows by index: a static-shape scatter builds the slot->token
   map, one gather fills the [E, C, D] slot tensor, one gather + a
   k-term weighted sum combines — zero dispatch matmuls, O((kT + EC)·D)
-  memory traffic, still static shapes for XLA.  Both paths produce
-  bit-identical outputs (parity-tested); ``"auto"`` picks dense only
+  memory traffic, still static shapes for XLA.  Both paths seat the
+  same assignments and agree (parity-tested): bit for bit under top-1,
+  within a few float32 ulps under top-2, where the two-term combine is
+  added in another order than the dense contraction over all slots;
+  ``"auto"`` picks dense only
   below a small-shape threshold where a single fused einsum beats
   gather launch overhead (see :func:`resolve_dispatch_impl`).
 - **Experts live sharded over ``ep``.**  Dispatch is two
@@ -38,6 +41,7 @@ The load-balancing auxiliary loss is the Switch one:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -118,7 +122,7 @@ class MoEMLP(nn.Module):
     router_top_k: int = 1  # 1 = Switch; 2 = GShard-style top-2 gating
     dispatch_impl: str = "auto"  # "dense" | "sorted" | "auto" — see
                                  # resolve_dispatch_impl; same seating
-                                 # either way (bit-parity tested)
+                                 # either way (parity tested)
     compute_dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -258,11 +262,167 @@ class MoEMLP(nn.Module):
             y_tok = jnp.take(padded, dest, axis=0)              # [k, T, D]
             gates_c = gates_rank.astype(self.compute_dtype)     # [k, T]
             # the k-term sum as a contraction (not an explicit mul+add):
-            # XLA lowers it through the same dot/FMA machinery as the
-            # dense combine einsum, which is what keeps the two paths
-            # bit-identical rather than 1-ulp apart under top-2
+            # XLA lowers it through the same dot machinery as the dense
+            # combine einsum; under top-2 the two paths still end an ulp
+            # or two apart (the order of the two-term sum)
             out = jnp.einsum("kt,ktd->td", gates_c, y_tok)
         return out.astype(x.dtype), aux
+
+
+# -- the sigmoid-routed expert layer with a shared expert ---------------------
+#
+# One replica's SHARE of an expert-parallel layer: the router scores every
+# expert of the deployment (``num_experts``), this replica holds experts
+# ``[lo, hi)`` and computes their part; what the experts held elsewhere
+# would add is left out.  No capacity, no drops: every assignment to a held
+# expert is computed, whatever the imbalance.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_tokens(x, order, inverse, k: int):
+    """Rows of ``x`` [T, D] in sorted-assignment order: row ``r`` is token
+    ``order[r] // k`` (assignment ``a = t * k + j`` is token ``t``'s ``j``-th
+    choice).  Its transpose is a gather too (by the inverse permutation, then
+    a sum over each token's ``k`` rows), not a scatter-add."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _gather_tokens_fwd(x, order, inverse, k):
+    return _gather_tokens(x, order, inverse, k), inverse
+
+
+def _gather_tokens_bwd(k, inverse, g):
+    rows = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
+    return jnp.sum(rows.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+
+
+_gather_tokens.defvjp(_gather_tokens_fwd, _gather_tokens_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(y, order, inverse):
+    """Sorted rows back in assignment order (the inverse permutation)."""
+    return jnp.take(y, inverse, axis=0)
+
+
+def _unsort_rows_fwd(y, order, inverse):
+    return _unsort_rows(y, order, inverse), order
+
+
+def _unsort_rows_bwd(order, g):
+    return jnp.take(g, order, axis=0), None, None
+
+
+_unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
+
+
+class HeldExpertsMLP(nn.Module):
+    """Sigmoid top-k router over ``num_experts``, a shared expert, and the
+    experts ``experts_held = [lo, hi)`` this replica holds; tokens [T, D] ->
+    [T, D].
+
+    ``s = sigmoid(float32(x @ W_r))``; ``S = top_k(s + b)`` (the bias ``b``
+    selects only and has no gradient); ``w_e = route_scale * s_e / (sum of
+    the k selected scores + 1e-20)``; ``out = Shared(x) + sum over e in S and
+    held of w_e * Expert_e(x)``, each expert a SwiGLU of width
+    ``hidden_dim``.  The normaliser runs over all k selected scores, held or
+    not.  The router's matmul, the sigmoid and the selection run in float32
+    (``Precision.HIGHEST``: a bfloat16 pass seats near-ties differently).
+
+    No token is dropped and there is no capacity: all ``T * k`` assignments
+    are sorted by held expert (the ones held elsewhere last), their rows
+    gathered into one [T * k, D] buffer, and ``lax.ragged_dot`` multiplies
+    each held expert's group of rows — the groups are as uneven as the
+    router makes them, and the rows past the last group are not computed.
+    The buffer is the static bound ``T * k`` itself, so there is no
+    overflow to handle (537 MB in bfloat16 at 16,384 tokens, top-8, D 2,048;
+    on average one row in ``num_experts / (hi - lo)`` is a held one).
+
+    The layer sows its assignment counts over ALL experts (``moe_counts`` /
+    ``assignments``, int32 [num_experts]) for the training step, which
+    moves the bias with them (:func:`bias_update`, through
+    ``models/transformer.py::routed_step_hook``); no loss term.  It runs one
+    replica's share without its exchange, and that is the only way it runs:
+    the exchange between the replicas of a layer is not built, so the layer
+    has no expert-parallel axis to be given.
+    """
+
+    num_experts: int
+    experts_held: Tuple[int, int]
+    model_dim: int
+    hidden_dim: int
+    top_k: int = 8
+    route_scale: float = 1.0
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        t, d = x.shape
+        e, k, f = self.num_experts, self.top_k, self.hidden_dim
+        lo, hi = (int(v) for v in self.experts_held)
+        if not 0 <= lo < hi <= e:
+            raise ValueError(f"experts_held {self.experts_held} is no range of "
+                             f"the {e} experts")
+        if k > e:
+            raise ValueError(f"top_k {k} exceeds num_experts {e}")
+        held_n, cd = hi - lo, self.compute_dtype
+        router = self.param("router", nn.initializers.normal(0.02), (d, e))
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (held_n, d, f))
+        w_up = self.param("w_up", init, (held_n, d, f))
+        w_down = self.param("w_down", init, (held_n, f, d))
+        xc = x.astype(cd)
+
+        with jax.named_scope("moe.route"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST))                   # [T, E]
+            _, choice = lax.top_k(scores + lax.stop_gradient(bias), k)   # [T, k]
+            gates = jnp.take_along_axis(scores, choice, axis=-1)
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+            gates = gates * self.route_scale
+            counts = jnp.sum(choice[:, :, None] == jnp.arange(e, dtype=choice.dtype),
+                             axis=(0, 1), dtype=jnp.int32)           # [E]
+        self.sow("moe_counts", "assignments", counts)
+
+        with jax.named_scope("moe.shared"):
+            g = nn.Dense(f, use_bias=False, dtype=cd, name="shared_gate")(xc)
+            u = nn.Dense(f, use_bias=False, dtype=cd, name="shared_up")(xc)
+            shared = nn.Dense(d, use_bias=False, dtype=cd, name="shared_down")(nn.silu(g) * u)
+
+        with jax.named_scope("moe.dispatch"):
+            local = choice.reshape(-1) - lo                           # [T * k]
+            held = (local >= 0) & (local < held_n)
+            order = jnp.argsort(jnp.where(held, local, held_n), stable=True)
+            inverse = jnp.argsort(order)
+            group_sizes = counts[lo:hi]
+            # rows past the last group belong to experts held elsewhere:
+            # ragged_dot does not compute them, and what it leaves there
+            # must not reach a sum
+            live = (jnp.arange(t * k) < jnp.sum(group_sizes))[:, None]
+            rows = jnp.where(live, _gather_tokens(xc, order, inverse, k), 0)
+
+        with jax.named_scope("moe.experts"):
+            g = lax.ragged_dot(rows, w_gate.astype(cd), group_sizes)
+            u = lax.ragged_dot(rows, w_up.astype(cd), group_sizes)
+            act = jnp.where(live, nn.silu(g) * u, 0)
+            y = jnp.where(live, lax.ragged_dot(act, w_down.astype(cd), group_sizes), 0)
+
+        with jax.named_scope("moe.combine"):
+            per_choice = _unsort_rows(y, order, inverse).reshape(t, k, d)
+            w = jnp.where(held.reshape(t, k), gates, 0.0)
+            routed = jnp.sum(per_choice.astype(jnp.float32) * w[:, :, None], axis=1)
+        return (shared + routed.astype(cd)).astype(x.dtype)
+
+
+def bias_update(bias, counts, coeff: float):
+    """The selection bias after one optimizer step: ``delta = coeff *
+    sign(mean(c) - c)``, ``b <- b + delta - mean(delta)`` with ``c`` the
+    step's assignment counts over all experts."""
+    c = counts.astype(jnp.float32)
+    delta = coeff * jnp.sign(jnp.mean(c) - c)
+    return bias + (delta - jnp.mean(delta)).astype(bias.dtype)
 
 
 @register_model("moe_mlp_classifier")
@@ -283,6 +443,10 @@ class MoEClassifier(nn.Module):
     ep_size: int = 1
     router_top_k: int = 1
     dispatch_impl: str = "auto"
+
+    @staticmethod
+    def sown_collections(config) -> tuple:
+        return ("aux_loss", "router_stats")
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -409,7 +573,6 @@ def _make_moe_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
                               "dispatch_flops_pct"):
                 if stat_name in stats:
                     obs.gauge(f"moe_{stat_name}").set(float(stats[stat_name]))
-            obs.counter("moe_steps_total").inc()
         return out
 
     return step_with_telemetry

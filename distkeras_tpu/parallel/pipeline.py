@@ -137,6 +137,10 @@ def make_pp_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
     if spec.config.get("moe_experts"):
         raise ValueError("MoE FFN does not compose with pipeline parallelism "
                          "(v1); use make_moe_lm_train_step or a dense spec")
+    # the stages apply ONE block module to a stacked slab of layers
+    from distkeras_tpu.models.transformer import reject_block_features
+
+    reject_block_features(spec.config, "pipeline parallelism (make_pp_train_step)")
     if schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"schedule must be 'gpipe' or '1f1b', got {schedule!r}")
     pp = mesh.shape[pp_axis]

@@ -66,6 +66,7 @@ def make_zero_train_step(spec: ModelSpec, loss: Callable,
        (or use the replicated trainers).
     """
     spec.reject_silent_aux("make_zero_train_step")
+    spec.reject_step_hook("make_zero_train_step")
     spec.reject_rng_spec("make_zero_train_step")
     apply_fn = spec.apply_fn()
     n = mesh.shape[axis]

@@ -627,6 +627,7 @@ class AsyncDistributedTrainer(Trainer):
     def train(self, dataset: Dataset, shuffle: bool = True, checkpointer=None,
               validation_data: Optional[Dataset] = None) -> Model:
         self.model.spec.reject_rng_spec(type(self).__name__ + ".train")
+        self.model.spec.reject_step_hook(type(self).__name__ + ".train")
         if validation_data is not None:
             raise ValueError(
                 "per-epoch validation is not supported for async trainers "

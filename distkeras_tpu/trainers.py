@@ -315,6 +315,7 @@ class SingleTrainer(Trainer):
         of its kwargs (``patience``/``min_delta``/``monitor``/
         ``restore_best``) — Keras-EarlyStopping semantics over the per-epoch
         validation metrics (requires ``validation_data=``)."""
+        self.model.spec.reject_step_hook("SingleTrainer.train")
         self.record_training_start()
         stopper = self._early_stopper(early_stopping)
         if stopper is not None and validation_data is None:

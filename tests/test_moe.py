@@ -112,7 +112,7 @@ def test_moe_train_step_learns_dp_ep():
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0] * 0.5, losses[:3] + losses[-3:]
     # router observability comes back with every step
-    assert set(stats) == {"dropped_fraction", "max_expert_load"}
+    assert set(stats) == {"dropped_fraction", "max_expert_load", "dispatch_flops_pct"}
     assert 0.0 <= float(stats["dropped_fraction"]) <= 1.0
     assert float(stats["max_expert_load"]) >= 0.0
 
@@ -194,15 +194,25 @@ def test_top2_expert_parallel_matches_single_device(tokens_and_params):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
+def _ulps(values, n: int) -> float:
+    """``n`` float32 ulps at the largest magnitude among ``values``."""
+    return n * float(np.spacing(np.float32(np.max(np.abs(np.asarray(values))))))
+
+
 @pytest.mark.parametrize("top_k", [1, 2])
 @pytest.mark.parametrize("cap", [5, T])  # 5: heavy drops; T: no drops
 def test_sorted_dispatch_bit_parity(top_k, cap):
-    """The sorted (scatter/gather) dispatch must be BIT-identical to the
-    dense one-hot einsums — outputs, aux loss, and gradients — for both
-    routing modes and capacities with and without drops.  Parity by
-    construction: the two impls share the seating computation and differ
-    only in how rows move; the combine contraction runs through the same
-    dot/FMA machinery on both sides."""
+    """The sorted (scatter/gather) dispatch must agree with the dense
+    one-hot einsums — outputs, aux loss, and gradients — for both routing
+    modes and capacities with and without drops.  Parity by construction:
+    the two impls share the seating computation and differ only in how rows
+    move.  Under top-1 the outputs are bit-identical.  Under top-2 the
+    combine adds two weighted rows, and the dense path's contraction over
+    the [E, C] slots (all but two of them zeros) may add them in another
+    order or through an FMA than the sorted path's two-term contraction:
+    float32 round-off of one or two ulps (3e-8 read here at values of order
+    0.3).  The tolerance is four ulps of the largest output; a wrong
+    seating, weight or row moves the output by its own size."""
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.normal(size=(24, D)), dtype=jnp.float32)
 
@@ -215,7 +225,11 @@ def test_sorted_dispatch_bit_parity(top_k, cap):
     params = dense.init(jax.random.PRNGKey(top_k), x)["params"]
     out_d, aux_d = dense.apply({"params": params}, x)
     out_s, aux_s = srt.apply({"params": params}, x)
-    np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_d))
+    if top_k == 1:
+        np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_d))
+    else:
+        np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_d), rtol=0,
+                                   atol=_ulps(out_d, 4))
     assert float(aux_s) == float(aux_d)
 
     def loss(p, mod):
@@ -274,8 +288,10 @@ def test_sorted_expert_parallel_matches_dense_single_device(tokens_and_params):
 
 @pytest.mark.parametrize("top_k", [1, 2])
 def test_sorted_ep4_bit_matches_dense_ep4(top_k):
-    """ep=4 sorted == ep=4 dense BIT-for-bit (same sharding, same seating,
-    only the row movement differs — k=1 and k=2)."""
+    """ep=4 sorted == ep=4 dense (same sharding, same seating, only the row
+    movement differs): bit for bit at k=1, within four ulps of the largest
+    output at k=2, where the two-term combine's order of addition differs
+    (see ``test_sorted_dispatch_bit_parity``)."""
     if not hasattr(jax, "shard_map"):
         pytest.skip("jax.shard_map unavailable in this environment")
     rng = np.random.default_rng(13)
@@ -306,7 +322,10 @@ def test_sorted_ep4_bit_matches_dense_ep4(top_k):
         outs.append(np.asarray(sharded(
             jax.device_put(params, psh),
             jax.device_put(x, NamedSharding(mesh, P("ep"))))))
-    np.testing.assert_array_equal(outs[0], outs[1])
+    if top_k == 1:
+        np.testing.assert_array_equal(outs[0], outs[1])
+    else:
+        np.testing.assert_allclose(outs[1], outs[0], rtol=0, atol=_ulps(outs[0], 4))
 
 
 def test_resolve_dispatch_impl_and_flops():
