@@ -95,6 +95,7 @@ TELEMETRY_NAMES = frozenset({
     # back (models/transformer.py::routed_step_hook.publish)
     "moe_assignments_total", "moe_assignments_held_total",
     "moe_expert_load_max_over_mean",
+    "moe_layer_calls_total", "moe_layer_calls_full_total",
     # device-side jax.named_scope names (models/transformer.py,
     # parallel/moe.py): op_name metadata of the compiled program, mapped
     # back to a trace's device events by obs.device_scopes

@@ -21,6 +21,7 @@ see ``parallel/lm.py :: lm_param_specs``.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import flax.linen as nn
@@ -501,6 +502,20 @@ class TransformerLM(nn.Module):
         return self.head(self._trunk(tokens, pos_offset))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RoutedStats:
+    """What the expert layers of one forward hand the training step, a row a
+    layer; the window program sums it over its steps and replicas.  Read as
+    an array it is the counts, which is all the bias rule asks for."""
+
+    counts: jax.Array    # [expert layers, routed_experts] int32: assignments
+    calls: jax.Array     # [expert layers, 2] int32: calls, and those over all T * k rows
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.counts, dtype=dtype)
+
+
 def _expert_blocks(tree) -> list:
     """Names of the blocks that carry the routed expert layer, in layer order."""
     return sorted((k for k, v in tree.items()
@@ -510,12 +525,11 @@ def _expert_blocks(tree) -> list:
 
 def routed_step_hook(spec: ModelSpec) -> StepHook:
     """The step hook of a ``transformer_lm`` spec with ``routed_experts``:
-    the forward hands back every expert layer's assignment counts
-    ([expert layers, routed_experts] int32), and after each optimizer step
-    the selection bias of each such layer moves by
-    ``parallel/moe.py::bias_update`` (``route_balance_coeff``).  The bias is
-    a leaf of the parameter tree, so it rides pull and commit like any
-    weight."""
+    the forward hands back what every expert layer sowed (:class:`RoutedStats`),
+    and after each optimizer step the selection bias of each such layer moves
+    with its counts by ``parallel/moe.py::bias_update``
+    (``route_balance_coeff``).  The bias is a leaf of the parameter tree, so
+    it rides pull and commit like any weight."""
     from distkeras_tpu.parallel.moe import bias_update
 
     module = spec.build()
@@ -525,11 +539,11 @@ def routed_step_hook(spec: ModelSpec) -> StepHook:
 
     def apply(params, x):
         out, sown = module.apply({"params": params}, x, mutable=["moe_counts"])
-        counts = sown["moe_counts"]
-        return out, jnp.stack([counts[b]["experts"]["assignments"][0]
-                               for b in _expert_blocks(counts)])
+        sown = [sown["moe_counts"][b]["experts"] for b in _expert_blocks(sown["moe_counts"])]
+        return out, RoutedStats(jnp.stack([s["assignments"][0] for s in sown]),
+                                jnp.stack([s["calls"][0] for s in sown]))
 
-    def update(params, counts):
+    def update(params, stats):
         if not coeff:
             return params
         params = dict(params)
@@ -537,16 +551,20 @@ def routed_step_hook(spec: ModelSpec) -> StepHook:
             for n, name in enumerate(_expert_blocks(params)):
                 block = dict(params[name])
                 experts = dict(block["experts"])
-                experts["router_bias"] = bias_update(experts["router_bias"], counts[n], coeff)
+                experts["router_bias"] = bias_update(experts["router_bias"],
+                                                     stats.counts[n], coeff)
                 block["experts"] = experts
                 params[name] = block
         return params
 
-    def publish(counts) -> None:
-        counts = np.asarray(counts, dtype=np.int64).reshape(-1, e)   # a row a layer a window
+    def publish(stats) -> None:
+        counts = np.asarray(stats.counts, dtype=np.int64).reshape(-1, e)   # a row a layer a window
         held = counts[:, lo:hi]
         obs.counter("moe_assignments_total").inc(int(counts.sum()))
         obs.counter("moe_assignments_held_total").inc(int(held.sum()))
+        calls = np.asarray(stats.calls, dtype=np.int64).reshape(-1, 2).sum(axis=0)
+        obs.counter("moe_layer_calls_total").inc(int(calls[0]))
+        obs.counter("moe_layer_calls_full_total").inc(int(calls[1]))
         load = held.sum(axis=0)
         if load.sum():
             obs.gauge("moe_expert_load_max_over_mean").set(float(load.max() / load.mean()))

@@ -396,7 +396,7 @@ class WindowEngine:
                     losses, stats = losses
                 losses = np.asarray(losses)
         if telemetry and self._hook is not None:
-            self._hook.publish(np.asarray(stats))
+            self._hook.publish(jax.tree.map(np.asarray, stats))
         if telemetry:
             # identity as labels (ARCHITECTURE.md convention): a process
             # with several engines (bench legs, elastic rebuilds) must not

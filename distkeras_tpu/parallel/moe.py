@@ -42,7 +42,7 @@ The load-balancing auxiliary loss is the Switch one:
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -316,6 +316,211 @@ def _unsort_rows_bwd(order, g):
 _unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 
 
+def _up(rows, w_gate, w_up, group_sizes):
+    """Each held expert's two up-projections over its group of sorted rows."""
+    return (lax.ragged_dot(rows, w_gate, group_sizes),
+            lax.ragged_dot(rows, w_up, group_sizes))
+
+
+def _down(g, u, w_down, live, group_sizes):
+    """SwiGLU's product and each held expert's down-projection.  Rows past
+    the last group belong to no held expert: ``ragged_dot`` does not compute
+    them, and what it leaves there must not reach a sum."""
+    act = jnp.where(live, nn.silu(g) * u, 0)
+    return jnp.where(live, lax.ragged_dot(act, w_down, group_sizes), 0)
+
+
+def _routed_full(k, xc, w, w_gate, w_up, w_down, order, inverse, group_sizes):
+    """The held experts' part of the output over ALL ``T * k`` sorted rows:
+    the only static bound no step can overflow.  ``w`` [T, k] float32 is
+    each choice's weight, 0 where the expert is held elsewhere."""
+    t, d = xc.shape
+    with jax.named_scope("moe.dispatch"):
+        live = (jnp.arange(t * k) < jnp.sum(group_sizes))[:, None]
+        rows = jnp.where(live, _gather_tokens(xc, order, inverse, k), 0)
+    with jax.named_scope("moe.experts"):
+        y = _down(*_up(rows, w_gate, w_up, group_sizes), w_down, live, group_sizes)
+    with jax.named_scope("moe.combine"):
+        per_choice = _unsort_rows(y, order, inverse).reshape(t, k, d)
+        routed = jnp.sum(per_choice.astype(jnp.float32) * w[:, :, None], axis=1)
+    return routed.astype(xc.dtype)
+
+
+# -- the same over a bounded number of rows ------------------------------------
+#
+# The stable sort puts the held assignments first, so while a step's held
+# assignments number at most ``R`` they are all among ``order[:R]``: the
+# gather, the experts and the masks run on [R, .] buffers.  Bringing R sorted
+# rows back to T tokens is a sum over each token's held choices (0 to k of
+# them), and a scatter-add of rows is slow on the chip; here it is one grouped
+# matmul.  The R rows are put in token order (a sort of R keys and one gather
+# of R rows), tokens are cut into tiles of ``_TOKEN_TILE``, and a tile's
+# output is ``onehot^T @ rows`` over that tile's rows alone: ``onehot`` [rows,
+# _TOKEN_TILE] places a row at its token's slot in the tile, at the row's gate
+# weight.  The same product without weights transposes the gather, so no
+# gradient is a scatter-add either.
+
+# How far the bounded path's buffers reach above the balanced share of a
+# step's assignments, ``T * k * held / num_experts``.  Measured held shares
+# at 16 of 128 experts were 10.5-13.2% against 12.5% balanced (PERF.md,
+# PR 28), so twice the balanced share leaves a whole share of room; a step
+# over it takes the full-size path and loses nothing but time.
+_ROW_BOUND_OVER_BALANCED = 2
+_ROW_BOUND_MULTIPLE = 512       # the bound is rounded up to whole row tiles
+_TOKEN_TILE = 256
+
+
+def held_row_bound(t: int, k: int, held_n: int, num_experts: int) -> int:
+    """Rows the bounded path holds for ``t`` tokens of ``k`` choices where
+    ``held_n`` of ``num_experts`` experts are held; ``t * k`` where the
+    bound reaches it (half the experts or more held): no bounded path then."""
+    rows = -(-_ROW_BOUND_OVER_BALANCED * t * k * held_n // num_experts)
+    return min(-(-rows // _ROW_BOUND_MULTIPLE) * _ROW_BOUND_MULTIPLE, t * k)
+
+
+def _take(table, index):
+    """``table[index]`` along the first axis for indices that are in range by
+    construction: clamped, where ``jnp.take`` would follow its gather with a
+    pass that fills what was out of range."""
+    return jnp.take(table, index, axis=0, mode="clip")
+
+
+class _RowPlan(NamedTuple):
+    """Where the first ``R`` sorted rows come from and go to; integers only."""
+
+    token: jnp.ndarray       # [R] the token of sorted row r
+    live: jnp.ndarray        # [R, 1] whether row r is a held assignment
+    by_token: jnp.ndarray    # [R] the sorted rows' indices in token order, live ones first
+    slot: jnp.ndarray        # [R] in that order, the token's place in its tile
+    tile_sizes: jnp.ndarray  # [tiles] live rows of each tile of _TOKEN_TILE tokens
+
+
+def _row_plan(order, group_sizes, bound: int, t: int, k: int) -> _RowPlan:
+    head = order[:bound]
+    live = jnp.arange(bound) < jnp.sum(group_sizes)
+    # assignment ids ascend with the token: sorting them is sorting by token
+    in_order, by_token = lax.sort_key_val(jnp.where(live, head, t * k),
+                                          jnp.arange(bound, dtype=head.dtype))
+    tiles = -(-t // _TOKEN_TILE)
+    tile = jnp.where(live, head // (k * _TOKEN_TILE), tiles)
+    tile_sizes = jnp.sum(tile[:, None] == jnp.arange(tiles, dtype=tile.dtype),
+                         axis=0, dtype=jnp.int32)
+    return _RowPlan(head // k, live[:, None], by_token, in_order // k % _TOKEN_TILE,
+                    tile_sizes)
+
+
+def _rows_to_tokens(rows, weight, plan: _RowPlan, t: int):
+    """``out[token] = sum over the token's live rows of weight * row``, the
+    products and sums in float32: [R, D] -> [T, D] in ``rows.dtype``.
+    ``weight`` [R] float32, or ``None`` for 1.  A float32 weight is split
+    into as many addends of ``rows.dtype`` as hold its mantissa, one block of
+    slots each, so that the matmul's operands stay in the compute dtype and
+    the products are exact."""
+    dtype, tiles = rows.dtype, plan.tile_sizes.shape[0]
+    ordered = _take(rows, plan.by_token)
+    onehot = plan.slot[:, None] == jnp.arange(_TOKEN_TILE, dtype=plan.slot.dtype)
+    if weight is None:
+        places = [onehot.astype(dtype)]
+    else:
+        rest, places = _take(weight, plan.by_token), []
+        for _ in range(1 if dtype == jnp.float32 else 3):
+            part = rest.astype(dtype)
+            places.append(jnp.where(onehot, part[:, None], 0))
+            rest = rest - part.astype(jnp.float32)
+    dims = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    out = lax.ragged_dot_general(jnp.concatenate(places, axis=1), ordered, plan.tile_sizes,
+                                 dims, preferred_element_type=jnp.float32)
+    out = jnp.sum(out.reshape(tiles, len(places), _TOKEN_TILE, -1), axis=1)
+    return out.reshape(tiles * _TOKEN_TILE, -1)[:t].astype(dtype)
+
+
+def _bounded(xc, w, w_gate, w_up, w_down, order, inverse, group_sizes, plan: _RowPlan):
+    """:func:`_routed_full` for a step whose held assignments number at most
+    ``R``: the same groups through the same ``ragged_dot``s, on [R, .].
+    Beside the output, what :func:`_bounded_pull` needs of it.  A row that is
+    not live holds its token's row all the same, which no group reads, and
+    belongs to a choice held elsewhere: its weight is 0."""
+    with jax.named_scope("moe.dispatch"):
+        rows = _take(xc, plan.token)
+    with jax.named_scope("moe.experts"):
+        g, u = _up(rows, w_gate, w_up, group_sizes)
+        y = _down(g, u, w_down, plan.live, group_sizes)
+    with jax.named_scope("moe.combine"):
+        weight = _take(w.reshape(-1), order[:plan.token.shape[0]])
+        out = _rows_to_tokens(y, weight, plan, xc.shape[0])
+    return out, (rows, g, u, y, weight)
+
+
+def _bounded_pull(kept, xc, w, w_gate, w_up, w_down, order, inverse, group_sizes,
+                  plan: _RowPlan, g_out):
+    """The gradients of :func:`_bounded`'s output into its first five
+    arguments.  Every transpose of a gather is a gather: a row's gradient is
+    its token's, and the tokens' is :func:`_rows_to_tokens` again."""
+    rows, g, u, y, weight = kept
+    bound = plan.token.shape[0]
+    with jax.named_scope("moe.combine"):
+        g_rows = _take(g_out, plan.token).astype(jnp.float32)
+        d_y = (g_rows * weight[:, None]).astype(y.dtype)
+        d_weight = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)
+        d_w = jnp.where(inverse < bound, _take(d_weight, inverse), 0).reshape(w.shape)
+    with jax.named_scope("moe.experts"):
+        # the stages' outputs are in ``kept``: of these two only the pullbacks run
+        d_g, d_u, d_down = jax.vjp(
+            lambda g, u, w_down: _down(g, u, w_down, plan.live, group_sizes),
+            g, u, w_down)[1](d_y)
+        d_rows, d_gate, d_up = jax.vjp(
+            lambda rows, w_gate, w_up: _up(rows, w_gate, w_up, group_sizes),
+            rows, w_gate, w_up)[1]((d_g, d_u))
+    with jax.named_scope("moe.dispatch"):
+        d_x = _rows_to_tokens(jnp.where(plan.live, d_rows, 0), None, plan, xc.shape[0])
+    return d_x, d_w, d_gate, d_up, d_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(k, over, xc, w, w_gate, w_up, w_down, order, inverse, group_sizes, plan):
+    """The bounded path, or the full-size one where ``over`` says that this
+    step's held assignments exceed the bound.  One conditional forward and
+    one backward, each written out: differentiating THROUGH a ``lax.cond``
+    would make it hand back every branch's residuals, zero-filled [T * k, .]
+    buffers for the branch not taken, which is the traffic the bound removes.
+    Here the residuals are the inputs and the bounded path's [R, .] buffers
+    (blank after a full-size forward, whose backward branch recomputes its
+    forward): a recomputed block runs the experts once more, not twice."""
+    return lax.cond(over,
+                    lambda *a: _routed_full(k, *a[:-1]),
+                    lambda *a: _bounded(*a)[0],
+                    xc, w, w_gate, w_up, w_down, order, inverse, group_sizes, plan)
+
+
+def _routed_fwd(k, over, *inputs):
+    def blank_like(a):       # under shard_map as varying as what the other branch keeps
+        zeros = jnp.zeros(a.shape, a.dtype)
+        return lax.pcast(zeros, tuple(a.vma), to="varying") if a.vma else zeros
+
+    blank = jax.tree.map(blank_like, jax.eval_shape(_bounded, *inputs)[1])
+    out, kept = lax.cond(over,
+                         lambda *a: (_routed_full(k, *a[:-1]), blank),
+                         _bounded,
+                         *inputs)
+    return out, (over, inputs, kept)
+
+
+def _routed_bwd(k, res, g_out):
+    over, (*diff, order, inverse, group_sizes, plan), kept = res
+    ints = (order, inverse, group_sizes)
+    grads = lax.cond(
+        over,
+        lambda *d: jax.vjp(lambda *d: _routed_full(k, *d, *ints), *d)[1](g_out),
+        lambda *d: _bounded_pull(kept, *d, *ints, plan, g_out),
+        *diff)
+    return (None, *grads, None, None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 class HeldExpertsMLP(nn.Module):
     """Sigmoid top-k router over ``num_experts``, a shared expert, and the
     experts ``experts_held = [lo, hi)`` this replica holds; tokens [T, D] ->
@@ -331,16 +536,22 @@ class HeldExpertsMLP(nn.Module):
 
     No token is dropped and there is no capacity: all ``T * k`` assignments
     are sorted by held expert (the ones held elsewhere last), their rows
-    gathered into one [T * k, D] buffer, and ``lax.ragged_dot`` multiplies
-    each held expert's group of rows — the groups are as uneven as the
-    router makes them, and the rows past the last group are not computed.
-    The buffer is the static bound ``T * k`` itself, so there is no
-    overflow to handle (537 MB in bfloat16 at 16,384 tokens, top-8, D 2,048;
-    on average one row in ``num_experts / (hi - lo)`` is a held one).
+    gathered into one buffer, and ``lax.ragged_dot`` multiplies each held
+    expert's group of rows — the groups are as uneven as the router makes
+    them, and the rows past the last group are not computed.  The buffer has
+    ``R = held_row_bound(...)`` rows: twice the balanced share of the
+    assignments, ``2 * T * k * (hi - lo) / num_experts`` (32,768 of 131,072
+    at 16,384 tokens, top-8, 16 of 128 held), which the layer computes from
+    its own shapes.  A step whose held assignments exceed ``R`` takes the
+    same arithmetic over all ``T * k`` rows, the one static bound that
+    cannot overflow (537 MB a buffer in bfloat16 at those sizes), behind one
+    conditional; a replica that holds half the experts or more has ``R = T *
+    k`` and no conditional.  Both paths compute every held assignment.
 
-    The layer sows its assignment counts over ALL experts (``moe_counts`` /
-    ``assignments``, int32 [num_experts]) for the training step, which
-    moves the bias with them (:func:`bias_update`, through
+    The layer sows (collection ``moe_counts``) its assignment counts over
+    ALL experts (``assignments``, int32 [num_experts]) and ``calls``, int32
+    [2]: 1, and whether this call ran over all ``T * k`` rows.  The training
+    step moves the bias with the counts (:func:`bias_update`, through
     ``models/transformer.py::routed_step_hook``); no loss term.  It runs one
     replica's share without its exchange, and that is the only way it runs:
     the exchange between the replicas of a layer is not built, so the layer
@@ -391,29 +602,24 @@ class HeldExpertsMLP(nn.Module):
             u = nn.Dense(f, use_bias=False, dtype=cd, name="shared_up")(xc)
             shared = nn.Dense(d, use_bias=False, dtype=cd, name="shared_down")(nn.silu(g) * u)
 
+        bound = held_row_bound(t, k, held_n, e)
         with jax.named_scope("moe.dispatch"):
             local = choice.reshape(-1) - lo                           # [T * k]
             held = (local >= 0) & (local < held_n)
             order = jnp.argsort(jnp.where(held, local, held_n), stable=True)
             inverse = jnp.argsort(order)
             group_sizes = counts[lo:hi]
-            # rows past the last group belong to experts held elsewhere:
-            # ragged_dot does not compute them, and what it leaves there
-            # must not reach a sum
-            live = (jnp.arange(t * k) < jnp.sum(group_sizes))[:, None]
-            rows = jnp.where(live, _gather_tokens(xc, order, inverse, k), 0)
-
-        with jax.named_scope("moe.experts"):
-            g = lax.ragged_dot(rows, w_gate.astype(cd), group_sizes)
-            u = lax.ragged_dot(rows, w_up.astype(cd), group_sizes)
-            act = jnp.where(live, nn.silu(g) * u, 0)
-            y = jnp.where(live, lax.ragged_dot(act, w_down.astype(cd), group_sizes), 0)
-
-        with jax.named_scope("moe.combine"):
-            per_choice = _unsort_rows(y, order, inverse).reshape(t, k, d)
             w = jnp.where(held.reshape(t, k), gates, 0.0)
-            routed = jnp.sum(per_choice.astype(jnp.float32) * w[:, :, None], axis=1)
-        return (shared + routed.astype(cd)).astype(x.dtype)
+            plan = _row_plan(order, group_sizes, bound, t, k) if bound < t * k else None
+        inputs = (xc, w, w_gate.astype(cd), w_up.astype(cd), w_down.astype(cd),
+                  order, inverse, group_sizes)
+        if plan is None:
+            over, routed = True, _routed_full(k, *inputs)
+        else:
+            over = jnp.sum(group_sizes) > bound
+            routed = _routed(k, over, *inputs, plan)
+        self.sow("moe_counts", "calls", jnp.stack([jnp.int32(1), jnp.asarray(over, jnp.int32)]))
+        return (shared + routed).astype(x.dtype)
 
 
 def bias_update(bias, counts, coeff: float):

@@ -217,8 +217,8 @@ def test_paths_that_cannot_run_the_new_block_refuse_it_by_name(path, spec, names
 
 
 def test_step_hook_moves_the_bias_after_the_optimizer():
-    """``make_minibatch_step(hook=)``: the step hands back the counts beside
-    the loss, and the bias leaf moves by the rule, summing to zero."""
+    """``make_minibatch_step(hook=)``: the step hands back the layers' stats
+    beside the loss, and the bias leaf moves by the rule, summing to zero."""
     from distkeras_tpu.ops.losses import get_loss
     from distkeras_tpu.parallel.engine import make_minibatch_step
 
@@ -228,10 +228,13 @@ def test_step_hook_moves_the_bias_after_the_optimizer():
     step = make_minibatch_step(spec.apply_fn(), get_loss("sparse_categorical_crossentropy"),
                                sgd, hook=spec.step_hook())
     xs = jax.random.randint(jax.random.PRNGKey(0), (3, 2, 16), 0, 64)
-    (new, _), (losses, counts) = jax.lax.scan(step, (params, sgd.init(params)), (xs, xs))
+    (new, _), (losses, stats) = jax.lax.scan(step, (params, sgd.init(params)), (xs, xs))
     assert losses.shape == (3,) and losses.dtype == jnp.float32
     assert np.isfinite(np.asarray(losses)).all()
-    assert counts.shape == (3, 1, 4) and int(counts.sum()) == 3 * 32 * 2
+    assert stats.counts.shape == (3, 1, 4) and int(stats.counts.sum()) == 3 * 32 * 2
+    # half the experts held: the bound is tokens x top-k, every call runs over all rows
+    assert np.asarray(stats.calls).tolist() == [[[1, 1]]] * 3
+    assert np.array_equal(np.asarray(stats), np.asarray(stats.counts))
     bias = np.asarray(new["block_1"]["experts"]["router_bias"])
     assert np.abs(bias).max() > 0 and abs(bias.sum()) < 1e-6
 
