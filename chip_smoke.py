@@ -51,10 +51,10 @@ import numpy as np
 
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# The widest model the repo runs (bench.py ``_LM_LEGS`` row 4): nothing is
-# cut.  If HBM or host RAM ever forces a cut, cut whole layers HERE — never
-# a width, the head size, the sequence or the vocabulary; the depth used is
-# printed with the phases and carried in the summary.
+# The bring-up check's LM, at these widths as written: nothing is cut.  If
+# HBM or host RAM ever forces a cut, cut whole layers HERE — never a width,
+# the head size, the sequence or the vocabulary; the depth used is printed
+# with the phases and carried in the summary.
 LM = dict(vocab_size=8192, model_dim=1024, num_heads=8, num_layers=16,
           max_seq_len=2048)
 LM_BATCH = 4     # per replica / per worker
@@ -259,8 +259,8 @@ def _live_leaf_devices(shape) -> set:
 def _async_run(cls, model0, ds, workers: int, batch: int, window: int,
                windows: int, runs: int = 1, **kw):
     """``runs`` trainings of ``cls`` on ONE trainer instance, each from
-    ``model0`` (the bench's methodology: the window program is cached per
-    instance, so the first run compiles and the next is steady).  Checks
+    ``model0`` (the window program is cached per instance, so the first
+    run compiles and the next is steady).  Checks
     what every run must satisfy; returns ``(trainer, centers, seconds)``."""
     import jax
 

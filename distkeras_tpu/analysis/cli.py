@@ -70,8 +70,7 @@ def run_all(root: Optional[str] = None,
     pkg_sources = hub_sources = None
     if any(n in names for n in ("wire-parity", "telemetry", "lock-order",
                                 "blocking", "guarded-by", "protocol")):
-        pkg_sources = load_sources(python_files(root, ("distkeras_tpu",),
-                                                extra=("bench.py",)))
+        pkg_sources = load_sources(python_files(root, ("distkeras_tpu",)))
         hub_paths = set(python_files(root, lock_order.DEFAULT_SUBDIRS))
         hub_sources = {p: s for p, s in pkg_sources.items()
                        if p in hub_paths}

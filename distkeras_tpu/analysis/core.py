@@ -153,11 +153,10 @@ def apply_annotations(findings: Sequence[Finding], sources: Dict[str, SourceFile
     return out
 
 
-def python_files(root: str, subdirs: Sequence[str] = ("distkeras_tpu",),
-                 extra: Sequence[str] = ()) -> List[str]:
+def python_files(root: str,
+                 subdirs: Sequence[str] = ("distkeras_tpu",)) -> List[str]:
     """All ``.py`` files under ``root``'s ``subdirs`` (recursive, sorted,
-    ``__pycache__`` skipped) plus any ``extra`` root-relative files that
-    exist."""
+    ``__pycache__`` skipped)."""
     out: List[str] = []
     for sub in subdirs:
         top = os.path.join(root, sub)
@@ -165,10 +164,6 @@ def python_files(root: str, subdirs: Sequence[str] = ("distkeras_tpu",),
             dirnames[:] = sorted(d for d in dirnames if d != "__pycache__")
             out.extend(os.path.join(dirpath, f) for f in sorted(filenames)
                        if f.endswith(".py"))
-    for name in extra:
-        p = os.path.join(root, name)
-        if os.path.exists(p):
-            out.append(p)
     return out
 
 

@@ -6,8 +6,8 @@ telemetry_registry.TELEMETRY_NAMES`.  Two collectors:
 
 - **call sites**: the first string argument of every
   ``counter``/``gauge``/``histogram``/``span``/``phase``/``start_span``/
-  ``record_span`` call in the package (and ``bench.py``) — covers every
-  direct emission regardless of namespace;
+  ``record_span`` call in the package — covers every direct
+  emission regardless of namespace;
 - **namespace sweep**: every string literal shaped like a project
   telemetry name (``ps_*``, ``ps.*``, ``worker.*``, ``health.*``) in the
   package and in ``native/*.cpp`` — covers indirect tables such as
@@ -136,8 +136,7 @@ def run(root: Optional[str] = None,
         sources: Optional[Dict[str, SourceFile]] = None) -> List[Finding]:
     root = root or repo_root()
     if sources is None:
-        sources = load_sources(python_files(root, ("distkeras_tpu",),
-                                            extra=("bench.py",)))
+        sources = load_sources(python_files(root, ("distkeras_tpu",)))
     cpp_files: Dict[str, str] = {}
     for path in sorted(glob.glob(os.path.join(root, "native", "*.cpp"))):
         with open(path, encoding="utf-8") as f:

@@ -25,9 +25,7 @@ TELEMETRY_NAMES = frozenset({
     "ps_commits_total", "ps_pulls_total",
     "ps_commit_bytes_total", "ps_pull_bytes_total",
     "ps_fenced_commits_total", "ps_idle_evictions_total",
-    "ps_commit_log_dropped_total",
     "ps_live_workers", "ps_staleness", "ps_commit_staleness",
-    "ps_rpc_seconds",
     "ps_snapshots_total", "ps_snapshot_sets_total",
     # replication / HA
     "ps_replicas_attached_total", "ps_replicas_connected",
@@ -57,25 +55,20 @@ TELEMETRY_NAMES = frozenset({
     "ps.sparse_hot_rows",
     "ps_sparse_cache_hits_total", "ps_sparse_cache_misses_total",
     "ps.repl_sparse_bytes_saved",
-    # self-scaling fleet + multi-job admission (ISSUE 19): controller
-    # decisions, job namespace admission verdicts, live job count
+    # self-scaling fleet (ISSUE 19): controller decisions
     "ps_fleet_spawns_total", "ps_fleet_retires_total",
     "ps_fleet_preemptions_total", "ps_fleet_target_size",
-    "ps_jobs_admitted_total", "ps_jobs_rejected_total", "ps_active_jobs",
     # -- worker / health planes ------------------------------------------------
     "worker.restarts", "worker.preemptions",
     "health.event",
     # -- transport -------------------------------------------------------------
     "net_tx_frames_total", "net_tx_bytes_total",
-    "net_rx_frames_total", "net_rx_bytes_total",
     # zero-copy shm transport + batched receive (ISSUE 18): frames moved
     # over shared-memory rings, producer parks on a full ring, and the
     # frames-per-syscall-batch histogram of the hub's batched receive
     "ps.shm_frames_total", "ps.shm_ring_full_waits", "ps_recv_batch_depth",
     # -- trainer / engine / data planes ----------------------------------------
-    "trainer_epochs_total", "trainer_epoch_seconds",
-    "trainer_samples_total", "trainer_samples_per_sec_per_chip",
-    "trainer_window_loss", "trainer.epoch",
+    "trainer_epochs_total", "trainer_window_loss", "trainer.epoch",
     "engine_epoch_seconds", "engine.run_epoch",
     "async_windows_total", "async_window_wall_seconds",
     "async_window_device_seconds",
@@ -90,7 +83,7 @@ TELEMETRY_NAMES = frozenset({
     # sync plane and the trainer's feed
     "engine.place", "engine.dispatch", "engine.device_wait", "feed.wait",
     "feed_chunk_load_seconds", "feed_queue_depth", "feed_chunks_total",  # producer side
-    "data_loads_total", "data_load_seconds", "data.load",
+    "data.load",
     # the routed expert layer, from the counts the window program hands
     # back (models/transformer.py::routed_step_hook.publish)
     "moe_assignments_total", "moe_assignments_held_total",
@@ -101,5 +94,5 @@ TELEMETRY_NAMES = frozenset({
     # back to a trace's device events by obs.device_scopes
     "attn.sliding", "attn.full", "moe.route", "moe.dispatch", "moe.experts",
     "moe.shared", "moe.combine", "moe.bias",
-    "punchcard_jobs_total", "punchcard.job",
+    "punchcard.job",
 })

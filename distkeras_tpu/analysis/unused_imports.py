@@ -24,7 +24,7 @@ from distkeras_tpu.analysis.core import Finding, SourceFile, rel, repo_root
 #: the sweep's package vocabulary — mirrors the historical parametrized
 #: test cells so scoping can never silently drop a tree
 PACKAGES = ("observability", "runtime", ".", "tests", "data", "parallel",
-            "models", "ops", "examples", "bench", "analysis")
+            "models", "ops", "examples", "analysis")
 
 _NOQA_RE = re.compile(r"#\s*noqa(?!:)|#\s*noqa:[^#]*\bF401\b")
 
@@ -78,9 +78,6 @@ def package_files(root: str, package: str) -> List[str]:
             return []
         return [os.path.join(d, f) for f in sorted(os.listdir(d))
                 if f.endswith(".py")]
-    if package == "bench":
-        p = os.path.join(root, "bench.py")
-        return [p] if os.path.exists(p) else []
     if package == "examples":
         files: List[str] = []
         for d in (os.path.join(root, "distkeras_tpu", "examples"),

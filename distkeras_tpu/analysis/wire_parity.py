@@ -175,8 +175,7 @@ def run(root: Optional[str] = None,
         sources: Optional[Dict[str, SourceFile]] = None) -> List[Finding]:
     root = root or repo_root()
     if sources is None:
-        sources = load_sources(python_files(root, ("distkeras_tpu",),
-                                            extra=("bench.py",)))
+        sources = load_sources(python_files(root, ("distkeras_tpu",)))
     net_path = os.path.join(root, "distkeras_tpu", "runtime", "networking.py")
     ps_path = os.path.join(root, "distkeras_tpu", "runtime",
                            "parameter_server.py")

@@ -14,7 +14,7 @@ of the vocabulary receives ``hot_prob`` of the traffic, the cold tail is
 uniform.  Labels are LEARNABLE, not noise: each id carries a fixed random
 propensity weight and the click probability is the sigmoid of the
 impression's summed weights — so a trained embedding model's loss
-actually falls, and bench/e2e runs exercise real gradients over real row
+actually falls, and end-to-end runs exercise real gradients over real row
 subsets.
 """
 

@@ -20,13 +20,11 @@ from distkeras_tpu import observability as obs
 from distkeras_tpu import utils
 
 # Out-of-core chunk-size budget (bytes of feature data per chunk) for the
-# double-buffered feed.  Promoted from bench.py's ``feed`` chunk_mb sweep
-# (12/25/49/98 MB legs, ``best_chunk_mb``): the bench re-runs the sweep
-# every capture and uses its own best for the headline comparison, so a
-# platform where a different size wins shows up as a recorded number —
-# re-promote this constant when the sweep moves.  25 MB balances transfer
-# granularity (enough batches per chunk to amortize the per-transfer
-# host cost) against double-buffer residency (2 chunks in flight).
+# double-buffered feed.  25 MB balances transfer granularity (enough
+# batches per chunk to amortize the per-transfer host cost) against
+# double-buffer residency (2 chunks in flight).  No benchmark cell feeds
+# out of core yet (PERF.md section 7, ``lm590m_sync_feed``): re-measure
+# before moving it.
 DEFAULT_CHUNK_BUDGET_BYTES = 25 * 2**20
 
 
@@ -58,9 +56,7 @@ def prefetch_to_device(chunks: Iterator, place: Callable,
     background thread with a one-chunk queue, so host-side IO overlaps
     training too, not just the transfer.  At most two chunks are in
     flight either way, so feeding stays O(chunk) memory — the out-of-core
-    epoch's IO/H2D/compute overlap (SURVEY §7 step 3; round-4 verdict
-    weak #6: the old loop issued synchronous per-chunk transfers with no
-    overlap).
+    epoch's IO/H2D/compute overlap (SURVEY §7 step 3).
 
     Telemetry: the consumer's wait for its next chunk is the leaf phase
     ``feed.wait`` (the trainer's thread blocked on input, once a chunk;

@@ -1,4 +1,4 @@
-"""Dataset loaders for the BASELINE.md measurement matrix.
+"""Dataset loaders for the `BASELINE.json` config matrix.
 
 Reference parity: the reference's examples fed MNIST / CIFAR / Higgs CSVs
 through Spark DataFrames (SURVEY §2.21).  Here loaders produce columnar
@@ -39,7 +39,6 @@ import os
 import pickle
 import struct
 import tarfile
-import time
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -273,9 +272,6 @@ def _load(filename: str, num_classes: int, image_shape: Tuple[int, ...],
             filename, num_classes, image_shape, synthetic_sizes, seed,
             cache_dir, synthetic_fallback, flatten, raw_finder,
             signal_amplitude)
-    if obs.enabled():
-        obs.counter("data_loads_total", dataset=filename,
-                    synthetic=str(bool(info["synthetic"])).lower()).inc()
     return train, test, info
 
 
@@ -284,7 +280,6 @@ def _load_inner(filename: str, num_classes: int, image_shape: Tuple[int, ...],
                 cache_dir: Optional[str], synthetic_fallback: bool,
                 flatten: bool, raw_finder=None,
                 signal_amplitude: float = 7.0) -> Tuple[Dataset, Dataset, Dict]:
-    t0 = time.perf_counter()
     path = _find_npz(filename, cache_dir)
     raw = raw_source = None
     if path is None and raw_finder is not None:
@@ -313,8 +308,6 @@ def _load_inner(filename: str, num_classes: int, image_shape: Tuple[int, ...],
             "and synthetic_fallback=False (this environment has no network access)")
     train, test = _to_datasets(xtr, ytr, xte, yte, num_classes, flatten)
     info.update(num_classes=num_classes, train_rows=len(train), test_rows=len(test))
-    if obs.enabled():
-        obs.histogram("data_load_seconds").observe(time.perf_counter() - t0)
     return train, test, info
 
 
@@ -331,8 +324,8 @@ def load_cifar10(cache_dir: Optional[str] = None, synthetic_fallback: bool = Tru
                  ) -> Tuple[Dataset, Dataset, Dict]:
     """CIFAR-10: features [N, 32, 32, 3] float32 in [0,1].
 
-    Synthetic amplitude 3.5 (v5e calibration, 2026-07-31): at the round-3
-    default of 7.0 the 32x32x3 CNN separated the classes in 1-2 epochs
+    Synthetic amplitude 3.5: at the generator's default of 7.0 the
+    32x32x3 CNN separated the classes in 1-2 epochs
     (0.986 after epoch 1), defeating the wall-to-target metric.  At 3.5
     the DOWNPOUR/AEASGD BASELINE configs climb 0.67 -> 0.78 -> 0.88 ->
     0.89 -> 0.90 -> 0.92 and cross their 0.90 target around epoch 5."""

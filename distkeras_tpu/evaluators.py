@@ -87,7 +87,7 @@ class TopKAccuracyEvaluator(Evaluator):
 
     Needs a vector prediction column (logits/probabilities); beyond the
     reference surface (which had accuracy only), standard for the CIFAR/
-    ImageNet-style configs in BASELINE.md.
+    ImageNet-style configs of ``BASELINE.json``.
     """
 
     def __init__(self, k: int = 5, prediction_col: str = "prediction",

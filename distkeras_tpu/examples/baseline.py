@@ -3,7 +3,9 @@
 Runs each config end to end — load data, train, evaluate after every
 epoch — and reports samples/sec/chip plus wall-clock-to-target-accuracy,
 the two halves of the headline metric.  One JSON line per config; a
-summary table at the end; optionally writes ``BASELINE_RESULTS.json``.
+summary table at the end; ``--out FILE`` also writes the records there.
+A run of this matrix is not the repository's account of speed: that is
+``benchmark/run.py``'s cells and ``PERF_LEDGER.jsonl`` (PERF.md).
 
 Offline environments run on the loaders' deterministic synthetic
 stand-ins (flagged in every record); drop real ``mnist.npz`` /
@@ -36,7 +38,7 @@ def _evaluate(model, test_ds) -> float:
 
 
 def _steady_rate(trainer, train_ds, reps: int = 3, max_windows: int = 64) -> float:
-    """In-program steady-state samples/sec/chip (round-2 weak #7 fix): the
+    """In-program steady-state samples/sec/chip: the
     multi-epoch program amortizes per-dispatch host overhead, so this
     column reflects chip throughput — unlike the wall columns, which also
     bill host feeding and one dispatch per epoch."""
@@ -59,7 +61,7 @@ def _steady_rate(trainer, train_ds, reps: int = 3, max_windows: int = 64) -> flo
         return engine.steady_state_rate(
             state, chunk[trainer.features_col], chunk[trainer.label_col], reps=reps)
 
-    # SingleTrainer: same shape as the headline MNIST bench — an outer scan
+    # SingleTrainer: an outer scan
     # over reps of the inner per-batch scan, one compiled program.  Reject
     # dropout-bearing specs like the engine path does: silently timing the
     # eval-mode forward would overstate the steady rate
@@ -111,13 +113,12 @@ def run_config(num: int, epochs_cap: int, batch_size: Optional[int] = None,
 
     # (name, trainer class, trainer kwargs, spec, loader,
     #  real-data target, synthetic target).  Synthetic targets are
-    # calibrated per shape on v5e so every config needs multiple epochs of
-    # REAL training: the CIFAR-10 stand-in runs at signal amplitude 3.5
-    # (2026-07-31 recalibration — at the old 7.0 the CNN configs hit 0.99
-    # in 2 epochs, defeating wall-to-target; at 3.5 / target 0.90 they
-    # cross around epoch 5), and 100-way classification plateaus near
-    # 0.73 on the amplitude-7.0 generator (bar 0.70, first crossed at
-    # epoch 14 in the recorded v5e run — see BASELINE_RESULTS.json).
+    # calibrated per shape so every config needs multiple epochs of REAL
+    # training: the CIFAR-10 stand-in runs at signal amplitude 3.5 (at 7.0
+    # the CNN configs hit 0.99 in 2 epochs, defeating wall-to-target; at
+    # 3.5 / target 0.90 they cross around epoch 5), and 100-way
+    # classification plateaus near 0.73 on the amplitude-7.0 generator
+    # (bar 0.70, crossed around epoch 14).
     configs = {
         1: ("SingleTrainer MLP/MNIST", SingleTrainer, {},
             mnist_mlp_spec(), lambda: load_mnist(flatten=True), 0.97, 0.95),
@@ -143,10 +144,9 @@ def run_config(num: int, epochs_cap: int, batch_size: Optional[int] = None,
 
     samples_per_epoch = len(train_ds)
     accs: List[float] = []
-    epoch_walls: List[float] = []  # per-epoch train+eval wall (round-3
-    # verdict weak #6: single-shot wall columns swung 2-8x run to run
-    # (v5e, 2026-07-31, cause not established); the per-epoch spread makes
-    # the noise visible and the median gives a de-noised wall estimate)
+    epoch_walls: List[float] = []  # per-epoch train+eval wall: a
+    # single-shot wall column swings from run to run; the per-epoch
+    # spread makes the noise visible and the median de-noises the wall
     t0 = time.perf_counter()
     t_target = None
     for epoch in range(epochs_cap):
@@ -210,10 +210,7 @@ def run_config(num: int, epochs_cap: int, batch_size: Optional[int] = None,
         "samples_per_sec_per_chip_train": max(
             (m["samples_per_sec_per_chip"] for m in trainer.metrics), default=None),
         # in-program multi-epoch rate (see _steady_rate): wall-timed over
-        # one compiled program — comparable to the bench headline's v2
-        # wall tag, NOT its round-4 v3 device tag, which additionally
-        # excludes the per-dispatch host time (a ~10-20% gap on v5e,
-        # 2026-07-31, not a regression)
+        # one compiled program, so it still holds one dispatch's host time
         "samples_per_sec_per_chip_steady": round(_steady_rate(trainer, train_ds), 1),
         "final_loss": round(trainer.history[-1], 4) if trainer.history else None,
     }
@@ -225,7 +222,7 @@ def main(argv=None) -> None:
                         help="1-5 or 'all'")
     parser.add_argument("--cpu", type=int, default=0,
                         help="simulate this many CPU devices instead of real chips")
-    # default cap sized for the HARDEST config on the round-3 synthetics
+    # default cap sized for the HARDEST config on the synthetics
     # (config 5 crosses its 0.70 bar around epoch 14)
     parser.add_argument("--epochs-cap", type=int, default=18)
     parser.add_argument("--batch-size", type=int, default=None)

@@ -20,11 +20,10 @@ class CNN(nn.Module):
 
     ``compute_dtype`` (e.g. ``"bfloat16"``) runs convs/matmuls and
     activations in that dtype with float32 params/optimizer — the LM
-    stack's mixed-precision scheme, and the measured-faster choice even
-    at MNIST scale (1.35x the f32 headline on v5e; the old "bf16 slower"
-    result applied to a whole-model cast — see BASELINE.md round 5).
-    The head always emits float32 logits.  ``None`` keeps float32 (the
-    historical default; parity-tested against bf16)."""
+    stack's mixed-precision scheme (bfloat16 operands at the MXU's
+    native rate; a whole-model cast, parameters included, is a
+    different scheme).  The head always emits float32 logits.  ``None``
+    keeps float32 (the historical default; parity-tested against bf16)."""
 
     conv_channels: Sequence[int] = (32, 64)
     kernel_size: int = 3

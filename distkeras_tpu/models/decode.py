@@ -92,10 +92,9 @@ class QKVCache(NamedTuple):
     per-(position, head) float32 scales [L, B, S, H, 1].
 
     Serving memory-bandwidth lever (batched decode reads the whole cache
-    every step — ~86MB/token at bench size, the dominant cost at batch
-    8): storing KV int8 halves that traffic, and XLA fuses the
-    dequantize into the attention dots' operand reads (measured 1.53x on
-    the cache-attention pass, v5e 2026-07-31).  Quantization error is
+    every step, the dominant cost once the batch grows): storing KV int8
+    halves that traffic, and XLA fuses the dequantize into the attention
+    dots' operand reads.  Quantization error is
     one rounding step per K/V row — NOT bit-exact with the bf16 cache;
     the ``tests/test_decode.py`` oracle pins that the quantized-cache
     forward equals a full-precision forward over the SAME
@@ -157,8 +156,8 @@ def _block(pb: dict, x: jnp.ndarray, cache, layer: int, start_pos, dtype,
     ``cache`` is the STACKED [layers, B, S, H, Dh] :class:`KVCache` (or
     :class:`QKVCache`); only the L new K/V rows of layer ``layer`` are
     written (in place when XLA can alias the scan carry — the whole
-    point: rewriting the full cache per decoded token would move
-    ~50MB/token at bench size).  Queries attend over the layer's slab
+    point: rewriting the full cache per decoded token would move every
+    layer's slab once a token).  Queries attend over the layer's slab
     masked to ``key_pos <= start_pos + query_offset``, which also masks
     dead rows beyond the write head.
 
@@ -374,13 +373,13 @@ def warn_quantized_cache_gqa(config: dict, context: str) -> None:
     The int8 KV cache pays a quantize-on-write op per step to halve cache
     READ traffic; GQA (``num_kv_heads < num_heads``) has already cut that
     traffic by the head ratio, so there is little bandwidth left to win
-    and the write cost dominates: v5e b64 batched decode measured
-    **94.9k -> 82.4k tok/s (-13%)** when int8 was stacked on a 4x-GQA
-    cache (BENCH_r05 gqa_b64, 2026-07-31, not re-measured).  The
-    combination composes silently in config, so
-    every decode builder routes through this guard; it stays a WARNING
-    (not a refusal) because the crossover may return at much longer
-    cache_len — re-measure at your shape before suppressing it."""
+    and the write cost dominates.  The figure the message quotes is a
+    July 2026 record (v5e, batch 64, int8 stacked on a 4x-GQA cache)
+    that no benchmark cell has repeated: decoding has no cell (ROADMAP,
+    "Never on the ledger").  The combination composes silently in
+    config, so every decode builder routes through this guard; it stays
+    a WARNING (not a refusal) because the crossover may return at much
+    longer cache_len — re-measure at your shape before suppressing it."""
     kv_heads = config.get("num_kv_heads") or config["num_heads"]
     if kv_heads < config["num_heads"]:
         warnings.warn(
@@ -390,8 +389,7 @@ def warn_quantized_cache_gqa(config: dict, context: str) -> None:
             "batch 64, -13%): GQA already cut the cache reads by the head "
             "ratio, so int8's read savings no longer cover its "
             "quantize-on-write cost.  Drop quantize_cache (keep GQA), or "
-            "re-measure at your shape (bench.py decode legs fp_b64_gqa vs "
-            "kv_int8_b64_gqa) before relying on this combination.",
+            "re-measure at your shape before relying on this combination.",
             UserWarning, stacklevel=3)
 
 

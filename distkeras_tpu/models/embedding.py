@@ -104,7 +104,7 @@ MultiTableCTRClassifier.sparse_field_map = {
 def ctr_embedding_spec(rows, dim: int = 16, fields: int = 4,
                        hidden_sizes: Sequence[int] = (32,),
                        num_outputs: int = 2) -> ModelSpec:
-    """Spec for the synthetic-CTR example/bench: ``fields`` int32 id
+    """Spec for the synthetic-CTR example: ``fields`` int32 id
     columns in, click/no-click logits out.
 
     ``rows`` as an int keeps the PR-9 single-shared-vocabulary

@@ -21,8 +21,7 @@ class MLP(nn.Module):
 
     ``compute_dtype`` (e.g. ``"bfloat16"``) runs the hidden matmuls and
     activations in that dtype with float32 params/optimizer — the LM
-    stack's mixed-precision scheme (models/transformer.py), measured
-    1.35x on the CNN headline (see BASELINE.md round 5).  The head
+    stack's mixed-precision scheme (models/transformer.py).  The head
     always emits float32 logits (softmax-CE stability).  ``None`` keeps
     everything float32 (the historical default; parity-tested)."""
 

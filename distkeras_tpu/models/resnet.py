@@ -1,4 +1,4 @@
-"""ResNet-20 (CIFAR variant) — the BASELINE.md config-5 model.
+"""ResNet-20 (CIFAR variant) — the `BASELINE.json` config-5 model.
 
 Classic 3-stage CIFAR ResNet (He et al. 2015): 6n+2 layers with n=3.
 Uses GroupNorm instead of BatchNorm: batch statistics are a cross-replica

@@ -14,9 +14,9 @@ bfloat16 the k+1-window forward can flip argmax near-ties relative to the
 single-token forward (different matmul shapes accumulate differently), so
 the two equally-valid greedy trajectories may diverge after such a tie.
 
-Measured on v5e (8-layer/512-dim bf16 target, 2-layer/256-dim draft,
-k=4, 256 new tokens): 1.17-1.41x over plain greedy decoding depending on
-acceptance rate.
+What it gains depends on the acceptance rate; decoding has no benchmark
+cell, so no figure for it is on the ledger (ROADMAP, "Never on the
+ledger").
 
 Per loop iteration, with m = number of accepted draft tokens (0..k):
 ``m + 1`` tokens commit (the accepted prefix plus the target's correction
@@ -142,20 +142,19 @@ def make_speculative_generate_fn(target_spec: ModelSpec, draft_spec: ModelSpec,
 
     ``draft_step_impl``: the draft's k sequential single-token proposal
     steps are the serial bottleneck of every round, and they run on a
-    SMALL model — exactly the regime where the fused Pallas decode-step
-    kernel (``ops/decode_step.py``) beats the XLA step (2.1x at
-    2-layer/128-dim, v5e device time).  ``None`` auto-selects it on TPU
-    at batch 1 for draft shapes inside the kernel's measured win region;
-    ``"fused"``/``"xla"`` pin the path.  The target's k+1-token verify
+    SMALL model — exactly the regime the fused Pallas decode-step
+    kernel (``ops/decode_step.py``) was written for: per-op sequencing
+    cost, not weight bytes, bounds the step.  ``None`` auto-selects it
+    on TPU at batch 1 for draft shapes inside ``fused_step_auto``'s
+    bound; ``"fused"``/``"xla"`` pin the path.  The target's k+1-token verify
     window is MXU-shaped and always stays XLA.
 
     ``quantize_cache=True`` stores BOTH models' KV int8 with per-(position,
     head) scales (:class:`~distkeras_tpu.models.decode.QKVCache`), exactly
     like ``make_generate_fn``'s flag: cache HBM traffic halves — the
-    dominant batched-decode cost, 1.91x on the plain b64 leg — at one
-    rounding step per K/V row.  Rewound draft rows re-quantize on
-    overwrite (per-position state, so the rewind semantics are
-    unchanged).  Requires the XLA draft step (the fused kernel's slabs
+    dominant batched-decode cost — at one rounding step per K/V row.
+    Rewound draft rows re-quantize on overwrite (per-position state, so
+    the rewind semantics are unchanged).  Requires the XLA draft step (the fused kernel's slabs
     are bf16), so it suits the BATCHED regime where the fused draft
     would not be auto-selected anyway.
 

@@ -302,12 +302,12 @@ class TransformerLM(nn.Module):
 
     vocab_size: int = 32000
     model_dim: int = 512
-    num_heads: int = 4   # head_dim 128 = model_dim/num_heads: the v5e-
-                         # recommended config (BASELINE.md head-dim study:
-                         # at IDENTICAL FLOPs, head_dim 128 contracts the
+    num_heads: int = 4   # head_dim 128 = model_dim/num_heads: at
+                         # IDENTICAL FLOPs, head_dim 128 contracts the
                          # attention matmuls over the MXU's full 128-wide
-                         # systolic dim and halves per-score VPU overhead —
-                         # 0.577 vs 0.389 MFU at 2k tokens vs head_dim 64)
+                         # systolic dim and halves per-score VPU overhead
+                         # against head_dim 64 (every benchmark
+                         # configuration has heads of 128)
     num_kv_heads: Optional[int] = None  # GQA (see TransformerBlock); None = MHA
     num_layers: int = 6
     max_seq_len: int = 2048  # positional-table size under "learned"; under

@@ -22,7 +22,7 @@ async engine, feed path, MoE router, punchcard daemon):
 Telemetry is **disabled by default** (instrumented call sites cost one
 branch).  Turn it on with :func:`enable` — or set ``DKT_TELEMETRY=1`` in
 the environment, which enables it at import time (the no-code-change
-switch for daemons and bench runs)::
+switch for daemons)::
 
     from distkeras_tpu import observability as obs
 

@@ -16,8 +16,7 @@ Design constraints (all load-bearing):
   instrument takes its own small lock.
 - **Near-zero when disabled.**  Telemetry is OFF by default: every mutator
   is a single attribute check and early return, so instrumented hot paths
-  (per-RPC, per-window, per-chunk) cost one branch.  The ≤2% bench
-  overhead budget in ISSUE #1 is met by construction — nothing allocates,
+  (per-RPC, per-window, per-chunk) cost one branch: nothing allocates,
   formats, or locks until ``enable()`` has run.
 
 Naming convention (see ARCHITECTURE.md "Observability"): metric names are

@@ -3,10 +3,9 @@
 Spans are the wall-clock complement to the metrics registry: where a
 histogram says "window wall time is bimodal", the trace says WHICH windows
 were slow and what they overlapped with (the pull RPC? the H2D transfer?
-another worker's commit?).  The round-5 wall-vs-device async decomposition
-(371 ms vs 1.6 ms per window, VERDICT.md) was hand-instrumented exactly
-this way; this module makes that measurement a permanent, exportable
-signal.
+another worker's commit?).  The async plane's wall-vs-device
+decomposition of a window was first instrumented by hand exactly this
+way; this module makes that measurement a permanent, exportable signal.
 
 Two export forms:
 

@@ -79,13 +79,13 @@ def ring_block_impl(l_local: int, head_dim: int) -> str:
     ``l_local`` positions on TPU; dense-XLA below the crossover, the flash
     kernel above it (which also needs Mosaic-legal 128-divisible blocks).
 
-    The crossover tracks per-block WORK, not length alone — v5e
-    device-time measurements (fwd+bwd per block; bench ``ring`` legs
-    track the hd-64 row): head_dim 64 flash/dense = 0.79x at l_local
-    1024, 4.0x at 2048; head_dim 128 = 0.72x at 512, 1.05x at 1024,
-    2.29x at 2048.  Both cross between 65k and 131k of l_local*head_dim,
-    so the rule is area >= 2048*64.  Single source for the threshold —
-    the bench imports this instead of restating it."""
+    The crossover tracks per-block WORK, not length alone: a small block
+    cannot amortize the kernel's fixed VPU overhead, and a wider head
+    amortizes it at a shorter shard, so the rule is the area
+    ``l_local * head_dim >= 2048 * 64``.  It was set from a July 2026
+    device-time sweep that no benchmark cell has repeated (no cell runs
+    the ring: PERF.md); re-measure at your shape before moving it.
+    Single source for the threshold."""
     return ("flash" if (on_tpu()
                         and l_local * head_dim >= 2048 * 64
                         and l_local % 128 == 0)
@@ -99,17 +99,14 @@ def attention_impl(lq: int, lk: int) -> str:
     otherwise (including the CPU backend, where the interpreted kernel is
     test-only).
 
-    Measured on v5e DEVICE time (fwd+bwd, 2026-07-31 sweep, not
-    re-measured): flash is 1.1-1.9x at every L >= 2048 shape probed
-    (b1-b8, head_dim 64 and 128, 2k-8k tokens).  An earlier rule
-    additionally required B*L >= 16k tokens — that cutoff was an artifact
-    of WALL timing on small, fast steps; it cost the head_dim-128 LM legs
-    30-44% (e.g. the 1024-dim leg: dense 126.8 ms/step vs flash 88.1).
     Deliberately LENGTH-only, unlike ``ring_block_impl``'s area rule:
-    below 2048 the winner flips with batch as well (L=1024 device sweep:
-    0.77x at b2/hd64 but 2.09x at b8/hd64; 0.92x at b2/hd128, 1.12x at
-    b8/hd128), so there is no clean sub-2048 predicate — the length rule
-    is the measured safe-everywhere region."""
+    from 2048 positions on the kernel never materializes the [L, L]
+    scores dense XLA writes and re-reads, at every batch and head size;
+    below that the winner flips with batch as well as head size, so
+    there is no clean sub-2048 predicate and dense XLA is the safe
+    choice.  Every benchmark cell runs at 2048 positions or more and so
+    takes the kernel; its measured share of its roofline is PERF.md's
+    ``flash_fwd_roofline`` / ``flash_bwd_roofline``."""
     return ("flash" if (on_tpu() and lq >= 2048
                         and lq % 128 == 0 and lk % 128 == 0)
             else "dense")
@@ -142,11 +139,9 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, axis_name: st
       flash backward as a delta shift).
 
     ``impl``: ``None`` auto-selects — the flash kernel on TPU for shards
-    long enough to win (measured v5e per-block crossover, DEVICE time
-    2026-07-31, tracked by ``bench.py``'s ``ring`` legs: 5.0x at
-    l_local=4096, 4.0x at 2048, 0.79x at 1024 — small blocks can't
-    amortize the kernel's VPU overhead), dense-XLA otherwise (including
-    CPU meshes, where interpret-mode flash is also prohibitively slow for
+    long enough to amortize the kernel's VPU overhead
+    (:func:`ring_block_impl`), dense-XLA otherwise (including CPU
+    meshes, where interpret-mode flash is also prohibitively slow for
     tests).  ``"flash"``/``"dense"`` force a path (CPU flash-ring
     composition tests; numerical cross-checks).
     """

@@ -1,15 +1,14 @@
 """Fused single-token decode step: one Pallas kernel per transformer block.
 
-Why this exists (measured on v5e, 2026-07-31): the XLA decode step at
-batch 1 lowers to ~15 ops per block (LN, qkv, two cache updates, scores,
-mask, softmax, pv, proj, residual, LN, up, gelu, down, residual), and a
-1-layer/64-dim probe showed the per-token cost scales with that op count
-(~0.75us fixed cost per op) rather than matmul size — at 8 layers the
-~120-op program spends roughly as much time sequencing ops as it does
-moving the ~69MB of weights + KV cache a token actually needs (84us at
-819GB/s vs the 89us measured step).  Collapsing each block into ONE
-Mosaic kernel removes the per-op overhead floor and leaves the step
-bounded by what it must be bounded by: HBM traffic for weights and cache.
+Why this exists: the XLA decode step at batch 1 lowers to ~15 ops per
+block (LN, qkv, two cache updates, scores, mask, softmax, pv, proj,
+residual, LN, up, gelu, down, residual), and at one token a step each op
+is so small that the per-token cost follows the op count (a fixed cost
+to sequence each op) rather than the matmul sizes.  Collapsing each
+block into ONE Mosaic kernel removes that per-op floor and leaves the
+step bounded by what it must be bounded by: HBM traffic for weights and
+cache.  Decoding has no benchmark cell, so none of this is on the ledger
+(ROADMAP, "Never on the ledger").
 
 Design (single kernel, grid over layers — Mosaic grids run sequentially,
 so the hidden-state carry lives in VMEM scratch across grid steps):
@@ -33,9 +32,8 @@ so the hidden-state carry lives in VMEM scratch across grid steps):
   V row-major [L, B, S, H, D].  This makes both attention contractions
   canonical MXU matmuls with NO [S, HD]-sized elementwise pass and no
   lane<->sublane transposes (Mosaic supports neither a cheap [1, HD] ->
-  [HD, 1] reshape nor fast big elementwise f32 passes — the first cut
-  of this kernel did five of them and scaled 15x worse per cache row
-  than the XLA step):
+  [HD, 1] reshape nor fast big elementwise f32 passes, so a kernel
+  built on them scales badly with the cache's rows):
 
       scores^T [H, S] = (sel^T ⊙ q_row) [H, HD]  @  k_slab^T [HD, S]
       mix      [H, HD] =          p^T [H, S]     @  v_slab   [S, HD]
@@ -68,9 +66,9 @@ from distkeras_tpu.platform import on_tpu
 
 _NEG_INF = float("-inf")
 
-# the b8 bench working set (two ~6MB KV slabs + double-buffered 6.5MB
-# weight blocks + attention temps) sits near 30MB; v5e VMEM fits it
-# comfortably but Mosaic's 16MB default does not
+# a batch-8 working set (two KV slabs + double-buffered weight blocks +
+# attention temps) runs to tens of MB; v5e VMEM fits it comfortably but
+# Mosaic's 16MB default does not
 _VMEM_LIMIT = 96 * 1024 * 1024
 
 
@@ -96,9 +94,8 @@ def stack_decode_weights(params: Any, num_layers: int,
     Inside a jitted generate fn this is loop-invariant w.r.t. the decode
     scan, so XLA materializes the slabs once per call, not per token.
     int8 ``QTensor`` leaves are dequantized here (the fused kernel
-    streams weights in the compute dtype; weight-only int8 decode showed
-    <3% at batch 1 (v5e, 2026-07-31, not re-measured), so the fused path
-    optimizes the dominant costs instead).
+    streams weights in the compute dtype: at batch 1 the per-op floor,
+    not weight bytes, is what the fused path removes).
     """
     def deq(w):
         return w.dequantize(dtype) if isinstance(w, QTensor) else w.astype(dtype)
@@ -178,14 +175,12 @@ def fused_step_supported(config: dict, batch: int, cache_len: int) -> bool:
             and _kernel_vmem_bytes(config, batch, cache_len) <= _VMEM_BUDGET)
 
 
-# auto-select crossover, measured on v5e (2026-07-31, batch 1, 768-row
-# cache, device time, us/step fused vs XLA): 2L/128 9.8 vs 20.6 (2.1x),
-# 4L/256 19.8 vs 38.1 (1.9x), 6L/384 50.5 vs 58.0 (1.15x), 8L/512 111 vs
-# 89 (0.8x — XLA wins; its step is already overlap/bandwidth-optimal at
-# that weight volume).  The kernel's edge is the fixed ~15-op-per-layer
+# auto-select crossover.  The kernel's edge is the fixed ~15-op-per-layer
 # sequencing cost it removes, which stops mattering once per-layer weight
-# streaming dominates — so auto-select keys on total block-weight bytes,
-# conservatively inside the measured winning region.
+# streaming dominates (XLA's step already overlaps that streaming) — so
+# auto-select keys on total block-weight bytes.  The bound was set inside
+# the winning region of a July 2026 sweep (batch 1, 768-row cache, 2 to 8
+# layers) that no benchmark cell has repeated: re-measure before moving it.
 _AUTO_MAX_BLOCK_BYTES = 24 * 1024 * 1024
 
 
@@ -193,7 +188,7 @@ def fused_step_auto(config: dict, batch: int, cache_len: int) -> bool:
     """Should the fused kernel be auto-selected?  True only in the regime
     where it measured FASTER than the XLA step: batch 1 (the batched
     kernel's lockstep score block loses to XLA's amortization) and a
-    small-to-mid model (see crossover table above).  ``step_impl='fused'``
+    small-to-mid model (see the crossover note above).  ``step_impl='fused'``
     overrides this for A/B measurement; ``fused_step_supported`` is the
     hard shape gate."""
     e = config["model_dim"]
@@ -208,8 +203,8 @@ def fused_step_auto(config: dict, batch: int, cache_len: int) -> bool:
 def resolve_step_impl(config: dict, batch: int, cache_len: int,
                       requested, *, what: str = "step_impl") -> str:
     """The ONE selection policy shared by ``make_generate_fn``,
-    ``make_speculative_generate_fn`` (draft side), and the bench's leg
-    labelling: ``None`` -> fused iff on TPU and ``fused_step_auto``;
+    ``make_speculative_generate_fn`` (draft side): ``None`` -> fused iff
+    on TPU and ``fused_step_auto``;
     explicit ``"fused"`` -> hard-validated against
     ``fused_step_supported``; anything else must be ``"xla"``."""
     cache_len = round_cache_len(cache_len)

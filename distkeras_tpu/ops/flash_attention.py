@@ -58,11 +58,9 @@ _STAT_LANES = 8  # trailing lanes for per-row stats (min f32 tile lane count
 
 # Mosaic's default scoped-vmem budget is 16M, which the dkv kernel's working
 # set at (1024, 1024) blocks overflows by 8K inside full transformer backward
-# programs (round-2 block sweep).  24M is the measured sweet spot (v5e,
-# 2026-07-30 profiled device-time A/B): enough for the large-block dkv pass,
-# while a generous 96M grant made the same kernels ~4-5% SLOWER at 2k/8k —
-# Mosaic folds the budget into its pipelining decisions, so grant the
-# minimum that fits.
+# programs.  24M is enough for the large-block dkv pass; a generous grant
+# is not free — Mosaic folds the budget into its pipelining decisions —
+# so grant the minimum that fits.
 _VMEM_LIMIT = 24 * 1024 * 1024
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
@@ -291,8 +289,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     The separate dq kernel re-derives the identical [bq, bk] score and
     probability blocks the dkv kernel just computed — at small head dims
     that recompute IS the kernel cost, so fusing the two backward passes
-    cuts backward time by ~the dq kernel (measured ~25-30% off the whole
-    fwd+bwd attention step on v5e).
+    cuts backward time by about the dq kernel's.
 
     The catch is accumulation order: dK/dV accumulate over the inner qi
     steps (scratch flushed per kv block, as before) while dQ accumulates
@@ -414,29 +411,27 @@ def _forward(q, k, v, cfg: _Config):
     )(q, k, v)
 
 
-# Fused-backward eligibility (v5e scoped-vmem measurements, 2026-07-30).
+# Fused-backward eligibility (what the v5e compiler's scoped vmem admits).
 # The fused kernel's [Lq, D] float32 dq scratch plus its block working set
-# must fit the scoped-vmem budget; measured boundaries at D=64:
+# must fit the scoped-vmem budget; boundaries at D=64:
 #   (1024, 1024) blocks fit when BOTH the dq scratch and the streamed kv
-#     length stay small (through Lq=Lk=16k), and are 2-3% faster than
-#     (512, 1024) everywhere they fit; OOM when Lk reaches 32k;
+#     length stay small (through Lq=Lk=16k), and are preferred to
+#     (512, 1024) wherever they fit; OOM when Lk reaches 32k;
 #   (512, 1024) blocks fit through Lq=16k (dq scratch 4.2M) at ANY Lk
 #     (the 32k leg runs them via q-chunking), OOM at unchunked Lq=32k;
 #   (512, 512) blocks fit through Lq=32k (dq scratch 8.4M);
 #   above that, fall back to the two-kernel backward with wide blocks.
-# SINGLE-BLOCK tier (round-5, D=128 re-sweep): when the k block spans the
-# WHOLE sequence (reachable from auto-select when Lq, Lk <= 2048 — the
-# square Lq = Lk case is the measured one; cross-length shapes like
+# SINGLE-BLOCK tier: when the k block spans the WHOLE sequence (reachable
+# from auto-select when Lq, Lk <= 2048; cross-length shapes like
 # Lq 2048 / Lk 1024 take the same single-k-block structure) the fused
-# backward in one grid step beats (1024, 1024) despite skipping no causal
-# blocks — the
-# same fewer-passes-beats-fewer-FLOPs tradeoff the forward measured: 1.43
-# vs 1.57 ms/step on the 2k hd128 attention leg.  Its [bq, bk] f32
-# score/dp + bf16 p tiles (~10 B/element) outgrow the standard 24M grant,
-# so ``_bwd_compiler_params`` sizes the grant per call (48M measured flat
-# vs 56/64M).  At 8k the same wide blocks LOSE (5.20 vs 4.92: q-chunks
-# re-stream k/v and forgo the 44% causal-skip), hence the lk == bk_kv
-# containment rather than a general wide tier.
+# backward runs in one grid step, skipping no causal blocks — the same
+# fewer-passes-for-more-FLOPs trade the forward makes at these lengths.
+# Its [bq, bk] f32 score/dp + bf16 p tiles (~10 B/element) outgrow the
+# standard 24M grant, so ``_bwd_compiler_params`` sizes the grant per
+# call.  Past 2048 wide blocks would have q-chunks re-stream k/v and
+# forgo the causal skip, hence the lk == bk_kv containment rather than
+# a general wide tier.  (The cells' 2,048-position rows run this tier:
+# PERF.md's ``flash_bwd_roofline`` is its measurement.)
 _FUSED_WIDE_CAP = 5 * 1024 * 1024       # dq / lk-stream cap for 1024-wide blocks
 _FUSED_DQ_SCRATCH_CAP = 12 * 1024 * 1024  # dq scratch cap for (<=512, <=512)
 _BWD_WS_BYTES_PER_ELEM = 10             # f32 s + f32 dp + bf16 p per score
@@ -463,9 +458,8 @@ def _fused_bwd_ok(lq: int, d: int, bq_kv: int, bk_kv: int, lk: int) -> bool:
 def _bwd_compiler_params(bq_kv: int, bk_kv: int) -> pltpu.CompilerParams:
     """Scoped-vmem grant for a backward call, sized to its score-tile
     working set: the standard minimum-that-fits 24M grant through
-    (1024, 1024) blocks; the wide single-block tier measured fastest at
-    48M (v5e 2026-07-31: 48M == 56M == 64M within noise, all faster than
-    any 24M-compatible blocking).  >= so the boundary pair (2048, 1024)
+    (1024, 1024) blocks; the wide single-block tier gets 48M, above
+    what its tiles need.  >= so the boundary pair (2048, 1024)
     — reachable cross-length, e.g. Lq 2048 vs Lk 1024 — gets the sized
     grant its exactly-20M score tiles need rather than the 24M grant
     that only fits the 10M working set of (1024, 1024)."""
@@ -691,16 +685,17 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     visible blocks, not the whole sequence); the diagonal and the window's
     edge are masked.
 
-    Kernel structure and block defaults (v5e device-time sweeps,
-    2026-07-30): the forward uses one full-length block when the [Lq, Lk]
-    score tile fits scoped vmem and (1024, 1024) above that; the backward
-    normally runs as ONE fused kernel producing dq, dk and dv from a
-    single score/probability recompute (25-30% faster than the classic
-    two-kernel backward), preferring (512, 1024) blocks and chunking the
-    q range when its [Lq, D] f32 dq scratch outgrows scoped vmem
-    (``_fused_q_chunks``); the two-kernel path remains as the fallback for
-    shapes that cannot chunk.  Small blocks lose badly (128 runs at 0.4x
-    dense).
+    Kernel structure and block defaults: the forward uses one
+    full-length block when the [Lq, Lk] score tile fits scoped vmem and
+    (1024, 1024) above that; the backward normally runs as ONE fused
+    kernel producing dq, dk and dv from a single score/probability
+    recompute (the classic two-kernel backward recomputes them twice),
+    preferring (512, 1024) blocks and chunking the q range when its
+    [Lq, D] f32 dq scratch outgrows scoped vmem (``_fused_q_chunks``);
+    the two-kernel path remains as the fallback for shapes that cannot
+    chunk.  Small blocks pay per-block overhead many times over: keep
+    them wide.  What the kernels reach of their rooflines in the
+    benchmark's cells is in PERF.md.
 
     Explicit knobs: ``block_q``/``block_k`` govern the forward kernel;
     absent bwd overrides the backward AUTO-SELECTS fused-compatible blocks
@@ -764,19 +759,18 @@ def _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
     # a window that reaches past every key this call holds is plain causal
     window = 0 if (window is None or window >= q_offset + lq - k_offset) else int(window)
     d = q.shape[-1]
-    # forward defaults (v5e device-time sweep, 2026-07-30, fwd+bwd with all
-    # grads live): one full-length block when the whole [Lq, Lk] score tile
-    # fits scoped vmem (14% faster than (512, 1024) at 2k — no online
-    # correction passes, no grid overhead), (1024, 1024) above that (5%
-    # faster than (512, 1024) at 8k; [2048, 2048] f32 scores OOM at 8k+)
+    # forward defaults: one full-length block when the whole [Lq, Lk] score
+    # tile fits scoped vmem (no online correction passes, no grid
+    # overhead), (1024, 1024) above that ([2048, 2048] f32 scores OOM at
+    # 8k+)
     if block_q is None:
         block_q = lq if (lq <= 2048 and lk <= 2048) else 1024
     if block_k is None:
         block_k = lk if (lq <= 2048 and lk <= 2048) else 1024
     if block_q_bwd is None and block_k_bwd is None:
         # backward defaults aim for the FUSED single-pass backward kernel
-        # (one s/p recompute instead of two — measured 25-30% off the whole
-        # fwd+bwd step on v5e): first the single-block wide tier (only
+        # (one s/p recompute instead of two): first the single-block wide
+        # tier (only
         # reachable when the forward already runs full-length blocks, i.e.
         # Lq = Lk <= 2048 — see the _fused_bwd_ok tier note), then
         # (1024, 1024), degrading to (512, 1024), (512, 512) and finally

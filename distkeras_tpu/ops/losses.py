@@ -24,13 +24,11 @@ LossFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 
 # logits-size ceiling for the UNchunked CE path: below this the [rows, V]
-# f32 logits (plus cotangent) fit HBM comfortably and the dense form beats
-# the chunked lax.map — measured on the 2k hd128 train leg (v5e device
-# time, 2026-07-31): dense 31.8ms/step vs 32.7 chunked-2048 (the map's
-# sequential DUS accumulation plus the checkpoint's extra forward matmul
-# cost MORE than the extra HBM traffic of materializing 537MB of logits).
-# Above the ceiling (e.g. the 32k leg's 1GB logits) chunking still wins —
-# it exists for memory, and there it also measures faster.
+# f32 logits (plus cotangent) fit HBM comfortably and the dense form is
+# chosen over the chunked lax.map: the map's sequential DUS accumulation
+# and the checkpoint's extra forward matmul are work the dense form does
+# not do, against the HBM traffic of materializing the logits once.
+# Above the ceiling chunking is chosen — it exists for memory.
 _DENSE_CE_BYTES = 640 * 1024 * 1024
 _DEFAULT_CHUNK_ROWS = 2048  # chunk size target when the policy must chunk
 
@@ -38,7 +36,7 @@ _DEFAULT_CHUNK_ROWS = 2048  # chunk size target when the policy must chunk
 def _pick_chunks(rows: int, vocab: int, target_rows: Optional[int]) -> int:
     """Chunk count with the largest chunk size that divides ``rows`` and
     stays <= ``target_rows``.  One dense chunk when the full [rows, V] f32
-    logits stay under ``_DENSE_CE_BYTES`` (measured faster — see above;
+    logits stay under ``_DENSE_CE_BYTES`` (see above;
     the ceiling applies only on the DEFAULT policy ``target_rows=None`` —
     an explicit ``chunk_rows`` is a caller's memory bound and is honored
     strictly) or when ``rows`` factorizes awkwardly (e.g. prime ``rows``,
@@ -63,11 +61,11 @@ def unembed_cross_entropy(hidden: jnp.ndarray, table: jnp.ndarray,
                           targets: jnp.ndarray, chunk_rows: Optional[int] = None,
                           compute_dtype: Optional[jnp.dtype] = jnp.bfloat16) -> jnp.ndarray:
     """Fused unembed + softmax CE whose logits stay bounded: chunked when
-    they would be large, dense when materializing them once is faster.
+    they would be large, dense when they fit.
 
     ``hidden`` [B, L, E] (final-norm output), ``table`` [V, E] (the tied
     embedding matrix), ``targets`` [B, L] int.  Returns per-position CE
-    [B, L] in float32.  ``chunk_rows=None`` (default) picks the measured
+    [B, L] in float32.  ``chunk_rows=None`` (default) picks the
     policy below; an EXPLICIT ``chunk_rows`` is treated as a hard memory
     bound — the dense fast path is never taken over it.
 
@@ -82,7 +80,7 @@ def unembed_cross_entropy(hidden: jnp.ndarray, table: jnp.ndarray,
       chunk instead of keeping ~1 GB of logits (+ another in the
       cotangent) live across the whole backward.  Peak logit memory drops
       from O(B*L*V) to O(chunk_rows * V).  Below the ceiling the dense
-      single-matmul form runs (measured faster; see ``_pick_chunks``).
+      single-matmul form runs (see ``_pick_chunks``).
 
     ``compute_dtype=None`` keeps the inputs' dtype (exact-parity testing).
     """
@@ -121,8 +119,8 @@ def lm_token_cross_entropy(module, params, tokens: jnp.ndarray, targets: jnp.nda
     expose a ``hidden`` method (forward up to and including the final norm,
     no unembed) and keep its tied unembedding table at
     ``params['embed']['embedding']`` — i.e. ``models.transformer
-    .TransformerLM``.  Used by ``parallel/lm.py``, the bench, and the
-    parity tests so the pairing lives in exactly one place.
+    .TransformerLM``.  Used by ``parallel/lm.py`` and the parity tests
+    so the pairing lives in exactly one place.
     """
     h = module.apply({"params": params}, tokens, pos_offset=pos_offset,
                      method="hidden")

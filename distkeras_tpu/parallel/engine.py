@@ -236,9 +236,8 @@ class WindowEngine:
         """``reps > 1`` compiles ``reps`` passes over the same data into
         ONE program (outer lax.scan) — the steady-state measurement shape:
         per-dispatch host overhead amortizes across every epoch instead
-        of dominating each one (the round-2 baseline matrix, v5e
-        2026-07-30, measured ~100 ms of host time per dispatch, not the
-        chip; not re-measured on this installation)."""
+        of dominating each one at toy sizes (what a dispatch costs the
+        host at the benchmark's sizes is PERF.md's ``engine_host_ms``)."""
         algo = self.algorithm
         axis = self.axis_name
         needs_rng = self.needs_rng
@@ -399,7 +398,7 @@ class WindowEngine:
             self._hook.publish(jax.tree.map(np.asarray, stats))
         if telemetry:
             # identity as labels (ARCHITECTURE.md convention): a process
-            # with several engines (bench legs, elastic rebuilds) must not
+            # with several engines (elastic rebuilds) must not
             # merge differently-shaped programs into one histogram
             obs.histogram("engine_epoch_seconds", model=self.spec.name,
                           replicas=str(self.num_replicas)).observe(

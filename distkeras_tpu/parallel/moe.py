@@ -14,8 +14,8 @@ built for the MXU and ICI:
   (``dispatch_impl``): ``"dense"`` builds the classic [T, E, C] one-hot
   dispatch/combine tensors and einsums through them — no gathers, no
   dynamic shapes, everything MXU-tiled, but the einsums cost
-  ``4·T·E·C·D`` matmul FLOPs of pure routing plumbing per layer (41% of
-  ALL matmul work at the round-5 bench shape).  ``"sorted"`` computes
+  ``4·T·E·C·D`` matmul FLOPs of pure routing plumbing per layer, a
+  share that grows with ``T·E·C``.  ``"sorted"`` computes
   the SAME seating (expert id + queue position per assignment) and then
   moves rows by index: a static-shape scatter builds the slot->token
   map, one gather fills the [E, C, D] slot tensor, one gather + a
@@ -57,11 +57,10 @@ import flax.linen as nn
 
 # auto dispatch threshold: below this many [T, E, C] one-hot elements the
 # dense einsum pair is a single fused MXU kernel over <= 1 MB of f32 and
-# beats the sorted path's scatter+gather launch overhead; above it the
-# dense tax grows as 4·T·E·C·D matmul FLOPs (41% of ALL matmul work at
-# the round-5 bench shape T=2048, E=8, C=512) while sorted stays
-# O((kT + EC)·D) bytes moved.  The bench's dense-vs-sorted A/B legs
-# record the real crossover so drift after an XLA change trips visibly.
+# has less to launch than the sorted path's scatter + gathers; above it
+# the dense tax grows as 4·T·E·C·D matmul FLOPs while sorted stays
+# O((kT + EC)·D) bytes moved.  No benchmark cell runs MoEMLP, so the
+# crossover itself is not on the ledger (PERF.md).
 _DENSE_DISPATCH_MAX_TEC = 1 << 18
 
 
@@ -85,8 +84,8 @@ def dispatch_matmul_flops(t: int, e: int, c: int, d: int, impl: str) -> int:
 
     Dense: the [T,E,C] one-hot einsums cost ``2·T·E·C·D`` on each side.
     Sorted: zero — rows move by gather/scatter, not contraction.  The
-    single source of truth for the bench's ``dispatch_flops_pct`` and
-    the sown per-layer stat (multiply by 3 for fwd+bwd accounting)."""
+    layer sows its ``dispatch_flops_pct`` stat from this (multiply by 3
+    for fwd+bwd accounting)."""
     if impl == "sorted":
         return 0
     if impl != "dense":
@@ -192,9 +191,8 @@ class MoEMLP(nn.Module):
         layer_fl = 4 * e * c * d * f + 2 * t * d * e  # experts + router, fwd
         # NOTE the denominator: LAYER-local (dispatch + experts + router —
         # the module cannot see attention/unembed), so under dense
-        # dispatch this reads HIGHER than the bench's same-named
-        # model-wide field (~50% vs 41% at the r05 bench shape); both are
-        # exactly 0 on the sorted path, which is the number that matters
+        # dispatch this reads higher than a model-wide share would;
+        # exactly 0 on the sorted path
         self.sow("router_stats", "dispatch_flops_pct",
                  jnp.float32(100.0 * disp_fl / (disp_fl + layer_fl)))
 
@@ -796,9 +794,7 @@ def make_moe_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation
     ``max_expert_load`` (hottest expert's assignments / capacity) and
     ``dispatch_flops_pct`` (share of the MoE LAYER's matmul FLOPs —
     dispatch + experts + router — spent on routing plumbing; exactly 0
-    for sorted.  The bench's same-named field divides by the whole
-    MODEL's FLOPs incl. attention and unembed, so its dense numbers run
-    lower) — for the training loop's metrics.
+    for sorted) — for the training loop's metrics.
     """
     return _make_moe_step(
         spec, optimizer, mesh, dp_axis, ep_axis, aux_weight,

@@ -73,25 +73,6 @@ def pp_param_specs(outer: Dict[str, Any], blocks: Any, pp_axis: str):
     return outer_specs, block_specs
 
 
-def head_recompute_factor(pp: int, num_microbatches: int) -> float:
-    """1F1B's head (+CE) evaluations per step relative to GPipe's.
-
-    GPipe evaluates the final-norm + unembed + softmax-CE once per
-    microbatch (M total).  Since the head moved inside a ``lax.cond``
-    gated on (last rank AND valid backward unit), 1F1B evaluates it
-    exactly M times too — factor **1.0**.  The round-5 schedule's
-    ``jnp.where`` form computed-then-masked the head on every rank every
-    cycle, ``pp * (1 + 2(pp-1)/M)`` times GPipe's unembed FLOPs — the
-    measured reason it lost to GPipe at every M (1081 vs 596 ms at M=2).
-    The function stays so the bench ``pipeline`` leg keeps recording the
-    factor next to the measurement: a schedule change that reintroduces
-    head recompute must move this number, not a docstring."""
-    if pp < 1 or num_microbatches < 1:
-        raise ValueError(f"pp and num_microbatches must be >= 1, got "
-                         f"{pp}, {num_microbatches}")
-    return 1.0
-
-
 def make_pp_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
                        mesh: Mesh, num_microbatches: int,
                        dp_axis: str = "dp", pp_axis: str = "pp",
@@ -119,20 +100,15 @@ def make_pp_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
       Pick it when M must grow (long sequences / small microbatches)
       and GPipe's O(M) residuals would not fit HBM.
 
-      **Head cost (fixed in round 6):** ``unit_scalar`` runs the
-      final-norm + unembed matmul and the vocab-wide softmax-CE inside a
-      ``lax.cond`` whose predicate is (last rank AND valid backward
-      unit) — XLA conditionals execute one branch per device at
-      runtime, so only the last rank's M valid units ever pay the
-      vocab-sized matmul; every other rank (and fill/drain cycles) runs
-      the cheap cotangent chain term.  ``head_recompute_factor`` is
-      therefore 1.0 — the same head FLOPs as GPipe.  (The round-5 form
-      computed the head on every rank every cycle and masked it with
-      ``jnp.where`` — ``pp * (1 + 2(pp-1)/M)`` times GPipe's unembed
-      FLOPs, the measured reason 1F1B lost to GPipe at every M.)
-      ``bench.py``'s ``pipeline`` leg records the measured
-      gpipe-vs-1f1b step time next to the analytic factor so a
-      regression trips as a number, not a docstring drift.
+      **Head cost:** ``unit_scalar`` runs the final-norm + unembed
+      matmul and the vocab-wide softmax-CE inside a ``lax.cond`` whose
+      predicate is (last rank AND valid backward unit) — XLA
+      conditionals execute one branch per device at runtime, so only
+      the last rank's M valid units ever pay the vocab-sized matmul;
+      every other rank (and fill/drain cycles) runs the cheap cotangent
+      chain term: the same M head evaluations a step as GPipe.  (A
+      ``jnp.where`` mask would compute the head on every rank every
+      cycle, ``pp * (1 + 2(pp-1)/M)`` times GPipe's unembed FLOPs.)
     """
     if spec.config.get("moe_experts"):
         raise ValueError("MoE FFN does not compose with pipeline parallelism "
@@ -192,8 +168,8 @@ def make_pp_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
         folds the head + CE vjp into the same grad call via a
         ``lax.cond``-selected scalar (the cond's vjp is the cond of the
         branch vjps, so non-head units contribute exactly the cotangent
-        chain and zero head gradient — and, unlike the round-5
-        ``jnp.where`` form, never EXECUTE the vocab-sized head matmul).
+        chain and zero head gradient — and, unlike a ``jnp.where``
+        mask, never EXECUTE the vocab-sized head matmul).
 
         Resident activations really are O(pp): the embedding runs PER
         CYCLE on the current microbatch's tokens (the full-epoch token
@@ -233,12 +209,11 @@ def make_pp_train_step(spec: ModelSpec, optimizer: optax.GradientTransformation,
             executes only the cheap chain term at RUNTIME — XLA
             conditionals evaluate one branch per device, which is how a
             per-rank branch lives inside one SPMD program without every
-            rank paying the unembed matmul (the round-5 ``jnp.where``
-            form computed-then-masked it: pp ranks x every cycle of
-            vocab-sized waste, the reason 1F1B lost to GPipe at every
-            measured M).  Autodiff through cond yields the cond of the
-            branch vjps, so non-head units contribute exactly the
-            cotangent chain and zero head gradient, as before."""
+            rank paying the unembed matmul (a ``jnp.where`` mask would
+            compute it and throw it away on pp ranks in every cycle).
+            Autodiff through cond yields the cond of the branch vjps, so
+            non-head units contribute exactly the cotangent chain and
+            zero head gradient."""
             y = stage_apply(blocks_, x_in)
 
             def ce_term(y_):

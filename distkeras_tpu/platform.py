@@ -2,7 +2,7 @@
 from this package).
 
 Kept import-light on purpose: callers (tests/conftest.py, ``chip_smoke.py``,
-``bench.py``, the examples, ``distkeras-ps``) must be able to choose the
+``benchmark/run.py``, the examples, ``distkeras-ps``) must be able to choose the
 platform before any other module gets a chance to touch a JAX backend.  The
 package ``__init__`` is lazy (PEP 562) so ``from distkeras_tpu.platform
 import pin_cpu_devices`` executes only this file.

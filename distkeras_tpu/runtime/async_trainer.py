@@ -424,14 +424,9 @@ class AsyncDistributedTrainer(Trainer):
         self.worker_restarts = 0  # total supervisor restarts, last train()
         # planned-preemption records, last train(): one dict per drained
         # worker ({"worker", "window", "deadline_s", "drained_clean",
-        # "outstanding_after_drain"}) — the recovery drill and the bench
-        # tripwires read these
+        # "outstanding_after_drain"}) — the recovery drill reads these
         self.worker_preemptions: List[Dict[str, Any]] = []
         self.fleet_controller: Optional[Any] = None  # last train()'s, if any
-        # (monotonic_ts, worker) per completed window, autoscale runs only
-        # — the bench derives pre/post-preemption fleet throughput from it
-        self._window_log: List[Tuple[float, int]] = []
-        self._window_log_lock = threading.Lock()
         self.parameter_server: Optional[Any] = None
         self._window_fn: Optional[Callable] = None  # cached per instance so a
         # second train() on the same trainer reuses the compiled program
@@ -767,13 +762,7 @@ class AsyncDistributedTrainer(Trainer):
             return jax.tree.unflatten(treedef, list(flat))
 
         # telemetry (near-zero when disabled): window wall vs DEVICE time
-        # histograms are the round-5 VERDICT hand measurement (371 ms wall
-        # vs 1.6 ms device per window) made permanent.  Occupancy is two
-        # monotonic counters (started minus finished = live workers); a
-        # worker records its finish only if it recorded its start, so
-        # enabling telemetry mid-run can never drive the difference
-        # negative (a disable mid-run leaves at most a one-run positive
-        # residual — the finish inc no-ops)
+        # histograms (the benchmark's async_exchange_share reads both)
         m_wall = obs.histogram("async_window_wall_seconds")
         m_dev = obs.histogram("async_window_device_seconds")
         m_windows = obs.counter("async_windows_total")
@@ -785,8 +774,6 @@ class AsyncDistributedTrainer(Trainer):
         # exists even with autoscale off — the dynamic join below reads
         # `threads` under it either way
         self.worker_preemptions = []
-        with self._window_log_lock:
-            self._window_log = []
         fleet_lock = threading.Lock()
         drain_requests: set = set()   # worker idxs asked to retire
         drained: set = set()          # worker idxs that drained clean
@@ -1179,13 +1166,6 @@ class AsyncDistributedTrainer(Trainer):
                             if time.monotonic() >= h_next:
                                 send_health()
                                 h_next = time.monotonic() + health_interval
-                        if controller is not None:
-                            # fleet-throughput sample (bench pre/post-
-                            # preemption rates); autoscale runs only, so
-                            # the default path appends nothing
-                            with self._window_log_lock:
-                                self._window_log.append(
-                                    (time.monotonic(), idx))
                         # loss stays a device scalar until the run ends:
                         # float() here would add one more blocking round
                         # trip per window

@@ -20,8 +20,8 @@ every fault a *scheduled, seedable event*:
   ``fault_hook`` (raise at planned ``(worker, window)`` pairs, each fired
   at most once — so a restarted worker replaying the window survives).
 
-Used by ``tests/test_faults.py`` (the fault-injection matrix) and
-``bench.py :: _bench_async_recovery`` (time-to-recover + loss-parity leg).
+Used by ``tests/test_faults.py`` (the fault-injection matrix),
+``tests/test_ha.py`` and ``tests/test_fleet.py``.
 """
 
 from __future__ import annotations
@@ -147,8 +147,8 @@ class ChaosProxy:
     ``(seed, conn, direction)``, so a throttled chaos run replays its
     delay schedule bit-identically.  ``slow_conns`` restricts both to
     the named accept ordinals (default: every connection) — fronting a
-    whole fleet with one proxy while throttling only conn 0 is how the
-    bench's adaptive leg makes exactly one straggler."""
+    whole fleet with one proxy while throttling only conn 0 makes
+    exactly one straggler."""
 
     _CHUNK = 1 << 16
 
@@ -184,7 +184,7 @@ class ChaosProxy:
         self._lock = threading.Lock()
         self._running = False
         self._conn_seq = 0
-        self.faults_fired: List[Fault] = []  # observability for tests/bench
+        self.faults_fired: List[Fault] = []  # observability for tests
 
     # -- lifecycle -------------------------------------------------------------
     def start(self) -> "ChaosProxy":
@@ -427,9 +427,6 @@ class SpotPreemptionPlan:
             (int(w), int(k)) for w, k in preemptions}
         self.deadline_s = float(deadline_s)
         self.fired: List[Tuple[int, int]] = []
-        # monotonic timestamp per firing, aligned with ``fired`` — the
-        # bench splits its throughput window log on these
-        self.fired_at: List[float] = []
         self._lock = threading.Lock()
 
     def hook(self, worker: int, window: int) -> None:
@@ -438,7 +435,6 @@ class SpotPreemptionPlan:
         with self._lock:
             if key in self.preemptions and key not in self.fired:
                 self.fired.append(key)
-                self.fired_at.append(time.monotonic())
                 raise WorkerPreempted(worker, window, self.deadline_s)
 
 
@@ -447,8 +443,7 @@ class HubKillPlan:
     ``hub.kill()``, the SIGKILL-equivalent teardown — once it has applied
     ``after_commits`` commits.  Scheduling on the hub's own commit clock
     (not wall time) makes the drill replay at the same training progress
-    every run, so failover tests and the bench's failover leg are
-    comparable across machines.
+    every run, so failover tests are comparable across machines.
 
     ``start(hub)`` spawns the watcher; ``fired`` is set once the kill
     happened, with ``fired_at_clock`` recording the commit count at the

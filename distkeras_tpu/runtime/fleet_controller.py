@@ -102,7 +102,10 @@ class FleetController:
         self._strikes: Dict[str, int] = {}
         self._decisions: Deque[Dict[str, Any]] = collections.deque(
             maxlen=int(decision_capacity))
-        self._last_spawn = 0.0
+        # no spawn yet: time.monotonic() counts from an arbitrary origin
+        # (the machine's boot on Linux), so 0.0 here would hold the first
+        # spawn back for cooldown_s after boot
+        self._last_spawn = float("-inf")
         self._spawns = 0
         self._retires = 0
         self._preemptions = 0

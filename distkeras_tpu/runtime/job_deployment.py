@@ -695,7 +695,6 @@ class Punchcard:
                 rec.error = f"{type(e).__name__}: {e}"
                 rec.state = FAILED
             finally:
-                obs.counter("punchcard_jobs_total", state=rec.state).inc()
                 # a long-running daemon must not pin submitted datasets in
                 # RAM — cancelled ones included; only the fetchable model
                 # blobs outlive the run (and the spooled data.npz goes too).

@@ -608,7 +608,6 @@ class NativeParameterServer:
                       ("pull_bytes", "ps_pull_bytes_total"),
                       ("fenced_commits", "ps_fenced_commits_total"),
                       ("idle_evictions", "ps_idle_evictions_total"),
-                      ("commit_log_dropped", "ps_commit_log_dropped_total"),
                       ("sparse_rows_pulled", "ps.sparse_rows_pulled"),
                       ("sparse_rows_committed", "ps.sparse_rows_committed"),
                       ("sparse_wire_bytes_saved", "ps.sparse_wire_bytes_saved"),

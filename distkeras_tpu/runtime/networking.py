@@ -313,13 +313,7 @@ def recv_frame(sock: socket.socket, limit: int = MAX_FRAME) -> bytes:
     (n,) = struct.unpack(">Q", _recv_exact(sock, 8))
     if n > limit:
         raise ProtocolError(f"frame of {n} bytes exceeds limit={limit}")
-    payload = _recv_exact(sock, n)
-    # count only after the body fully arrived: a peer dying mid-frame must
-    # not inflate the byte accounting by data that never landed
-    if obs.enabled():
-        obs.counter("net_rx_frames_total").inc()
-        obs.counter("net_rx_bytes_total").inc(8 + n)
-    return payload
+    return _recv_exact(sock, n)
 
 
 def recv_frame_into(sock: socket.socket, buf: bytearray,
@@ -359,9 +353,6 @@ def recv_frame_into(sock: socket.socket, buf: bytearray,
         mv[:1] = head[8:]
         with body_span(head[8:], n):
             _recv_exact_into(sock, mv[1:])
-    if obs.enabled():
-        obs.counter("net_rx_frames_total").inc()
-        obs.counter("net_rx_bytes_total").inc(8 + n)
     return mv
 
 
@@ -464,9 +455,6 @@ def _scatter_recv_into(sock: socket.socket, out: Sequence[np.ndarray],
             # untouched per-table id set) and an empty ndarray cannot be
             # cast to a flat memoryview
             _recv_exact_into(sock, memoryview(dst).cast("B"))
-    if obs.enabled():
-        obs.counter("net_rx_frames_total").inc()
-        obs.counter("net_rx_bytes_total").inc(8 + n)
     return action
 
 
@@ -487,9 +475,6 @@ def recv_action(sock: socket.socket) -> bytes:
     (count,) = struct.unpack(">I", payload[1:5])
     if count != 0:
         raise ProtocolError(f"expected zero tensors, frame declares {count}")
-    if obs.enabled():
-        obs.counter("net_rx_frames_total").inc()
-        obs.counter("net_rx_bytes_total").inc(8 + n)
     return payload[0:1]
 
 
@@ -1112,8 +1097,11 @@ class ShmFrameRing:
         try:
             self._i[_SHM_I_PRODUCER_CLOSED] = 1
             self._i[_SHM_I_CONSUMER_CLOSED] = 1
-        except ValueError:
-            pass  # already unmapped
+        except (TypeError, ValueError):
+            # already closed: the connection's own thread saw its peer
+            # leave and released the ring (``_i`` is None) before the
+            # hub's stop() came round to sever it
+            pass
 
     def _release(self) -> None:
         self._q = self._i = self._data = None
@@ -1334,7 +1322,7 @@ class BatchedReceiver:
     frames from a committing worker), opportunistic nonblocking drains
     top the buffer up, and subsequent frames are parsed straight out of
     the buffer with zero syscalls.  The per-batch frame count lands in
-    the ``ps_recv_batch_depth`` histogram — the bench tripwire that the
+    the ``ps_recv_batch_depth`` histogram, which shows whether the
     batching actually batches.
 
     ``recv_frame_into`` mirrors :func:`recv_frame_into`'s contract: the
@@ -1450,7 +1438,4 @@ class BatchedReceiver:
         start = self._head + 8
         self._head += 8 + n
         self._batch_frames += 1
-        if obs.enabled():
-            obs.counter("net_rx_frames_total").inc()
-            obs.counter("net_rx_bytes_total").inc(8 + n)
         return memoryview(self._buf)[start:start + n]

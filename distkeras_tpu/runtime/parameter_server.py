@@ -38,9 +38,9 @@ connections reusing the same pipelined/zero-copy machinery per
 connection.  ``num_shards=1`` is byte-identical to the single-hub wire.
 
 This module and :mod:`distkeras_tpu.runtime.networking` import NO JAX, and
-must stay that way: one process owns the chip, and the bench and the tests
-start hubs and wire-only workers as children of a parent that already
-holds it.  A child that imported a JAX backend here would fail or hang on
+must stay that way: one process owns the chip, and ``chip_smoke.py`` and
+the tests start hubs and wire-only workers as children of a parent that
+already holds it.  A child that imported a JAX backend here would fail or hang on
 the chip its parent has.
 """
 
@@ -932,8 +932,7 @@ class SocketParameterServer:
     Telemetry (``distkeras_tpu.observability``, off by default): pull/
     commit counts and payload bytes (``ps_pulls_total``,
     ``ps_commits_total``, ``ps_pull_bytes_total``,
-    ``ps_commit_bytes_total``), per-RPC handler latency
-    (``ps_rpc_seconds{rpc=...}``) and the per-connection staleness gauge
+    ``ps_commit_bytes_total``) and the per-connection staleness gauge
     ``ps_staleness{conn=N}`` (N is the hub's accept ordinal modulo 256 —
     workers carry no identity on the wire, and the wrap bounds label
     cardinality under elastic connection churn) — the commit clock the paper lineage's
@@ -1812,15 +1811,8 @@ class SocketParameterServer:
                         state = _JobState(job, self.center)
                         self._jobs[job] = state
                         self.jobs_admitted += 1
-                        n_jobs += 1
             if state is None:
                 self.jobs_rejected += 1
-        if obs.enabled():
-            if state is not None:
-                obs.counter("ps_jobs_admitted_total", **self._mlabels).inc()
-                obs.gauge("ps_active_jobs", **self._mlabels).set(n_jobs)
-            else:
-                obs.counter("ps_jobs_rejected_total", **self._mlabels).inc()
         return (state is not None), reason, state
 
     def _job_commit_one(self, state: _JobState, delta: Sequence[np.ndarray],
@@ -2209,7 +2201,6 @@ class SocketParameterServer:
                 if joined:
                     self._member_touch(member_token)
                 telemetry = obs.enabled()
-                t0 = time.perf_counter() if telemetry else 0.0
                 if action == net.ACTION_PULL:
                     if job_rejected:
                         raise net.ProtocolError(
@@ -2231,9 +2222,6 @@ class SocketParameterServer:
                             obs.counter("ps_pull_bytes_total",
                                         **self._mlabels).inc(
                                 self._center_bytes)
-                            obs.histogram("ps_rpc_seconds", rpc="pull",
-                                          **self._mlabels).observe(
-                                time.perf_counter() - t0)
                         continue
                     if self._standby and not self._synced.is_set():
                         # same rule as commits: seed weights must never be
@@ -2260,9 +2248,6 @@ class SocketParameterServer:
                         obs.counter("ps_pulls_total", **self._mlabels).inc()
                         obs.counter("ps_pull_bytes_total",
                                     **self._mlabels).inc(self._center_bytes)
-                        obs.histogram("ps_rpc_seconds", rpc="pull",
-                                      **self._mlabels).observe(
-                            time.perf_counter() - t0)
                 elif action in (net.ACTION_COMMIT, net.ACTION_QCOMMIT):
                     if job_rejected:
                         raise net.ProtocolError(
@@ -2291,9 +2276,6 @@ class SocketParameterServer:
                             obs.counter("ps_commit_bytes_total",
                                         **self._mlabels).inc(
                                 sum(b.nbytes for b in blobs))
-                            obs.histogram("ps_rpc_seconds", rpc="commit",
-                                          **self._mlabels).observe(
-                                time.perf_counter() - t0)
                             obs.gauge("ps_staleness", conn=str(conn_idx),
                                       **self._mlabels).set(staleness)
                             obs.histogram("ps_commit_staleness",
@@ -2350,9 +2332,6 @@ class SocketParameterServer:
                         obs.counter("ps_commit_bytes_total",
                                     **self._mlabels).inc(
                             sum(b.nbytes for b in blobs))
-                        obs.histogram("ps_rpc_seconds", rpc="commit",
-                                      **self._mlabels).observe(
-                            time.perf_counter() - t0)
                         # per-connection staleness: commits the hub applied
                         # between this worker's last pull and its commit —
                         # the quantity DynSGD scales by, now visible for
@@ -2401,9 +2380,9 @@ class SocketParameterServer:
                     if telemetry:
                         obs.counter("ps_pulls_total", **self._mlabels).inc()
                         # raw tensor bytes, the same basis the dense pull
-                        # (_center_bytes) and both commit paths use — the
-                        # bench's sparse-vs-dense ratio must not compare
-                        # framed bytes against raw bytes
+                        # (_center_bytes) and both commit paths use: a
+                        # sparse-vs-dense ratio must not compare framed
+                        # bytes against raw bytes
                         obs.counter("ps_pull_bytes_total",
                                     **self._mlabels).inc(
                             sum(a.nbytes for a in arrays))
@@ -2412,9 +2391,6 @@ class SocketParameterServer:
                         obs.counter("ps.sparse_wire_bytes_saved",
                                     **self._mlabels).inc(
                             max(0, self._frame_bytes - sp_enc.frame_len))
-                        obs.histogram("ps_rpc_seconds", rpc="pull",
-                                      **self._mlabels).observe(
-                            time.perf_counter() - t0)
                 elif action in (net.ACTION_SPARSE_COMMIT,
                                 net.ACTION_SPARSE_QCOMMIT):
                     if job_state is not None or job_rejected:
@@ -2466,9 +2442,6 @@ class SocketParameterServer:
                         obs.counter("ps.sparse_wire_bytes_saved",
                                     **self._mlabels).inc(
                             max(0, dense_equiv - wire))
-                        obs.histogram("ps_rpc_seconds", rpc="commit",
-                                      **self._mlabels).observe(
-                            time.perf_counter() - t0)
                         obs.gauge("ps_staleness", conn=str(conn_idx),
                                   **self._mlabels).set(staleness)
                         obs.histogram("ps_commit_staleness",
@@ -2699,7 +2672,6 @@ class SocketParameterServer:
                 "pull_direct from a never-synced standby refused "
                 "(it holds no job state yet); wait_synced() first")
         telemetry = obs.enabled()
-        t0 = time.perf_counter() if telemetry else 0.0
         # the inproc call runs IN the worker's thread, so the committing
         # worker's thread-local trace context IS the right attribution
         with obs.span("ps.handle_pull", transport="inproc",
@@ -2709,9 +2681,6 @@ class SocketParameterServer:
                 clock = self._clock
         if telemetry:
             obs.counter("ps_pulls_total", **self._mlabels).inc()
-            obs.histogram("ps_rpc_seconds", rpc="pull.inproc",
-                          **self._mlabels).observe(
-                time.perf_counter() - t0)
         return snapshot, clock
 
     def commit_direct(self, delta: Sequence[np.ndarray], last_pull_clock: int) -> None:
@@ -2724,7 +2693,6 @@ class SocketParameterServer:
                 raise ValueError(f"commit tensor size {np.asarray(d).size} != "
                                  f"center size {c.size}")
         telemetry = obs.enabled()
-        t0 = time.perf_counter() if telemetry else 0.0
         if self._standby:
             if not self._synced.is_set():
                 # same rule as the socket path: a never-synced standby has
@@ -2759,9 +2727,6 @@ class SocketParameterServer:
                                  "staleness", staleness)
         if telemetry:
             obs.counter("ps_commits_total", **self._mlabels).inc()
-            obs.histogram("ps_rpc_seconds", rpc="commit.inproc",
-                          **self._mlabels).observe(
-                time.perf_counter() - t0)
             obs.histogram("ps_commit_staleness",
                           **self._mlabels).observe(staleness)
 
@@ -2786,7 +2751,6 @@ class SocketParameterServer:
             np.asarray(ids, net.ROW_ID_DTYPE), i)
             for ids, i in zip(ids_list, self.sparse_leaves)]
         telemetry = obs.enabled()
-        t0 = time.perf_counter() if telemetry else 0.0
         rows_pulled = int(sum(ids.size for ids in ids_list))
         with obs.span("ps.handle_pull", transport="inproc",
                       sparse_rows=rows_pulled, **self._shard_attrs,
@@ -2805,8 +2769,6 @@ class SocketParameterServer:
             obs.counter("ps_pulls_total", **self._mlabels).inc()
             obs.counter("ps.sparse_rows_pulled",
                         **self._mlabels).inc(rows_pulled)
-            obs.histogram("ps_rpc_seconds", rpc="pull.inproc",
-                          **self._mlabels).observe(time.perf_counter() - t0)
         return values, clock
 
     def commit_sparse_direct(self, parts: Sequence[Any],
@@ -2832,7 +2794,6 @@ class SocketParameterServer:
             else:
                 norm.append(np.asarray(p, np.float32).reshape(c.shape))
         telemetry = obs.enabled()
-        t0 = time.perf_counter() if telemetry else 0.0
         if self._standby:
             if not self._synced.is_set():
                 raise RuntimeError(
@@ -2859,8 +2820,6 @@ class SocketParameterServer:
             obs.counter("ps_commits_total", **self._mlabels).inc()
             obs.counter("ps.sparse_rows_committed",
                         **self._mlabels).inc(rows_committed)
-            obs.histogram("ps_rpc_seconds", rpc="commit.inproc",
-                          **self._mlabels).observe(time.perf_counter() - t0)
             obs.histogram("ps_commit_staleness",
                           **self._mlabels).observe(staleness)
 
@@ -3388,9 +3347,8 @@ class _HotTierCacheSurface:
         return sum(lru.misses for lru in self._lru.values())
 
     def sparse_cache_bytes(self) -> int:
-        """Host bytes the sparse-table caches hold — the number the
-        hyperscale bench tripwire compares against the full-vocabulary
-        footprint."""
+        """Host bytes the sparse-table caches hold (compare against the
+        full-vocabulary footprint)."""
         if self._cache_rows is not None:
             return sum(lru.nbytes() for lru in self._lru.values())
         total = sum(c.nbytes for c in self._cache.values())
@@ -4575,6 +4533,9 @@ class InprocPSClient(_HotTierCacheSurface):
                           if compress else None)
         self._last_pull_clock = 0
         self._pulled: Optional[List[np.ndarray]] = None
+        # (leaf, ids, rows) of a sparse pull not yet taken: merged into
+        # the cache by wait_weights()
+        self._pending_rows: List[Tuple[int, np.ndarray, np.ndarray]] = []
         # what the health plane's TRANS column reports for this worker
         # (PSClient: "tcp"/"shm" depending on the attach negotiation)
         self.transport = "inproc"
@@ -4650,13 +4611,18 @@ class InprocPSClient(_HotTierCacheSurface):
                         (time.perf_counter() - t0) * 1e3)
                 return
             values, clock = self.ps.pull_sparse_direct(ids_list)
+            # the rows are fetched NOW (the center a socket pull sent here
+            # would see) but merged into the cache only when the caller
+            # takes the weights, as PSClient does: the cache arrays are
+            # what the previous pull handed out, and a pipelined caller's
+            # window program may still be reading them (on the CPU
+            # backend jax.device_put can alias a numpy buffer)
             result: List[np.ndarray] = []
             si = 0
             for i, v in enumerate(values):
                 if i in self._sparse_set:
-                    ids = ids_list[si]
-                    if ids.size:
-                        self._cache[i][ids] = v
+                    if ids_list[si].size:
+                        self._pending_rows.append((i, ids_list[si], v))
                     result.append(self._cache[i])
                     si += 1
                 else:
@@ -4680,6 +4646,9 @@ class InprocPSClient(_HotTierCacheSurface):
         if self._pulled is None:
             raise RuntimeError("wait_weights() with no pull in flight")
         pulled, self._pulled = self._pulled, None
+        for i, ids, rows in self._pending_rows:
+            self._cache[i][ids] = rows
+        self._pending_rows.clear()
         return pulled
 
     def commit_nowait(self, delta: Sequence[np.ndarray],

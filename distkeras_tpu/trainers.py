@@ -64,8 +64,8 @@ class Trainer:
         self.seed = seed
         # bound host->device feeding to this many windows per transfer
         # (None = whole epoch in one transfer, the small-data fast path;
-        # "auto" = size chunks near DEFAULT_CHUNK_BUDGET_BYTES — the feed
-        # bench's promoted chunk_mb — resolved per dataset at train time)
+        # "auto" = size chunks near DEFAULT_CHUNK_BUDGET_BYTES, resolved
+        # per dataset at train time)
         if chunk_windows is None or chunk_windows == "auto":
             self.chunk_windows = chunk_windows
         else:
@@ -266,9 +266,9 @@ class Trainer:
         ``jax.device_count()``, which would under-report per-chip rate when
         fewer replicas than visible devices are in use.
 
-        Mirrored into the process telemetry registry (when enabled) under
-        the ``trainer`` label, so a snapshot pulled off a running job sees
-        the same per-epoch numbers this list accumulates."""
+        The per-epoch numbers stay in ``self.metrics``; the process
+        telemetry registry (when enabled) counts the epochs under the
+        ``trainer`` label."""
         rate = round(samples / max(seconds, 1e-9) / max(chips, 1), 1)
         self.metrics.append({
             "epoch": epoch,
@@ -278,11 +278,8 @@ class Trainer:
             "samples_per_sec_per_chip": rate,
         })
         if obs.enabled():
-            name = type(self).__name__
-            obs.counter("trainer_epochs_total", trainer=name).inc()
-            obs.counter("trainer_samples_total", trainer=name).inc(samples)
-            obs.histogram("trainer_epoch_seconds", trainer=name).observe(seconds)
-            obs.gauge("trainer_samples_per_sec_per_chip", trainer=name).set(rate)
+            obs.counter("trainer_epochs_total",
+                        trainer=type(self).__name__).inc()
 
     def _record_window_losses(self, losses) -> None:
         """Append per-window mean losses to ``history`` and (when telemetry

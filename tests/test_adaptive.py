@@ -811,14 +811,7 @@ def test_plain_client_bytes_identical_against_replicated_adaptive_primary(
 # -- off-path parity + trainer integration -------------------------------------
 
 @pytest.mark.parametrize("trainer_name", [
-    # tier-1 keeps one cell per device_commit family (DOWNPOUR-delta and
-    # elastic-difference); the other three ride the slow suite — the
-    # PR-6 cheapest-cell convention
-    "AsyncADAG",
-    "AsyncAEASGD",
-    pytest.param("AsyncDOWNPOUR", marks=pytest.mark.slow),
-    pytest.param("AsyncDynSGD", marks=pytest.mark.slow),
-    pytest.param("AsyncEAMSGD", marks=pytest.mark.slow),
+    "AsyncADAG", "AsyncAEASGD", "AsyncDOWNPOUR", "AsyncDynSGD", "AsyncEAMSGD",
 ])
 def test_adaptive_off_constructs_zero_adaptive_machinery(
         trainer_name, toy_dataset, monkeypatch):
@@ -855,14 +848,12 @@ def _native_mark():
 
 
 # hub dimension (ISSUE 11): the C++ combiner's batch-of-one must equal
-# the plain apply too.  Tier-1 keeps the cheapest native cell (PR-6
-# convention); the second native cell rides the slow suite
+# the plain apply too
 @pytest.mark.parametrize("trainer_name,pipeline,native", [
     ("AsyncADAG", False, False),
     ("AsyncDynSGD", True, False),  # pipelined: nonzero self-staleness scales
     pytest.param("AsyncDynSGD", True, True, marks=_native_mark()),
-    pytest.param("AsyncADAG", False, True,
-                 marks=[_native_mark(), pytest.mark.slow]),
+    pytest.param("AsyncADAG", False, True, marks=_native_mark()),
 ])
 def test_adaptive_on_uncontended_trajectory_bit_equal(trainer_name, pipeline,
                                                       native, toy_dataset,
@@ -947,8 +938,6 @@ def test_adaptive_trainer_end_to_end(toy_dataset, fresh_health):
     assert any(w in ("0", "1") for w in workers), workers
 
 
-@pytest.mark.slow  # the inproc combiner path is tier-1-covered by the
-# commit_direct tests; this full-trainer cell rides the slow suite
 def test_adaptive_inproc_trainer_end_to_end(toy_dataset, fresh_health):
     import distkeras_tpu as dk
     from distkeras_tpu.models.base import Model, ModelSpec

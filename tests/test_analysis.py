@@ -667,8 +667,7 @@ def serve(transport="socket"):
     # and the real tree's guidance names only knobs that exist
     from distkeras_tpu.analysis.core import load_sources, python_files
 
-    real = load_sources(python_files(ROOT, ("distkeras_tpu",),
-                                     extra=("bench.py",)))
+    real = load_sources(python_files(ROOT, ("distkeras_tpu",)))
     assert not wire_parity.check_nie_knobs(real, ROOT)
 
 
@@ -738,8 +737,7 @@ def test_unused_import_packages_cover_the_historical_cells():
     """The consolidated pass must scan at least every tree the old
     per-package test cells scanned (plus the analysis package itself)."""
     assert {"observability", "runtime", ".", "tests", "data", "parallel",
-            "models", "ops", "examples", "bench",
-            "analysis"} <= set(ui.PACKAGES)
+            "models", "ops", "examples", "analysis"} <= set(ui.PACKAGES)
 
 
 # -- optional C++ linters (present-in-container only) --------------------------
@@ -1169,6 +1167,29 @@ def test_protocol_model_covers_full_registry():
 
 # -- lockset (dynamic) fixtures ------------------------------------------------
 
+def _run_together(fns):
+    """One thread per callable, 50 calls each, all alive at once:
+    the detector tells writers apart by ``threading.get_ident()``, which
+    CPython hands to the next thread as soon as one has ended — on a
+    loaded machine three short threads started in turn can be one ident,
+    and the attribute then never looks shared.  The barrier holds every
+    thread until the last has started."""
+    import threading
+
+    gate = threading.Barrier(len(fns))
+
+    def body(fn):
+        gate.wait()
+        for _ in range(50):
+            fn()
+
+    ts = [threading.Thread(target=body, args=(fn,)) for fn in fns]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+
+
 def test_lockset_declared_guard_violation_detected():
     import threading
 
@@ -1184,13 +1205,7 @@ def test_lockset_declared_guard_violation_detected():
             Victim,
             guarded_by={"Victim._count": ("Victim._lock", "")}) as chk:
         v = Victim()
-        ts = [threading.Thread(
-            target=lambda: [v.bump_racy() for _ in range(50)])
-            for _ in range(3)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join()
+        _run_together([v.bump_racy] * 3)
     assert any("declared guarded by Victim._lock" in f.message
                for f in chk.findings), [str(f) for f in chk.findings]
     assert all(f.rule == "lockset" for f in chk.findings)
@@ -1215,12 +1230,7 @@ def test_lockset_empty_intersection_on_undeclared_attr():
 
     with lockset.instrument(Victim) as chk:
         v = Victim()
-        ts = [threading.Thread(target=lambda fn=fn: [fn() for _ in range(50)])
-              for fn in (v.a, v.b, v.a)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join()
+        _run_together([v.a, v.b, v.a])
     assert any("lockset went EMPTY" in f.message for f in chk.findings), \
         [str(f) for f in chk.findings]
 
@@ -1284,7 +1294,6 @@ def test_lockset_run_is_inert_without_env(monkeypatch):
     assert lockset.enabled()
 
 
-@pytest.mark.slow
 def test_lockset_stress_harness_is_clean():
     """The DKT_LOCKSET gate: hammer commit/pull/sparse/replication/health
     concurrently under instrumentation — zero dynamic findings at HEAD
@@ -1394,7 +1403,7 @@ def test_dump_graph_emits_guarded_by_table(capsys):
 
 # -- TSAN wiring (ISSUE 14 sanitizer cell) -------------------------------------
 
-@pytest.mark.slow
+@pytest.mark.slow  # needs a g++ with -fsanitize=thread (skip-guarded)
 @pytest.mark.tsan
 def test_native_hub_is_tsan_clean(tmp_path):
     """Compile the C++ hub with ``-fsanitize=thread`` together with the

@@ -49,7 +49,6 @@ def test_beam_width_1_is_greedy(model):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 14 satellite): 7.5 s: exhaustive logprob oracle; beam_width_1/greedy parity stays in tier-1
 def test_beam_scores_are_true_logprobs_and_beat_greedy(model):
     """Every returned beam's score must equal the sequence's true total
     logprob under the model, and the best beam must score >= the greedy

@@ -97,7 +97,6 @@ def test_result_line_holds_exactly_the_keys_the_driver_reads():
                                "count": len(jax.devices())}}
 
 
-@pytest.mark.slow  # ~4 s: the README CNN's conv gradients compile slowly here
 def test_canary_phase_tiny():
     rec = chip_smoke.phase_canary(2, batch=4, window=2, windows=2)
     assert rec["platform"] == "cpu" and rec["workers"] == 2
@@ -109,7 +108,6 @@ def test_sync_lm_phase_tiny(tiny_lm):
     assert rec["mosaic_calls"] == 0 and rec["layers"] == 1
 
 
-@pytest.mark.slow  # ~5 s: three trainers, each compiling its window program
 def test_async_lm_phase_tiny():
     tiny_lm = chip_smoke.lm_model(**_TINY_LM)
     recs = chip_smoke.phase_async_lm(tiny_lm, 2, batch=2, window=2, windows=2)
@@ -119,13 +117,11 @@ def test_async_lm_phase_tiny():
     assert recs[0]["hub_updates"] == recs[2]["hub_updates"] == 4
 
 
-@pytest.mark.slow  # ~6 s: a real distkeras-ps subprocess imports JAX + Flax
 def test_ps_daemon_phase_tiny():
     rec = chip_smoke.phase_ps_daemon(2, batch=4, window=2, windows=2)
     assert rec["daemon_exit"] == 0
 
 
-@pytest.mark.slow  # ~7 s: interpreted Pallas kernels compile slowly on the CPU
 def test_kernel_phase_tiny():
     recs = chip_smoke.phase_kernels(
         flash_cases=(("flash_tiny", 64, 16, 1, False, ()),
@@ -155,7 +151,7 @@ def test_mosaic_kernel_names_are_read_from_a_tpu_lowering():
         "_bwd_fused_kernel", "_fwd_kernel"]
 
 
-@pytest.mark.slow  # ~30 s: all six multi-device sections compile on the CPU mesh
+@pytest.mark.slow  # 41 s alone: all six multi-device sections compile on the CPU mesh
 def test_multichip_phase_tiny():
     recs = chip_smoke.phase_multichip(4, ring_l_local=8, ring_heads=4,
                                       ring_kv_heads=2, ring_head_dim=16,

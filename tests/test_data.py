@@ -89,7 +89,7 @@ def test_label_index_transformer():
 
 
 def test_chunk_windows_for_budget():
-    """Budget helper (feed-bench promoted default): chunks sized near the
+    """Budget helper: chunks sized near the
     byte budget, floored at one window, loud on nonsense inputs."""
     from distkeras_tpu.data.dataset import (DEFAULT_CHUNK_BUDGET_BYTES,
                                             chunk_windows_for_budget)

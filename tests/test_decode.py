@@ -41,7 +41,6 @@ def test_prefill_logits_match_training_forward(model):
     assert np.all(np.asarray(cache2.k[:, :, 9:]) == 0)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 14 satellite): 7.8 s: whole-sequence incremental parity; the fused/greedy/quantized parity cells keep decode coverage in tier-1
 def test_incremental_decode_matches_full_forward(model):
     """Feeding tokens one at a time through the cache must give the same
     last-position logits as re-running the full prefix each time."""

@@ -652,7 +652,6 @@ def test_hub_kill_restart_recovery_end_to_end(toy_dataset, tmp_path):
         ps2.stop()
 
 
-@pytest.mark.slow
 def test_hub_sigkill_subprocess_soak(toy_dataset, tmp_path):
     """Soak: a REAL `distkeras-ps` process SIGKILLed mid-run and relaunched
     with --restore — the full deployment shape (process death, not an

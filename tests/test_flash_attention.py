@@ -181,7 +181,7 @@ def test_split_backward_fallback_matches_fused():
 
 
 def test_single_block_bwd_tier_selection():
-    """The round-5 wide tier: auto-select takes the single-block fused
+    """The wide tier: auto-select takes the single-block fused
     backward exactly when the forward runs full-length blocks (Lq = Lk
     <= 2048) past 1024, keeps the (1024, 1024) rung at 8k+, and sizes
     the scoped-vmem grant to the score-tile working set."""

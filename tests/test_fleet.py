@@ -77,13 +77,25 @@ def _wait_until(pred, timeout=10.0, interval=0.01):
 
 # -- the controller's decision rules -------------------------------------------
 
-def test_controller_spawns_on_regression_with_cooldown_and_cap():
+def test_controller_spawns_on_regression_with_cooldown_and_cap(monkeypatch):
+    import types
+
+    from distkeras_tpu.runtime import fleet_controller
+
+    # the controller's clock, one second after its origin: time.monotonic()
+    # counts from boot on Linux, and the first spawn must not wait out a
+    # cooldown since then (on a machine up for under an hour it did)
+    clock = [1.0]
+    monkeypatch.setattr(
+        fleet_controller, "time",
+        types.SimpleNamespace(monotonic=lambda: clock[0], time=time.time))
     mon = _monitor()
     spawned = []
     fc = FleetController(mon, spawn_fn=spawned.append,
                          cooldown_s=3600.0, max_spawns=8)
     try:
         mon.emit("throughput_regression", dedup="a", ratio=0.5)
+        clock[0] += 3599.0
         mon.emit("throughput_regression", dedup="b", ratio=0.4)
         # the second firing lands inside the spawn cooldown: one spawn
         assert spawned == [None]
@@ -189,7 +201,6 @@ def test_spot_preemption_plan_fires_once_per_pair():
     assert ei.value.deadline_s == 3.0
     plan.hook(1, 2)  # the respawned replacement replays the window freely
     assert plan.fired == [(1, 2)]
-    assert len(plan.fired_at) == 1
 
 
 # -- multi-job admission + namespace isolation ---------------------------------
@@ -381,6 +392,11 @@ def test_two_job_isolation_drill_and_fairness_report(fresh_health):
         assert info["num_updates"] == 0  # the default center never moved
         assert all(float(np.abs(c).sum()) == 0.0 for c in ps.center)
 
+        # a handler records its ps.handle_commit span on leaving it, AFTER
+        # the ack its client's drain() returned on: wait for the last one
+        assert _wait_until(lambda: sum(
+            e.get("name") == "ps.handle_commit"
+            for e in obs.TRACER.events()) >= 4 * commits_per_worker)
         report = fleet_report(events=obs.TRACER.events())
         jobs = report["jobs"]
         assert sorted(jobs["per_job"]) == ["jobA", "jobB"]

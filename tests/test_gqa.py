@@ -107,8 +107,8 @@ def test_quantized_cache_gqa(gqa_model):
 
 
 def test_quantized_cache_gqa_warns_net_loss(gqa_model):
-    """int8 KV x GQA is a measured 13% net loss (94.9k -> 82.4k tok/s at
-    b64, BASELINE.md round 5) that composes silently in config — every
+    """int8 KV x GQA (a measured net loss in a July 2026 record, see
+    ``warn_quantized_cache_gqa``) composes silently in config — every
     decode builder must emit the documented warning, and must NOT emit it
     for int8-on-MHA or GQA-without-int8 (issue 2 satellite)."""
     import warnings as _warnings

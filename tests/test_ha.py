@@ -1117,7 +1117,6 @@ def test_kill_primary_mid_run_failover_adag(pipeline):
 
 
 @pytest.mark.chaos
-@pytest.mark.slow
 @pytest.mark.parametrize("trainer_name",
                          ["AsyncDOWNPOUR", "AsyncDynSGD", "AsyncAEASGD",
                           "AsyncEAMSGD"])
@@ -1127,7 +1126,6 @@ def test_kill_primary_mid_run_failover_matrix(trainer_name):
 
 
 @pytest.mark.chaos
-@pytest.mark.slow
 def test_kill_primary_sigkill_subprocess(tmp_path):
     """The deployment-shaped drill: a REAL distkeras-ps primary process
     SIGKILLed mid-run, a distkeras-ps --replica-of standby in-process

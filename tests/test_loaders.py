@@ -22,7 +22,7 @@ def test_mnist_flatten():
     assert train["features"].shape == (60000, 784)
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
+@pytest.mark.slow  # 55-63 s alone: two 50,000-image synthetic sets are generated
 def test_cifar_shapes():
     train, test, info = load_cifar10()
     assert train["features"].shape == (50000, 32, 32, 3)

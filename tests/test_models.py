@@ -21,7 +21,6 @@ def test_forward_shapes(spec_fn, batch_shape, out_shape):
     assert model.apply(x).shape == out_shape
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 14 satellite): 8.1 s: compiles the full ResNet-20 graph; transformer/cnn forwards keep model coverage in tier-1
 def test_resnet20_forward():
     model = Model.init(resnet20_spec(num_outputs=100), seed=0)
     x = np.zeros((2, 32, 32, 3), dtype=np.float32)
@@ -46,7 +45,6 @@ def test_spec_dict_roundtrip():
     assert ModelSpec.from_dict(spec.to_dict()) == spec
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
 def test_transformer_remat_matches_non_remat():
     """remat=True must be a pure memory trade: identical loss and grads."""
     import jax
@@ -141,7 +139,7 @@ def test_model_summary():
     assert f"{want:,} params" in s
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
+@pytest.mark.slow  # 64-73 s alone: four classic families, each compiled in two dtypes
 def test_compute_dtype_policy_parity_classic_family():
     """bf16-compute CNN/MLP/ResNet: identical float32 param trees (the
     policy touches activations only), logits within bf16 rounding of the

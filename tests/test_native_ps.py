@@ -608,8 +608,8 @@ def _feed_pair(primary_native, standby_native):
 
 @pytest.mark.parametrize("primary_native,standby_native", [
     (True, False),
-    pytest.param(False, True, marks=pytest.mark.slow),
-    pytest.param(True, True, marks=pytest.mark.slow),
+    (False, True),
+    (True, True),
 ])
 def test_native_replication_centers_track(primary_native, standby_native):
     """Hub implementations mix freely across the R feed: the standby's

@@ -329,21 +329,6 @@ def test_prefetch_raises_when_producer_dies_without_sentinel(monkeypatch):
         next(it)
 
 
-def test_head_recompute_factor_formula():
-    from distkeras_tpu.parallel.pipeline import head_recompute_factor
-
-    # round 6: the 1F1B head + CE runs in a lax.cond taken only on the
-    # last rank's valid backward units — exactly M evaluations per step,
-    # same as GPipe, so the factor is 1.0 at EVERY (pp, M).  The round-5
-    # where-masked schedule measured pp * (1 + 2(pp-1)/M); if this
-    # assertion ever needs a formula again, head recompute came back
-    assert head_recompute_factor(1, 8) == 1.0
-    assert head_recompute_factor(2, 8) == 1.0
-    assert head_recompute_factor(4, 8) == 1.0
-    with pytest.raises(ValueError):
-        head_recompute_factor(0, 8)
-
-
 def test_punchcard_telemetry_action(telemetry, tmp_path):
     from distkeras_tpu.runtime.job_deployment import Punchcard, fetch_telemetry
 
@@ -912,7 +897,7 @@ def test_disabled_telemetry_hot_path_makes_zero_registry_calls(monkeypatch):
 
 @pytest.mark.parametrize("package", ["observability", "runtime", ".", "tests",
                                      "data", "parallel", "models", "ops",
-                                     "examples", "bench", "analysis"])
+                                     "examples", "analysis"])
 def test_package_is_lint_clean(package):
     """Satellite (PR 5, extended package-by-package through PR 10, and
     consolidated by PR 12): ruff-clean check scoped to the instrumented

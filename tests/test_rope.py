@@ -57,7 +57,7 @@ def test_rotation_properties():
         rope_rotate(x[..., :15], pos)
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
+@pytest.mark.slow  # 30-36 s alone: thirty un-jitted Adam steps
 def test_rope_tree_has_no_table_and_model_learns():
     model = Model.init(_rope_spec(), seed=0)
     assert "pos_embed" not in model.params

@@ -537,18 +537,11 @@ def _reference(trainer_name):
     return _REFERENCE_CACHE[trainer_name]
 
 
-# tier-1 keeps ADAG's full 2x2 plus every trainer on the cheapest cell;
-# the full suite (-m slow) runs the remaining 12 matrix cells
-_MATRIX = []
-for _name in _ALL_TRAINERS:
-    for _transport in ("socket", "inproc"):
-        for _hub in ("python", "native"):
-            fast = (_name == "AsyncADAG"
-                    or (_transport == "inproc" and _hub == "python"))
-            _MATRIX.append(pytest.param(
-                _name, _transport, _hub,
-                marks=() if fast else pytest.mark.slow,
-                id=f"{_name}-{_transport}-{_hub}"))
+_MATRIX = [pytest.param(_name, _transport, _hub,
+                        id=f"{_name}-{_transport}-{_hub}")
+           for _name in _ALL_TRAINERS
+           for _transport in ("socket", "inproc")
+           for _hub in ("python", "native")]
 
 
 @pytest.mark.parametrize("trainer_name,transport,hub", _MATRIX)

@@ -562,21 +562,16 @@ def _native_mark():
 
 
 # hub dimension (ISSUE 11): the C++ hub serves the sparse wire plane, so
-# THE acceptance pin runs against both implementations.  Tier-1 keeps the
-# cheapest native cell (PR-6 convention); the rest of the native matrix
-# rides the slow suite
+# THE acceptance pin runs against both implementations
 @pytest.mark.parametrize("compress,pipeline,epochs,hub", [
     (None, True, 1, "python"),
-    pytest.param(None, False, 2, "python", marks=pytest.mark.slow),
+    (None, False, 2, "python"),
     ("int8", True, 1, "python"),
-    pytest.param("int8", False, 2, "python", marks=pytest.mark.slow),
+    ("int8", False, 2, "python"),
     pytest.param(None, True, 1, "native", marks=_native_mark()),
-    pytest.param("int8", True, 1, "native",
-                 marks=[_native_mark(), pytest.mark.slow]),
-    pytest.param(None, False, 2, "native",
-                 marks=[_native_mark(), pytest.mark.slow]),
-    pytest.param("int8", False, 2, "native",
-                 marks=[_native_mark(), pytest.mark.slow]),
+    pytest.param("int8", True, 1, "native", marks=_native_mark()),
+    pytest.param(None, False, 2, "native", marks=_native_mark()),
+    pytest.param("int8", False, 2, "native", marks=_native_mark()),
 ])
 def test_sparse_vs_dense_full_touch_bit_parity(compress, pipeline, epochs,
                                                hub):

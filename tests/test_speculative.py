@@ -34,7 +34,6 @@ def test_matches_target_greedy_with_good_draft(target):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
 def test_matches_target_greedy_with_unrelated_draft(target):
     """Draft = a differently-seeded small model: proposals mostly rejected,
     output STILL equal (correctness never depends on draft quality)."""
@@ -61,7 +60,6 @@ def test_quantized_draft_still_exact(target):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
 def test_quantized_kv_cache_matches_plain_quantized_decode(target):
     """quantize_cache speculative == plain decode with the SAME int8
     cache rounding: both attend over identically-quantized K/V rows, so
@@ -86,7 +84,6 @@ def test_quantized_kv_cache_matches_plain_quantized_decode(target):
                                      draft_step_impl="fused")
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
 def test_batched_matches_per_row_greedy(target):
     """Batched lockstep commit: every row of a batch-3 speculative decode
     equals that row's own plain greedy decode, for a good AND a bad
@@ -108,7 +105,6 @@ def test_batched_matches_per_row_greedy(target):
     assert int(iters) == -(-(10 - 1) // 4)  # ceil((n-1)/(k+1))
 
 
-@pytest.mark.slow  # tier-1 budget fix (PR 11): heaviest cells ride the full suite
 def test_eos_matches_plain_decode_and_exits_early(target):
     """EOS semantics equal make_generate_fn's exactly — EOS kept, pads
     after, per row — for eos ids that fire at different points (or never),

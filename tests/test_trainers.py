@@ -122,7 +122,6 @@ def test_metrics_recorded_single_and_distributed(toy_dataset):
         assert t.metrics[0]["samples"] <= len(toy_dataset)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 14 satellite): 22.6 s, the single heaviest tier-1 cell: full jax profiler trace of a training run
 def test_profile_dir_writes_trace(toy_dataset, tmp_path):
     import os
 

@@ -47,7 +47,7 @@ def _assert_bit_identical(run_a, run_b):
 @pytest.mark.parametrize("trainer_name,pipeline,extra", [
     ("AsyncADAG", True, {}),
     ("AsyncADAG", False, {}),
-    pytest.param("AsyncAEASGD", True, {"rho": 2.0}, marks=pytest.mark.slow),
+    ("AsyncAEASGD", True, {"rho": 2.0}),
 ])
 def test_inproc_matches_socket_bit_identical(trainer_name, pipeline, extra,
                                              toy_dataset):
@@ -60,7 +60,6 @@ def test_inproc_matches_socket_bit_identical(trainer_name, pipeline, extra,
     _assert_bit_identical(sock, inproc)
 
 
-@pytest.mark.slow  # full-suite coverage; tier-1 keeps the f32 parity pins
 def test_inproc_matches_socket_with_int8_commits(toy_dataset):
     """The inproc client round-trips commits through the SAME quantize/
     dequantize + error-feedback math the wire uses, so compressed runs
@@ -72,7 +71,6 @@ def test_inproc_matches_socket_with_int8_commits(toy_dataset):
     _assert_bit_identical(sock, inproc)
 
 
-@pytest.mark.slow  # full-suite coverage; tier-1 keeps the f32 parity pins
 def test_inproc_multiworker_learns(toy_dataset):
     """inproc with real worker concurrency end to end: 4 workers race
     commit_direct under the hub lock and the center still learns."""
@@ -124,7 +122,6 @@ def test_shm_matches_socket_bit_identical(pipeline, toy_dataset):
     _assert_bit_identical(sock, shm)
 
 
-@pytest.mark.slow  # full-suite coverage; tier-1 keeps the f32 parity pins
 def test_shm_matches_socket_with_int8_commits(toy_dataset):
     """Quantized commits cross the rings bit-identically too, and a
     batched-receive hub (recv_batch_depth) changes syscall shape only —
