@@ -24,6 +24,9 @@ TELEMETRY_NAMES = frozenset({
     #    these; runtime/native.py maps the C++ stat keys onto them) ------------
     "ps_commits_total", "ps_pulls_total",
     "ps_commit_bytes_total", "ps_pull_bytes_total",
+    # client: prefetched pull replies claimed by land_weights, beside the
+    # window program (not by the commit's guard, not by wait_weights)
+    "ps_pulls_landed_early_total",
     "ps_fenced_commits_total", "ps_idle_evictions_total",
     "ps_live_workers", "ps_staleness", "ps_commit_staleness",
     "ps_snapshots_total", "ps_snapshot_sets_total",
@@ -75,8 +78,8 @@ TELEMETRY_NAMES = frozenset({
     "async.window",
     # -- leaf phases (obs.phase: ring + jax.profiler TraceAnnotation) ----------
     # worker loop, one set a window; seed and drain once a call
-    "async.pull_wait", "async.h2d", "async.dispatch", "async.device_wait",
-    "async.commit_d2h", "async.drain", "async.seed",
+    "async.pull_wait", "async.h2d", "async.dispatch", "async.pull_land",
+    "async.device_wait", "async.commit_d2h", "async.drain", "async.seed",
     # PS client inside ps.commit; hub handler thread and center lock
     "ps.commit_drain", "ps.commit_pack", "ps.commit_send",
     "ps.recv_commit", "ps.send_weights", "ps.apply",

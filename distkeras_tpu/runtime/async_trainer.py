@@ -21,8 +21,10 @@ Differences from the reference's execution (same semantics, new substrate):
   zero-copy flat framing path (one preallocated frame per direction,
   ``recv_into`` scatter receives; ``networking.FlatFrameCodec``);
 - the exchange is PIPELINED by default (``pipeline=True``): the pull for
-  window k+1 is prefetched while window k computes and commit acks
-  coalesce into later receives, so wall-per-window converges toward
+  window k+1 is requested right after window k's program is dispatched
+  and its reply is received on the worker's thread while that program
+  runs (``client.land_weights()``, phase ``async.pull_land``); commit acks
+  coalesce into that receive, so wall-per-window converges toward
   max(compute, wire) instead of their sum (staleness semantics:
   ARCHITECTURE.md "Async transport");
 - co-located workers may skip sockets entirely with ``transport="inproc"``
@@ -1105,9 +1107,10 @@ class AsyncDistributedTrainer(Trainer):
                                 # window's program runs: the request leaves
                                 # now (jax dispatch is async) and the
                                 # weights stream into the other landing
-                                # buffer under the compute — the center it
-                                # snapshots predates this window's commit
-                                # below (self-staleness 1; ARCHITECTURE.md)
+                                # buffer under the compute (async.pull_land
+                                # below) — the center it snapshots predates
+                                # this window's commit (self-staleness 1;
+                                # ARCHITECTURE.md)
                                 last_window = (w == n_windows - 1
                                                and epoch == self.num_epoch - 1)
                                 if pipeline and not last_window:
@@ -1127,6 +1130,14 @@ class AsyncDistributedTrainer(Trainer):
                                     else:
                                         client.pull_nowait()
                                         pull_pending = True
+                            # this thread has nothing to do until the
+                            # program is done, and the hub's send of the
+                            # prefetched reply moves only while this end
+                            # reads: receive it now, beside the compute,
+                            # not between the commit's copy-out and send
+                            if pull_pending:
+                                with obs.phase("async.pull_land"):
+                                    client.land_weights()
                             if telemetry:
                                 # the one statement only telemetry runs:
                                 # it splits the wait for the window program

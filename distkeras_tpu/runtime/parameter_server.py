@@ -3364,7 +3364,8 @@ class PSClient(_HotTierCacheSurface):
     """Worker-side connection: ``pull()`` / ``commit(delta)`` (reference:
     ``NetworkWorker.pull/commit``, SURVEY §2.10) — plus the pipelined
     fire-and-forget API the async hot path runs on
-    (``pull_nowait`` / ``wait_weights`` / ``commit_nowait`` / ``drain``).
+    (``pull_nowait`` / ``land_weights`` / ``wait_weights`` /
+    ``commit_nowait`` / ``drain``).
 
     Framing is the zero-copy flat path (:class:`~.networking.FlatFrameCodec`):
     commits leave through one preallocated frame buffer (one memcpy per
@@ -3377,8 +3378,11 @@ class PSClient(_HotTierCacheSurface):
 
     Pipelining: the nowait methods send a request and record the expected
     reply in a FIFO; replies are consumed lazily, in wire order, by
-    ``wait_weights``/``drain`` — commit acks coalesce into the next
-    weights receive instead of costing their own blocking round trip.  At
+    ``land_weights``/``wait_weights``/``drain`` — commit acks coalesce into
+    the next weights receive instead of costing their own blocking round
+    trip.  ``land_weights`` is the receive a caller places where its thread
+    has nothing else to do (beside the window program): a reply larger than
+    the socket's buffers crosses the wire only while this end reads.  At
     most ``max_inflight`` commits ride unacknowledged (enforced by
     consuming replies before sending more: wire back-pressure, not an
     unbounded queue).  After any mid-frame error the stream is
@@ -3420,8 +3424,10 @@ class PSClient(_HotTierCacheSurface):
 
     Telemetry (client side): ``ps.commit_bytes`` wire bytes,
     ``ps.pull_latency_ms`` / ``ps.commit_latency_ms`` send-to-reply-
-    consumed latencies, ``ps.pull_stall_ms`` time actually BLOCKED waiting
-    for weights (the post-overlap stall the trainer pays),
+    consumed latencies, ``ps.pull_stall_ms`` time BLOCKED receiving
+    weights (in ``wait_weights`` and ``commit_nowait``'s guard the stall the
+    trainer pays; in ``land_weights`` it lies beside the device's compute),
+    ``ps_pulls_landed_early_total`` replies claimed by ``land_weights``,
     ``ps.serialize_ms`` frame-pack time, ``ps.inflight_depth`` unacked
     commits, ``ps.reconnects`` successful reconnections and
     ``ps.reconnect_ms`` fault-to-reconnected recovery time."""
@@ -3534,9 +3540,16 @@ class PSClient(_HotTierCacheSurface):
                 for _ in range(2))
         self._flip = 0
         # weights replies consumed off the wire but not yet claimed by
-        # wait_weights (commit_nowait pre-drains them — see below); two
-        # landing buffers bound this queue at two entries
-        self._ready: Deque[List[np.ndarray]] = deque()
+        # wait_weights (land_weights and commit_nowait's guard put them
+        # here); two landing buffers bound this queue at two entries.
+        # Each entry carries the full-cache rows its reply brought: they
+        # are written into ``_cache`` only at hand-out (_hand_out), since
+        # the cache arrays are what the PREVIOUS pull handed out and a
+        # caller that lands a reply early may have a window program still
+        # reading them (a device_put can alias, or still be copying, a
+        # numpy buffer)
+        self._ready: Deque[Tuple[List[np.ndarray],
+                                 List[Tuple[int, Any, np.ndarray]]]] = deque()
         self.host, self.port, self.timeout = host, int(port), timeout
         # failover address list (ISSUE 7): the primary's address first,
         # then each hot standby.  Reconnect attempts rotate through the
@@ -4054,9 +4067,13 @@ class PSClient(_HotTierCacheSurface):
 
     # -- pipelined API ---------------------------------------------------------
     def pull_nowait(self, sparse_rows: Optional[Sequence] = None) -> None:
-        """Fire a pull request; the reply is consumed later by
-        :meth:`wait_weights`.  Issue it while the device computes and the
-        weights' wire time hides under the window.
+        """Fire a pull request; the reply is consumed later — by
+        :meth:`land_weights` where the caller has time to spare beside
+        the device, else by ``commit_nowait``'s guard or by
+        :meth:`wait_weights`, which hands it out.  Issue it while the
+        device computes, then land it, and the weights' wire time hides
+        under the window: the hub has the reply packed when the request
+        arrives, but the bytes move only while this end reads.
 
         ``sparse_rows`` (sparse-configured clients only): one row-id array
         per sparse table — the pull moves only those rows (action ``S``),
@@ -4131,6 +4148,39 @@ class PSClient(_HotTierCacheSurface):
             self._resilient(
                 lambda: self._commit_nowait_once(delta, sparse_rows))
 
+    def land_weights(self) -> None:
+        """Claim every pull reply still in flight into its landing buffer
+        NOW, with whatever precedes it in the reply FIFO (the previous
+        commit's ack); :meth:`wait_weights` hands it out later without
+        touching the socket.  For the caller whose thread would otherwise
+        only wait for the device: right after ``pull_nowait``, while the
+        window program runs.  The hub's ``sendall`` of a reply larger
+        than the socket's buffers advances only while this end reads, so
+        a reply left for ``commit_nowait``'s guard crosses the wire AFTER
+        the program, on the caller's critical path.  What the reply holds
+        was fixed when the request arrived (the hub packs under its
+        lock), so landing early changes no value; the buffer written is
+        the one the previous ``wait_weights`` did NOT hand out.  With
+        nothing in flight it returns at once."""
+        before = len(self._ready)
+        self._resilient(self._claim_pending_weights)
+        landed = len(self._ready) - before
+        if landed and obs.enabled():
+            obs.counter("ps_pulls_landed_early_total",
+                        **self._mlabels).inc(landed)
+
+    def _claim_pending_weights(self) -> None:
+        t0, claimed = time.perf_counter(), False
+        while (self._has_pending(net.ACTION_WEIGHTS)
+               or self._has_pending(net.ACTION_SPARSE_WEIGHTS)):
+            self._consume_one()
+            claimed = True
+        if claimed and obs.enabled():
+            # the receive time is pull wire-wait, so it lands in
+            # ps.pull_stall_ms like any other pull block
+            obs.histogram("ps.pull_stall_ms", **self._mlabels).observe(
+                (time.perf_counter() - t0) * 1e3)
+
     def _commit_nowait_once(self, delta: Sequence[np.ndarray],
                             sparse_rows: Optional[Sequence] = None) -> None:
         # deadlock avoidance: never start a potentially-blocking large
@@ -4140,20 +4190,12 @@ class PSClient(_HotTierCacheSurface):
         # forever once frames outgrow the socket buffers.  Claim any
         # pending pull into its landing buffer first (wait_weights
         # hands it out later); the hub is then parked in recv when the
-        # commit bytes arrive.  This receive time is pull wire-wait,
-        # so it lands in ps.pull_stall_ms like any other pull block.
+        # commit bytes arrive.  A caller that landed its prefetch
+        # (land_weights, as the async worker loop does) leaves this
+        # guard nothing to claim; it stands for every caller that did not.
         # The three leaf phases below (drain, pack, send) split ps.commit.
         with obs.phase("ps.commit_drain"):
-            if self._has_pending(net.ACTION_WEIGHTS) \
-                    or self._has_pending(net.ACTION_SPARSE_WEIGHTS):
-                t_drain = time.perf_counter() if obs.enabled() else 0.0
-                while (self._has_pending(net.ACTION_WEIGHTS)
-                       or self._has_pending(net.ACTION_SPARSE_WEIGHTS)):
-                    self._consume_one()
-                if t_drain:
-                    obs.histogram("ps.pull_stall_ms",
-                                  **self._mlabels).observe(
-                        (time.perf_counter() - t_drain) * 1e3)
+            self._claim_pending_weights()
             while self._unacked() >= self.max_inflight:
                 self._consume_one()
         telemetry = obs.enabled()
@@ -4252,7 +4294,13 @@ class PSClient(_HotTierCacheSurface):
         if telemetry:
             obs.histogram("ps.pull_stall_ms", **self._mlabels).observe(
                 (time.perf_counter() - t0) * 1e3)
-        return self._ready.popleft()
+        return self._hand_out()
+
+    def _hand_out(self) -> List[np.ndarray]:
+        weights, merges = self._ready.popleft()
+        for i, ids, rows in merges:
+            self._cache[i][ids] = rows
+        return weights
 
     def _fill_ready_once(self) -> None:
         while not self._ready:
@@ -4265,9 +4313,11 @@ class PSClient(_HotTierCacheSurface):
 
     def drain(self) -> None:
         """Consume every outstanding reply — trailing commit acks at the end
-        of a run, plus any prefetched pull that will go unused."""
+        of a run, plus any prefetched pull that will go unused (the rows it
+        brought still join the full cache, as when it was received)."""
         self._resilient(self._drain_once)
-        self._ready.clear()
+        while self._ready:
+            self._hand_out()
         if obs.enabled():
             obs.gauge("ps.inflight_depth", **self._mlabels).set(0)
 
@@ -4327,8 +4377,9 @@ class PSClient(_HotTierCacheSurface):
         if kind == net.ACTION_SPARSE_WEIGHTS:
             # sparse pull reply: dense leaves scatter into the flip
             # landing buffers exactly like a full pull, row blocks land in
-            # per-pull scratch.  Full-cache mode merges them into the
-            # per-table caches and hands the caches out; hot-tier mode
+            # per-pull scratch.  Full-cache mode hands the per-table
+            # caches out and merges the blocks into them then, at
+            # hand-out (see ``_ready``); hot-tier mode
             # files the MISS rows into their result-block positions and
             # the LRU (hit rows were gathered at issue time), handing the
             # [k, dim] blocks out instead of full-shape tables
@@ -4358,6 +4409,7 @@ class PSClient(_HotTierCacheSurface):
             self._last_io = time.monotonic()  # lint: unguarded-ok receive leg runs outside the io lock by design; the _consuming flag excludes the heartbeat's round trips, and a racing timestamp store only under-reports idleness
             self._sparse_pull_ids.popleft()
             result: List[np.ndarray] = []
+            merges: List[Tuple[int, Any, np.ndarray]] = []
             si = 0
             misses0 = self.sparse_cache_misses
             for i in range(len(self.templates)):
@@ -4373,12 +4425,12 @@ class PSClient(_HotTierCacheSurface):
                     else:
                         ids = ids_list[si]
                         if ids.size:
-                            self._cache[i][ids] = out[i]
+                            merges.append((i, ids, out[i]))
                         result.append(self._cache[i])
                     si += 1
                 else:
                     result.append(out[i])
-            self._ready.append(result)
+            self._ready.append((result, merges))
             if cached:
                 _count_cache_misses(self, misses0)
             if obs.enabled():
@@ -4422,16 +4474,18 @@ class PSClient(_HotTierCacheSurface):
                 self._pending.appendleft((kind, t_sent))
                 raise
             self._last_io = time.monotonic()  # lint: unguarded-ok receive leg runs outside the io lock by design; the _consuming flag excludes the heartbeat's round trips, and a racing timestamp store only under-reports idleness
-            # a full pull re-seeds the sparse caches: the landing buffer
-            # is reused two pulls later, the cache is the stable copy the
-            # sparse exchange merges into.  Hot-tier mode seeds/refreshes
-            # its bounded LRU instead (_hot_tier_seed)
+            # a full pull re-seeds the sparse caches (at hand-out, like a
+            # sparse pull's rows): the landing buffer is reused two pulls
+            # later, the cache is the stable copy the sparse exchange
+            # merges into.  Hot-tier mode seeds/refreshes its bounded LRU
+            # instead (_hot_tier_seed), which no caller is ever handed
+            merges = []
             for i in self._sparse:
                 if self._cache_rows is None:
-                    self._cache[i][...] = out[i]
+                    merges.append((i, Ellipsis, out[i]))
                 else:
                     _hot_tier_seed(self, i, out[i])
-            self._ready.append(out)
+            self._ready.append((out, merges))
             if obs.enabled():
                 obs.histogram("ps.pull_latency_ms", **self._mlabels).observe(
                     (time.perf_counter() - t_sent) * 1e3)
@@ -4641,6 +4695,10 @@ class InprocPSClient(_HotTierCacheSurface):
         if telemetry:
             obs.histogram("ps.pull_latency_ms").observe(
                 (time.perf_counter() - t0) * 1e3)
+
+    def land_weights(self) -> None:
+        """Nothing is ever in flight here: ``pull_nowait`` copies the
+        center at issue (:meth:`PSClient.land_weights`)."""
 
     def wait_weights(self) -> List[np.ndarray]:
         if self._pulled is None:
@@ -5564,6 +5622,11 @@ class ShardedPSClient:
                 zip(self.shards, self._route_rows(sparse_rows))):
             self._stripe(sid, lambda c=client, l=local:
                          c.pull_nowait(sparse_rows=l))
+
+    def land_weights(self) -> None:
+        """Each stripe's :meth:`PSClient.land_weights`, in shard order."""
+        for sid, client in enumerate(self.shards):
+            self._stripe(sid, client.land_weights)
 
     def wait_weights(self) -> List[np.ndarray]:
         """Full-order weight list; each dense leaf aliases its shard

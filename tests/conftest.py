@@ -52,6 +52,17 @@ def require_tool(*names):
             _pytest.skip(f"no {name} in this container")
 
 
+def native_mark():
+    """``pytest.param(..., marks=native_mark())``: skip the case where the
+    C++ hub cannot be built here."""
+    import pytest as _pytest
+
+    from distkeras_tpu.runtime.native import build_error, native_available
+
+    return _pytest.mark.skipif(not native_available(),
+                               reason=f"native PS unavailable: {build_error()}")
+
+
 from distkeras_tpu.platform import pin_cpu_devices  # noqa: E402
 
 pin_cpu_devices(8)
@@ -81,3 +92,17 @@ def toy_dataset(toy_classification):
     x, y = toy_classification
     onehot = np.eye(2, dtype=np.float32)[y]
     return Dataset({"features": x, "label": onehot, "label_index": y})
+
+
+@pytest.fixture
+def telemetry():
+    """Enable the process-global registry/tracer for one test, leaving a
+    clean disabled slate afterwards (other tests must keep paying only the
+    disabled-mode branch)."""
+    from distkeras_tpu import observability as obs
+
+    obs.reset()
+    obs.enable()
+    yield obs
+    obs.disable()
+    obs.reset()

@@ -155,6 +155,49 @@ def test_chaos_truncate_desyncs_then_recovers():
         ps.stop()
 
 
+@pytest.mark.parametrize("kind,keep_bytes", [("sever", 0),
+                                             ("truncate", 40_000)])
+def test_fault_mid_land_restores_the_pull_and_reissues_it(kind, keep_bytes):
+    """The prefetched reply dies while ``land_weights()`` receives it (before
+    its first byte, or 40 kB into a 256 kB frame): the pending entry and the
+    landing-buffer flip are restored, the client reconnects INSIDE the call
+    and re-issues the pull, and the landed weights — the hub's current
+    center — go into the buffer the previous ``wait_weights()`` did not
+    hand out."""
+    n = 1 << 16
+    tmpl = [np.zeros((n,), np.float32)]
+    ps = DeltaParameterServer(tmpl)
+    ps.start()
+    # s2c frames: 0 the seed pull's reply, 1 the commit's ack, 2 the prefetch
+    plan = FaultPlan([Fault(conn=0, direction="s2c", frame=2, kind=kind,
+                            keep_bytes=keep_bytes)])
+    try:
+        with ChaosProxy("127.0.0.1", ps.port, plan) as proxy:
+            with PSClient("127.0.0.1", proxy.port, templates=tmpl,
+                          max_reconnects=5, reconnect_backoff=0.02,
+                          timeout=10.0) as c:
+                held = c.pull()
+                c.commit_nowait([np.ones((n,), np.float32)])
+                c.pull_nowait()
+                flip = c._flip
+                c.land_weights()
+                assert len(proxy.faults_fired) == 1
+                assert c.reconnects_used == 1
+                assert c._flip == flip ^ 1 and len(c._ready) == 1
+                assert not c._pending
+                np.testing.assert_array_equal(held[0], 0.0)
+                landed = c.wait_weights()
+                assert landed[0] is not held[0]
+                # the ack crossed before the fault: the commit is in the
+                # center the re-issued pull observed
+                np.testing.assert_array_equal(landed[0], 1.0)
+                c.commit([np.ones((n,), np.float32)])
+                np.testing.assert_array_equal(c.pull()[0], 2.0)
+        assert ps.num_updates == 2
+    finally:
+        ps.stop()
+
+
 # -- reconnect/backoff bounds --------------------------------------------------
 
 def test_reconnect_storm_bounded_by_budget_and_backoff():

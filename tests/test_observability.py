@@ -27,18 +27,6 @@ from distkeras_tpu.observability import (
 )
 
 
-@pytest.fixture
-def telemetry():
-    """Enable the process-global registry/tracer for one test, leaving a
-    clean disabled slate afterwards (other tests must keep paying only the
-    disabled-mode branch)."""
-    obs.reset()
-    obs.enable()
-    yield obs
-    obs.disable()
-    obs.reset()
-
-
 # -- registry semantics -------------------------------------------------------
 
 def test_counter_gauge_histogram_basics():
@@ -358,23 +346,30 @@ def test_punchcard_telemetry_action(telemetry, tmp_path):
 # -- end-to-end acceptance: AsyncADAG smoke run -------------------------------
 
 WINDOW_PHASES = ("async.pull_wait", "async.h2d", "async.dispatch",
-                 "async.device_wait", "async.commit_d2h", "ps.commit_drain",
-                 "ps.commit_pack", "ps.commit_send")
+                 "async.pull_land", "async.device_wait", "async.commit_d2h",
+                 "ps.commit_drain", "ps.commit_pack", "ps.commit_send")
 
 
 def _check_window_phases(events, n_windows):
     """Every ``async.window``'s worker-thread leaf phases lie inside it, in
     order, without overlap, carry its worker/epoch/window, and cover it but
-    for bookkeeping; the hub's ``ps.apply`` names the worker it served."""
+    for bookkeeping; the hub's ``ps.apply`` names the worker it served.
+    A worker's last window prefetches nothing, so it alone has no
+    ``async.pull_land``; ``ps.commit_drain`` (the guard) is in every one."""
     windows = [e for e in events if e["name"] == "async.window"]
     assert len(windows) == n_windows
+    last = {}
+    for win in windows:
+        last[win["tid"]] = max(last.get(win["tid"], 0), win["ts_us"])
     cover = []
     for win in windows:
         lo, hi = win["ts_us"], win["ts_us"] + win["dur_us"]
         mine = sorted((e for e in events if e["tid"] == win["tid"]
                        and e["name"] in WINDOW_PHASES and lo <= e["ts_us"] <= hi),
                       key=lambda e: e["ts_us"])
-        assert [e["name"] for e in mine] == list(WINDOW_PHASES)
+        assert [e["name"] for e in mine] == [
+            p for p in WINDOW_PHASES
+            if p != "async.pull_land" or win["ts_us"] != last[win["tid"]]]
         for e in mine:
             assert e["ts_us"] + e["dur_us"] <= hi + 2     # whole microseconds
             assert {k: e["attrs"][k] for k in ("worker", "epoch", "window")} \

@@ -10,6 +10,8 @@ import tracemalloc
 import numpy as np
 import pytest
 
+from conftest import native_mark
+from distkeras_tpu import observability as obs
 from distkeras_tpu.runtime import networking as net
 from distkeras_tpu.runtime.parameter_server import (
     ADAGParameterServer,
@@ -424,6 +426,105 @@ def test_pipelined_pull_buffers_double_buffer():
             w3 = c.pull()  # reuses w1's buffer
             assert w3[0] is w1[0]
             np.testing.assert_array_equal(w3[0], 2)
+    finally:
+        ps.stop()
+
+
+def _landed_counter():
+    return obs.snapshot()["counters"].get("ps_pulls_landed_early_total", 0.0)
+
+
+def _stall_samples():
+    h = obs.snapshot()["histograms"].get("ps.pull_stall_ms")
+    return h["count"] if h else 0
+
+
+@pytest.mark.parametrize("hub", [
+    "python", pytest.param("native", marks=native_mark())])
+def test_land_weights_claims_the_prefetched_reply(hub, telemetry):
+    """The worker loop's schedule by hand: ``pull_nowait(); land_weights()``
+    puts the reply (and the ack ahead of it) into the OTHER landing buffer
+    and ``_ready``; the commit's guard then finds nothing to claim and
+    ``wait_weights()`` hands the pull out without touching the socket.  The
+    buffer the previous ``wait_weights()`` handed out — the one a running
+    window program may still be reading — keeps its bytes."""
+    tmpl = [np.zeros((1 << 14,), np.float32)]
+    one = [np.ones((1 << 14,), np.float32)]
+    if hub == "native":
+        from distkeras_tpu.runtime import native
+
+        ps = native.NativeParameterServer(tmpl, mode=native.MODE_DELTA)
+    else:
+        ps = DeltaParameterServer(tmpl)
+    ps.start()
+    try:
+        with PSClient("127.0.0.1", ps.port, templates=tmpl) as c:
+            held = c.pull()
+            for k in range(4):
+                snapshot = held[0].copy()
+                c.pull_nowait()             # prefetch: predates commit k
+                c.land_weights()            # ...and lands beside "the program"
+                assert _landed_counter() == k + 1
+                assert len(c._ready) == 1
+                assert not any(kind in (net.ACTION_WEIGHTS,
+                                        net.ACTION_SPARSE_WEIGHTS)
+                               for kind, _ in c._pending)
+                np.testing.assert_array_equal(held[0], snapshot)
+                stalls = _stall_samples()
+                c.commit_nowait(one)
+                assert _stall_samples() == stalls   # the guard claimed nothing
+                sock, c.sock = c.sock, None    # any use of it raises
+                try:
+                    nxt = c.wait_weights()
+                finally:
+                    c.sock = sock
+                assert nxt[0] is not held[0]
+                # self-staleness 1: the snapshot misses THIS window's commit
+                np.testing.assert_array_equal(nxt[0], np.full(1 << 14, float(k)))
+                np.testing.assert_array_equal(held[0], snapshot)
+                held = nxt
+            c.drain()
+            np.testing.assert_array_equal(c.pull()[0], np.full(1 << 14, 4.0))
+        assert ps.num_updates == 4
+    finally:
+        ps.stop()
+
+
+def test_land_weights_with_nothing_pending_and_the_guard_stands(telemetry):
+    """With no pull in flight ``land_weights()`` returns at once, reads
+    nothing and counts nothing (acks stay queued: it is not a drain).  A
+    caller that never lands is still protected: ``commit_nowait`` claims the
+    pending reply BEFORE any commit byte leaves — and that is not an early
+    landing."""
+    tmpl = [np.zeros((4,), np.float32)]
+    one = [np.ones((4,), np.float32)]
+    ps = DeltaParameterServer(tmpl)
+    ps.start()
+    try:
+        with PSClient("127.0.0.1", ps.port, templates=tmpl) as c:
+            c.pull()
+            c.commit_nowait(one)            # one ack in flight, no pull
+            sock, c.sock = c.sock, None    # any use of it raises
+            try:
+                c.land_weights()
+            finally:
+                c.sock = sock
+            assert [kind for kind, _ in c._pending] == [net.ACTION_ACK]
+            assert not c._ready and _landed_counter() == 0
+
+            c.pull_nowait()
+            sent = []
+            real = c._codec.send_packed
+            c._codec.send_packed = lambda sock: (
+                sent.append([kind for kind, _ in c._pending]), real(sock))
+            stalls = _stall_samples()
+            c.commit_nowait(one)
+            # when the commit's bytes left, the weights reply had been claimed
+            assert sent == [[]] and len(c._ready) == 1
+            assert _stall_samples() == stalls + 1
+            assert _landed_counter() == 0
+            np.testing.assert_array_equal(c.wait_weights()[0], np.ones(4))
+            c.drain()
     finally:
         ps.stop()
 

@@ -398,6 +398,54 @@ def test_sharded_chaos_proxy_severs_one_stripe_and_client_recovers():
         ps.stop()
 
 
+@pytest.mark.parametrize("fault", [None, "sever"])
+def test_striped_client_lands_every_stripe(fault, telemetry):
+    """``ShardedPSClient.land_weights`` claims each stripe's prefetched
+    reply (counted per shard); the commit that follows finds no stripe's
+    reply pending and ``wait_weights()`` assembles the center from what
+    landed.  A stripe severed mid-land reconnects inside the call and
+    hands out its re-issued pull."""
+    from distkeras_tpu.runtime.faults import Fault, FaultPlan, ShardedChaosProxy
+
+    t = _templates()
+    ps, plan = _start_sharded(t, 3)
+    try:
+        fault_plan = FaultPlan(
+            [Fault(conn=0, frame=1, direction="s2c", kind="sever", shard=1)]
+            if fault else [])
+        with ShardedChaosProxy([("127.0.0.1", p) for p in ps.ports],
+                               plan=fault_plan) as proxy:
+            client = ShardedPSClient(
+                [("127.0.0.1", p) for p in proxy.ports], t, plan,
+                max_reconnects=3, reconnect_backoff=0.02)
+            with client:
+                held = client.pull()                       # s2c frame 0
+                snapshot = [w.copy() for w in held]
+                client.pull_nowait()                       # s2c frame 1
+                client.land_weights()
+                for c in client.shards:
+                    assert len(c._ready) == 1
+                    assert not c._has_pending(net.ACTION_WEIGHTS)
+                for w, snap in zip(held, snapshot):
+                    np.testing.assert_array_equal(w, snap)
+                counters = obs.snapshot()["counters"]
+                for sid in range(3):
+                    assert counters[
+                        f'ps_pulls_landed_early_total{{shard="{sid}"}}'] == 1.0
+                client.commit_nowait([np.ones(a.shape, np.float32)
+                                      for a in t])
+                for w in client.wait_weights():            # predates the commit
+                    np.testing.assert_array_equal(w, 0.0)
+                client.drain()
+                for w in client.pull():
+                    np.testing.assert_array_equal(w, 1.0)
+            assert [f.shard for f in proxy.faults_fired] == ([1] if fault
+                                                             else [])
+            assert client.shards[1].reconnects_used == (1 if fault else 0)
+    finally:
+        ps.stop()
+
+
 # -- coordinated per-shard snapshots (restored as a unit) ----------------------
 
 def test_sharded_snapshot_set_restores_as_a_unit(tmp_path):

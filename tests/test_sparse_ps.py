@@ -8,6 +8,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import native_mark
 from distkeras_tpu import observability as obs
 from distkeras_tpu.runtime import networking as net
 from distkeras_tpu.runtime.parameter_server import (
@@ -385,9 +386,11 @@ def test_sparse_pull_merges_into_cache_and_full_pull_reseeds():
         ps.stop()
 
 
-def test_sparse_pull_reissued_after_reconnect():
+@pytest.mark.parametrize("claim", ["wait_weights", "land_weights"])
+def test_sparse_pull_reissued_after_reconnect(claim):
     """A severed reply mid-sparse-pull reconnects and re-asks for the SAME
-    rows (the _sparse_pull_ids FIFO survives the reconnect)."""
+    rows (the _sparse_pull_ids FIFO survives the reconnect) — whichever
+    call was receiving it."""
     from distkeras_tpu.runtime.faults import ChaosProxy, Fault, FaultPlan
 
     ps = _start()
@@ -400,7 +403,10 @@ def test_sparse_pull_reissued_after_reconnect():
                           reconnect_backoff=0.02) as c:
                 c.pull()  # frame 0 reply: full weights (survives)
                 c.pull_nowait(sparse_rows=[np.array([1, 2])])
-                w = c.wait_weights()  # frame 1 reply severed -> re-pulled
+                if claim == "land_weights":
+                    c.land_weights()  # frame 1 reply severed -> re-pulled
+                    assert c.reconnects_used == 1 and len(c._ready) == 1
+                w = c.wait_weights()  # ... or here
                 np.testing.assert_allclose(w[0][1], _weights()[0][1])
                 assert c.reconnects_used == 1
                 assert not c._sparse_pull_ids
@@ -487,6 +493,72 @@ def test_pipelined_sparse_commit_drains_pending_sparse_pull_first():
         ps.stop()
 
 
+@pytest.mark.parametrize("cache_rows", [None, 4])
+def test_landed_sparse_pull_leaves_the_handed_out_tables_alone(cache_rows,
+                                                               telemetry):
+    """A sparse reply landed early (``land_weights``, beside the window
+    program) must not write into what the previous ``wait_weights()`` handed
+    out: in full-cache mode that is the cache table itself, which the
+    running program may be reading, so the reply's rows join the cache only
+    when the pull is handed out; the hot tier hands out per-pull blocks.
+    Either way the commit's guard finds nothing left and ``wait_weights()``
+    needs no socket."""
+    ps = _start()
+    try:
+        with PSClient("127.0.0.1", ps.port, templates=_weights(),
+                      sparse_leaves=[0],
+                      sparse_cache_rows=cache_rows) as c, \
+                PSClient("127.0.0.1", ps.port, templates=_weights(),
+                         sparse_leaves=[0]) as writer:
+            c.pull()
+            first = np.array([0, 1], np.int64)
+            c.pull_nowait(sparse_rows=[first])
+            held = c.wait_weights()
+            writer.pull()
+            d = [np.zeros((8, 4), np.float32), np.ones((3,), np.float32)]
+            d[0][[1, 5]] = 1.0
+            writer.commit(d, sparse_rows=[np.array([1, 5])])
+            snapshot = [w.copy() for w in held]
+
+            nxt_ids = np.array([1, 5], np.int64)
+            c.pull_nowait(sparse_rows=[nxt_ids])
+            c.land_weights()
+            assert len(c._ready) == 1
+            assert not c._has_pending(net.ACTION_SPARSE_WEIGHTS)
+            for w, snap in zip(held, snapshot):
+                np.testing.assert_array_equal(w, snap)
+            assert obs.snapshot()["counters"][
+                "ps_pulls_landed_early_total"] == 1.0
+            stalls = obs.snapshot()["histograms"]["ps.pull_stall_ms"]["count"]
+            c.commit_nowait([np.zeros((8, 4), np.float32),
+                             np.zeros((3,), np.float32)],
+                            sparse_rows=[first])
+            assert obs.snapshot()["histograms"]["ps.pull_stall_ms"][
+                "count"] == stalls
+
+            sock = c.sock
+            c.sock = None           # wait_weights must not need it
+            try:
+                nxt = c.wait_weights()
+            finally:
+                c.sock = sock
+            center = ps.get_weights()
+            np.testing.assert_array_equal(nxt[1], np.ones(3))
+            if cache_rows is None:
+                assert nxt[0] is c._cache[0] is held[0]
+                np.testing.assert_array_equal(nxt[0][nxt_ids],
+                                              center[0][nxt_ids])
+            else:
+                # row 1 was resident (a hit, resolved at issue from the
+                # LRU), row 5 a miss filed when the reply landed
+                np.testing.assert_array_equal(nxt[0][0], snapshot[0][1])
+                np.testing.assert_array_equal(nxt[0][1], center[0][5])
+            np.testing.assert_array_equal(held[1], snapshot[1])
+            c.drain()
+    finally:
+        ps.stop()
+
+
 def test_pull_sparse_direct_rejects_wrong_id_array_count():
     """Review pin: too many id arrays is an error, not a silent
     truncation (the zip would otherwise drop the extras)."""
@@ -554,13 +626,6 @@ def _ctr_trainer(spec, sparse, **kw):
     return AsyncADAG(Model.init(spec, seed=0), **defaults)
 
 
-def _native_mark():
-    from distkeras_tpu.runtime.native import build_error, native_available
-
-    return pytest.mark.skipif(not native_available(),
-                              reason=f"native PS unavailable: {build_error()}")
-
-
 # hub dimension (ISSUE 11): the C++ hub serves the sparse wire plane, so
 # THE acceptance pin runs against both implementations
 @pytest.mark.parametrize("compress,pipeline,epochs,hub", [
@@ -568,10 +633,10 @@ def _native_mark():
     (None, False, 2, "python"),
     ("int8", True, 1, "python"),
     ("int8", False, 2, "python"),
-    pytest.param(None, True, 1, "native", marks=_native_mark()),
-    pytest.param("int8", True, 1, "native", marks=_native_mark()),
-    pytest.param(None, False, 2, "native", marks=_native_mark()),
-    pytest.param("int8", False, 2, "native", marks=_native_mark()),
+    pytest.param(None, True, 1, "native", marks=native_mark()),
+    pytest.param("int8", True, 1, "native", marks=native_mark()),
+    pytest.param(None, False, 2, "native", marks=native_mark()),
+    pytest.param("int8", False, 2, "native", marks=native_mark()),
 ])
 def test_sparse_vs_dense_full_touch_bit_parity(compress, pipeline, epochs,
                                                hub):

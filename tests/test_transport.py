@@ -12,6 +12,7 @@ become a different algorithm.
 import numpy as np
 import pytest
 
+from conftest import native_mark
 from distkeras_tpu import observability as obs
 from distkeras_tpu.models.base import Model, ModelSpec
 
@@ -23,15 +24,27 @@ def _mlp_spec():
 
 def _train(trainer_name, toy_dataset, *, transport, pipeline, num_workers=1,
            **extra):
+    """``sparse_tables`` in ``extra`` swaps the dense MLP on the blobs for
+    the CTR embedding model on its own ids (8 windows an epoch, partial
+    touch), the shape the row-sparse exchange exists for."""
     import distkeras_tpu as dk
 
+    spec, shuffle = _mlp_spec(), True
+    if extra.get("sparse_tables"):
+        from distkeras_tpu.data.ctr import synthetic_ctr_dataset
+        from distkeras_tpu.models.embedding import ctr_embedding_spec
+
+        spec = ctr_embedding_spec(64, dim=4, fields=2, hidden_sizes=(8,))
+        toy_dataset = synthetic_ctr_dataset(512, 64, fields=2, seed=0,
+                                            hot_prob=0.0)
+        shuffle = False
     cls = getattr(dk, trainer_name)
-    trainer = cls(Model.init(_mlp_spec(), seed=0),
+    trainer = cls(Model.init(spec, seed=0),
                   loss="categorical_crossentropy", batch_size=16, num_epoch=2,
                   num_workers=num_workers, communication_window=4,
                   learning_rate=0.05, seed=0, transport=transport,
                   pipeline=pipeline, **extra)
-    model = trainer.train(toy_dataset)
+    model = trainer.train(toy_dataset, shuffle=shuffle)
     return trainer, model
 
 
@@ -44,20 +57,73 @@ def _assert_bit_identical(run_a, run_b):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("trainer_name,pipeline,extra", [
-    ("AsyncADAG", True, {}),
-    ("AsyncADAG", False, {}),
-    ("AsyncAEASGD", True, {"rho": 2.0}),
+# the wire transports land the prefetched pull beside the window program
+# (PSClient.land_weights); inproc copies the center when the pull is issued.
+# Bit-equal trajectories say the early landing changed no float: ADAG and
+# one elastic trainer, socket and shm, Python and C++ hub, dense and
+# row-sparse (full cache and hot tier)
+@pytest.mark.parametrize("trainer_name,pipeline,wire,extra", [
+    ("AsyncADAG", True, "socket", {}),
+    ("AsyncADAG", False, "socket", {}),
+    ("AsyncAEASGD", True, "socket", {"rho": 2.0}),
+    ("AsyncADAG", True, "shm", {}),
+    ("AsyncAEASGD", True, "shm", {"rho": 2.0}),
+    pytest.param("AsyncADAG", True, "socket", {"native_ps": True},
+                 marks=native_mark()),
+    pytest.param("AsyncAEASGD", True, "shm", {"rho": 2.0, "native_ps": True},
+                 marks=native_mark()),
+    ("AsyncADAG", True, "socket", {"sparse_tables": "auto"}),
+    ("AsyncADAG", True, "shm", {"sparse_tables": "auto"}),
+    ("AsyncAEASGD", True, "socket", {"rho": 2.0, "sparse_tables": "auto"}),
+    ("AsyncADAG", True, "socket", {"sparse_tables": "auto",
+                                   "sparse_cache_rows": 16}),
+    pytest.param("AsyncADAG", True, "socket",
+                 {"sparse_tables": "auto", "native_ps": True},
+                 marks=native_mark()),
 ])
-def test_inproc_matches_socket_bit_identical(trainer_name, pipeline, extra,
-                                             toy_dataset):
+def test_inproc_matches_socket_bit_identical(trainer_name, pipeline, wire,
+                                             extra, toy_dataset):
     """Single-worker ADAG/AEASGD trajectories are bit-equal across
     transports, with and without the pipelined overlap."""
-    sock = _train(trainer_name, toy_dataset, transport="socket",
+    sock = _train(trainer_name, toy_dataset, transport=wire,
                   pipeline=pipeline, **extra)
     inproc = _train(trainer_name, toy_dataset, transport="inproc",
                     pipeline=pipeline, **extra)
     _assert_bit_identical(sock, inproc)
+
+
+@pytest.mark.parametrize("extra,per_run", [
+    # dense prefetch spans the epochs: every window but the run's last
+    ({}, lambda windows, epochs: windows - 1),
+    ({"transport": "shm"}, lambda windows, epochs: windows - 1),
+    # one landing a stripe
+    ({"num_shards": 2}, lambda windows, epochs: 2 * (windows - 1)),
+    # the sparse prefetch stops at each epoch's tail (the next epoch's ids
+    # do not exist yet)
+    ({"sparse_tables": "auto"}, lambda windows, epochs: windows - epochs),
+    # nothing is ever in flight: the serial exchange, the in-process client
+    ({"pipeline": False}, lambda windows, epochs: 0),
+    ({"transport": "inproc"}, lambda windows, epochs: 0),
+])
+def test_pulls_landed_early_counts_every_prefetch(extra, per_run, toy_dataset,
+                                                  telemetry):
+    """``ps_pulls_landed_early_total``: one for each prefetched reply the
+    worker loop claimed through ``land_weights()``, and the commit's guard
+    had nothing left to claim (one ``ps.pull_stall_ms`` sample a pull)."""
+    kw = {"transport": "socket", "pipeline": True, **extra}
+    trainer, _ = _train("AsyncADAG", toy_dataset, **kw)
+    snap = telemetry.snapshot()
+
+    def total(series, name, of=lambda v: v):    # every shard label added up
+        return sum(of(v) for k, v in snap[series].items()
+                   if k.split("{", 1)[0] == name)
+
+    landed = total("counters", "ps_pulls_landed_early_total")
+    assert landed == per_run(len(trainer.history), 2)
+    if kw["transport"] != "inproc":
+        # one sample a land and one a (socket-free) wait_weights, none a guard
+        assert total("histograms", "ps.pull_stall_ms", lambda h: h["count"]) \
+            == total("counters", "ps_pulls_total") + landed
 
 
 def test_inproc_matches_socket_with_int8_commits(toy_dataset):
