@@ -97,5 +97,9 @@ TELEMETRY_NAMES = frozenset({
     # back to a trace's device events by obs.device_scopes
     "attn.sliding", "attn.full", "moe.route", "moe.dispatch", "moe.experts",
     "moe.shared", "moe.combine", "moe.bias",
+    # the gated-delta-rule mixer (TransformerBlock._linear_attention) and
+    # its four parts
+    "attn.linear", "attn.linear.proj", "attn.linear.conv", "attn.linear.scan",
+    "attn.linear.out",
     "punchcard.job",
 })

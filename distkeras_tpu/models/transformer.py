@@ -50,9 +50,12 @@ _BLOCK_DEFAULTS = {
     "norm": "layernorm", "head_dim": None, "qk_norm": False, "attn_gate": False,
     "post_norm": False, "mlp": "gelu", "layer_types": None, "routed_experts": 0,
     "tie_word_embeddings": True, "embed_scale": 1.0,
+    "pre_norm": True, "linear_num_heads": 0, "linear_key_dim": 0, "linear_value_dim": 0,
+    "linear_conv_width": 4, "linear_neg_eigval": False,
 }
 LAYER_KINDS = {"full": "full", "full_attention": "full",
-               "sliding": "sliding", "sliding_attention": "sliding"}
+               "sliding": "sliding", "sliding_attention": "sliding",
+               "linear": "linear", "linear_attention": "linear"}
 
 
 def unsupported_features(config) -> list:
@@ -111,14 +114,29 @@ class TransformerBlock(nn.Module):
     head_dim: Optional[int] = None  # None = model_dim // num_heads; set
                                # apart from the width, heads x head_dim
                                # need not equal model_dim
-    qk_norm: bool = False      # RMSNorm over each q and k head vector
+    qk_norm: object = False    # RMSNorm over each q and k head vector (True
+                               # or "head") or over the whole projection,
+                               # all heads together ("full")
     attn_gate: bool = False    # o = attention(...) * sigmoid(x @ W_gate)
-    post_norm: bool = False    # a norm AFTER each sublayer too, before the
-                               # residual add
+    pre_norm: bool = True      # a norm BEFORE each sublayer
+    post_norm: bool = False    # a norm AFTER each sublayer, before the
+                               # residual add (alone, with pre_norm off: the
+                               # block whose residual stream is never normed)
     mlp: str = "gelu"          # "gelu" (mlp_ratio * model_dim) | "swiglu"
     mlp_dim: Optional[int] = None   # the SwiGLU's stated width
     attn_kind: str = "full"    # "full" | "sliding" (causal, sliding_window)
+                               # | "linear" (the gated delta rule: no
+                               # softmax, a matrix state a head)
     sliding_window: Optional[int] = None
+    # the "linear" mixer: its heads, the size of a key (and query) and of a
+    # value head, the taps of its causal depthwise convolutions, and whether
+    # the write strength may pass 1 (beta = 2 sigmoid: I - beta k k^T then
+    # has eigenvalues in (-1, 1])
+    linear_num_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_width: int = 4
+    linear_neg_eigval: bool = False
     rope_theta: float = 10000.0
     ffn_kind: str = "dense"    # "dense" | "moe": the sigmoid-routed expert
                                # layer with a shared expert
@@ -150,9 +168,9 @@ class TransformerBlock(nn.Module):
             return
         new = unsupported_features({
             "head_dim": self.head_dim, "attn_gate": self.attn_gate,
-            "post_norm": self.post_norm, "qk_norm": self.qk_norm,
-            "mlp": self.mlp, "norm": self.norm,
-            "layer_types": ("sliding",) if self.attn_kind != "full" else None,
+            "pre_norm": self.pre_norm, "post_norm": self.post_norm,
+            "qk_norm": self.qk_norm, "mlp": self.mlp, "norm": self.norm,
+            "layer_types": (self.attn_kind,) if self.attn_kind != "full" else None,
             "routed_experts": self.routed_experts if self.ffn_kind == "moe" else 0})
         if new:
             raise ValueError(
@@ -174,8 +192,12 @@ class TransformerBlock(nn.Module):
         if self.moe_experts and self.seq_axis is not None:
             raise ValueError("MoE FFN does not compose with sequence parallelism "
                              "(v1); train MoE LMs with make_moe_lm_train_step")
-        if self.attn_kind not in ("full", "sliding"):
-            raise ValueError(f"attn_kind must be 'full' or 'sliding', got {self.attn_kind!r}")
+        if self.attn_kind not in ("full", "sliding", "linear"):
+            raise ValueError(f"attn_kind must be 'full', 'sliding' or 'linear', "
+                             f"got {self.attn_kind!r}")
+        if self.qk_norm not in (False, True, "head", "full"):
+            raise ValueError(f"qk_norm must be False, True, 'head' or 'full', "
+                             f"got {self.qk_norm!r}")
         if self.attn_kind == "sliding" and not self.sliding_window:
             raise ValueError("attn_kind 'sliding' needs sliding_window")
         if self.ffn_kind == "moe" and (self.ep_size != 1 or self.ep_axis is not None):
@@ -191,12 +213,15 @@ class TransformerBlock(nn.Module):
             raise ValueError(f"num_heads {self.num_heads} not a multiple of "
                              f"num_kv_heads {kv_heads}")
         with jax.named_scope(f"attn.{self.attn_kind}"):
-            x = x + self._attention(x, pos_offset, heads_local, head_dim, kv_heads)
+            if self.attn_kind == "linear":
+                x = x + self._linear_attention(x)
+            else:
+                x = x + self._attention(x, pos_offset, heads_local, head_dim, kv_heads)
         return x + self._ffn(x, ffn_local)
 
     @nn.nowrap
     def _attention(self, x, pos_offset, heads_local: int, head_dim: int, kv_heads: int):
-        y = self._norm("attn_norm")(x)
+        y = self._norm("attn_norm")(x) if self.pre_norm else x
         if kv_heads == self.num_heads:
             qkv = nn.DenseGeneral((3, heads_local, head_dim), use_bias=False,
                                   dtype=self.compute_dtype, name="qkv")(y)  # [B, L, 3, Hl, Dh]
@@ -211,7 +236,14 @@ class TransformerBlock(nn.Module):
                                  use_bias=False, dtype=self.compute_dtype,
                                  name="kv")(y)
             k, v = kv[:, :, 0], kv[:, :, 1]
-        if self.qk_norm:
+        if self.qk_norm == "full":
+            # one RMSNorm over all heads' channels together, a gain a channel
+            def whole(name, t):
+                flat = t.reshape(t.shape[:2] + (-1,))
+                return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype,
+                                  name=name)(flat).reshape(t.shape)
+            q, k = whole("q_norm", q), whole("k_norm", k)
+        elif self.qk_norm:
             q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype, name="q_norm")(q)
             k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.compute_dtype, name="k_norm")(k)
         if self.positional == "rope":
@@ -237,8 +269,61 @@ class TransformerBlock(nn.Module):
         return self._norm("attn_post_norm")(o) if self.post_norm else o
 
     @nn.nowrap
+    def _linear_attention(self, x):
+        """The gated delta rule mixer (Gated DeltaNet; the recurrence is in
+        ``ops/linear_attention.py``): q, k, v each through a causal depthwise
+        convolution and SiLU, q and k L2-normalised a head, a decay and a
+        write strength a head and token, the chunked scan, then a gated
+        RMSNorm over each head's output and the output projection.  Position
+        reaches it through the convolutions and the decay alone."""
+        from distkeras_tpu.ops.linear_attention import gated_delta_rule
+
+        h, dk, dv, width = (self.linear_num_heads, self.linear_key_dim,
+                            self.linear_value_dim, self.linear_conv_width)
+        if not (h and dk and dv):
+            raise ValueError("attn_kind 'linear' needs linear_num_heads, linear_key_dim "
+                             "and linear_value_dim")
+        cd, f32 = self.compute_dtype, jnp.float32
+        y = self._norm("attn_norm")(x) if self.pre_norm else x
+        with jax.named_scope("attn.linear.proj"):
+            dense = lambda shape, name: nn.DenseGeneral(shape, use_bias=False, dtype=cd,
+                                                        name=name)(y)
+            q, k = dense((h, dk), "lin_q"), dense((h, dk), "lin_k")
+            v, gate = dense((h, dv), "lin_v"), dense((h, dv), "lin_gate")
+            # the decay's and the write strength's logits: one column a head,
+            # handed on in float32 (their cumulative sum over a chunk is)
+            head = lambda name: jnp.einsum(
+                "ble,eh->blh", y.astype(cd),
+                self.param(name, nn.initializers.lecun_normal(), (self.model_dim, h)).astype(cd),
+                preferred_element_type=f32)
+            a, b = head("lin_a"), head("lin_b")
+        with jax.named_scope("attn.linear.conv"):
+            taps = nn.initializers.normal(width ** -0.5)
+
+            def conv(name, t):      # y_t = sum_j w_j x_{t-(width-1)+j}, then SiLU
+                w = self.param(name, taps, (width,) + t.shape[2:])
+                pad = jnp.pad(t.astype(f32), ((0, 0), (width - 1, 0), (0, 0), (0, 0)))
+                return nn.silu(sum(pad[:, j:j + t.shape[1]] * w[j] for j in range(width)))
+
+            unit = lambda t: t * lax.rsqrt(jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+            q = (unit(conv("conv_q", q)) * dk ** -0.5).astype(cd)
+            k = unit(conv("conv_k", k)).astype(cd)
+            v = conv("conv_v", v).astype(cd)
+        with jax.named_scope("attn.linear.scan"):
+            a_log = self.param("A_log", _log_uniform(1.0, 16.0), (h,))
+            dt_bias = self.param("dt_bias", _inverse_softplus_log_uniform(1e-3, 1e-1), (h,))
+            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+            beta = jax.nn.sigmoid(b) * (2.0 if self.linear_neg_eigval else 1.0)
+            o = gated_delta_rule(q, k, v, g, beta)
+        with jax.named_scope("attn.linear.out"):
+            o = nn.RMSNorm(epsilon=self.norm_eps, dtype=cd, name="lin_norm")(o) * nn.silu(gate)
+            o = nn.DenseGeneral(self.model_dim, axis=(-2, -1), use_bias=False, dtype=cd,
+                                name="lin_out")(o)
+        return self._norm("attn_post_norm")(o) if self.post_norm else o
+
+    @nn.nowrap
     def _ffn(self, x, ffn_local: int):
-        y = self._norm("ffn_norm")(x)
+        y = self._norm("ffn_norm")(x) if self.pre_norm else x
         if self.ffn_kind == "moe":
             from distkeras_tpu.parallel.moe import HeldExpertsMLP
 
@@ -285,6 +370,21 @@ class TransformerBlock(nn.Module):
         y = nn.Dense(self.model_dim, use_bias=False, dtype=self.compute_dtype, name="down")(y)
         y = _maybe_psum(y, self.tp_axis)
         return self._norm("ffn_post_norm")(y) if self.post_norm else y
+
+
+def _log_uniform(lo: float, hi: float):
+    """Initializer: log of a uniform draw from [lo, hi] (the Mamba-2 ``A_log``)."""
+    return lambda key, shape, dtype=jnp.float32: jnp.log(
+        jax.random.uniform(key, shape, dtype, lo, hi))
+
+
+def _inverse_softplus_log_uniform(lo: float, hi: float):
+    """Initializer: ``x`` with ``softplus(x)`` log-uniform in [lo, hi] (the
+    Mamba-2 ``dt_bias``)."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype, np.log(lo), np.log(hi)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
 
 
 @register_model("transformer_lm")
@@ -334,15 +434,22 @@ class TransformerLM(nn.Module):
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     head_dim: Optional[int] = None
-    qk_norm: bool = False
+    qk_norm: object = False    # False | True / "head" | "full"
     attn_gate: bool = False
+    pre_norm: bool = True
     post_norm: bool = False
     mlp: str = "gelu"
     mlp_dim: Optional[int] = None
-    layer_types: Optional[tuple] = None  # per layer "full" | "sliding"
-                               # (or the published "full_attention" /
-                               # "sliding_attention"); None = all full
+    layer_types: Optional[tuple] = None  # per layer "full" | "sliding" |
+                               # "linear" (or the published "full_attention"
+                               # / "sliding_attention" / "linear_attention");
+                               # None = all full
     sliding_window: Optional[int] = None
+    linear_num_heads: int = 0  # the "linear" layers' mixer (TransformerBlock)
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_width: int = 4
+    linear_neg_eigval: bool = False
     rope_layers: str = "all"   # under positional="rope": "all" | "sliding"
                                # (full-attention layers get no positional
                                # signal at all)
@@ -426,8 +533,14 @@ class TransformerLM(nn.Module):
                             else self.positional),
                 norm=self.norm, norm_eps=self.norm_eps, head_dim=self.head_dim,
                 qk_norm=self.qk_norm, attn_gate=self.attn_gate,
-                post_norm=self.post_norm, mlp=self.mlp, mlp_dim=self.mlp_dim,
+                pre_norm=self.pre_norm, post_norm=self.post_norm,
+                mlp=self.mlp, mlp_dim=self.mlp_dim,
                 attn_kind=kind, sliding_window=self.sliding_window,
+                linear_num_heads=self.linear_num_heads,
+                linear_key_dim=self.linear_key_dim,
+                linear_value_dim=self.linear_value_dim,
+                linear_conv_width=self.linear_conv_width,
+                linear_neg_eigval=self.linear_neg_eigval,
                 rope_theta=self.rope_theta,
                 ffn_kind=("moe" if self.routed_experts and i >= self.num_dense_layers
                           else "dense"),

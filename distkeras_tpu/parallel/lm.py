@@ -72,7 +72,10 @@ def _tp_leaf_spec(path, leaf, tp_axis: Optional[str]) -> P:
 
 # leaves only the config-driven block builds: no Megatron split is defined
 _UNSHARDABLE = frozenset({"gate", "q_norm", "k_norm", "attn_post_norm", "ffn_post_norm",
-                          "gate_proj", "experts", "lm_head"})
+                          "gate_proj", "experts", "lm_head",
+                          # the linear-attention mixer
+                          "lin_q", "lin_k", "lin_v", "lin_gate", "lin_a", "lin_b", "lin_norm",
+                          "lin_out", "conv_q", "conv_k", "conv_v", "A_log", "dt_bias"})
 
 
 def lm_param_specs(params: Any, tp_axis: Optional[str]) -> Any:

@@ -245,3 +245,231 @@ def test_untied_head_hands_the_loss_float32_logits():
     spec = routed_spec(compute_dtype="bfloat16")
     x = jnp.zeros((1, 16), jnp.int32)
     assert spec.apply_fn()(spec.init_params(0), x).dtype == jnp.float32
+
+
+# -- PR 32: linear-attention layers, norm placement and QK-norm as data ---------
+
+def hybrid_spec(**over) -> ModelSpec:
+    """The Olmo-Hybrid shape of the block, tiny: no norm before a sublayer,
+    gated-delta-rule layers three to one full layer, QK-norm over the whole
+    projection, no positional signal."""
+    cfg = {"vocab_size": 64, "model_dim": 32, "num_heads": 2, "num_layers": 4,
+           "max_seq_len": 64, "positional": "none",
+           "layer_types": ("linear_attention",) * 3 + ("full_attention",),
+           "norm": "rmsnorm", "qk_norm": "full", "pre_norm": False, "post_norm": True,
+           "mlp": "swiglu", "mlp_dim": 48, "tie_word_embeddings": False,
+           "linear_num_heads": 2, "linear_key_dim": 8, "linear_value_dim": 16,
+           "linear_conv_width": 4, "linear_neg_eigval": True, "compute_dtype": "float32"}
+    cfg.update(over)
+    return ModelSpec(name="transformer_lm", config=cfg, input_shape=(64,), input_dtype="int32")
+
+
+def _shapes(spec):
+    return {jax.tree_util.keystr(k): v.shape for k, v in jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: spec.init_params(0)))[0]}
+
+
+def _step_text(spec) -> str:
+    """StableHLO of a value-and-grad of the spec's forward, from shapes."""
+    module = spec.build()
+    sown = list(spec.sown_collections())
+
+    def loss(p, x):
+        y = module.apply({"params": p}, x, mutable=sown or False)
+        return jnp.mean((y[0] if sown else y).astype(jnp.float32) ** 2)
+
+    return jax.jit(jax.value_and_grad(loss)).lower(
+        jax.eval_shape(lambda: spec.init_params(0)),
+        jax.ShapeDtypeStruct((2, 16), jnp.int32)).as_text()
+
+
+ACCEPTED = {
+    # the three configurations the benchmark had before PR 32, small; the
+    # sha256 is of the text the PARENT of PR 32 (6b5c2f0) lowers them to
+    # under this installation (jax 0.9.0)
+    "cerebras-gpt-590m": (
+        small_lm_spec(vocab_size=64, model_dim=32, num_heads=2, num_layers=2, max_seq_len=16,
+                      positional="learned"),
+        "3bc2e6ff932c749124908f547fe9d0a08db73176c81a778a4264df70d812f564"),
+    "cerebras-gpt-1.3b": (
+        small_lm_spec(vocab_size=64, model_dim=64, num_heads=4, num_layers=1, max_seq_len=16,
+                      positional="learned"),
+        "b8932fd33af7aafab7ad783bd0bd99f7077829bfaaf7f4b3fd9ca17b1c4fcede"),
+    "trinity-mini": (
+        routed_spec(remat=True, compute_dtype="bfloat16"),
+        "1d219401897b8f902fdc5ee8f2c403eb1b162722eefe7ed49855e7cf082a2a1d"),
+}
+NEW_KEYS = {"pre_norm": True, "linear_num_heads": 0, "linear_key_dim": 0, "linear_value_dim": 0,
+            "linear_conv_width": 4, "linear_neg_eigval": False}
+
+
+@pytest.mark.parametrize("name", sorted(ACCEPTED))
+def test_accepted_configurations_lower_to_the_program_they_had(name):
+    """Same parameter tree and the same StableHLO text, byte for byte: as
+    the parent lowered it (pinned), and with every new key spelt out at its
+    default."""
+    import hashlib
+
+    spec, parent_sha = ACCEPTED[name]
+    text = _step_text(spec)
+    spelt = ModelSpec(name=spec.name, config=dict(spec.config, **NEW_KEYS),
+                      input_shape=spec.input_shape, input_dtype=spec.input_dtype)
+    assert _shapes(spelt) == _shapes(spec)
+    assert _step_text(spelt) == text
+    assert hashlib.sha256(text.encode()).hexdigest() == parent_sha, (
+        "the config-driven block's defaults no longer lower to the program of before PR 32 "
+        "(or the installation's JAX changed: re-pin from the parent commit)")
+
+
+def test_linear_attention_layers_build_their_own_tree():
+    got = _shapes(hybrid_spec())
+    linear = {"['lin_q']['kernel']": (32, 2, 8), "['lin_k']['kernel']": (32, 2, 8),
+              "['lin_v']['kernel']": (32, 2, 16), "['lin_gate']['kernel']": (32, 2, 16),
+              "['lin_a']": (32, 2), "['lin_b']": (32, 2), "['conv_q']": (4, 2, 8),
+              "['conv_k']": (4, 2, 8), "['conv_v']": (4, 2, 16), "['A_log']": (2,),
+              "['dt_bias']": (2,), "['lin_norm']['scale']": (16,),
+              "['lin_out']['kernel']": (2, 16, 32)}
+    shared = {"['attn_post_norm']['scale']": (32,), "['ffn_post_norm']['scale']": (32,),
+              "['gate_proj']['kernel']": (32, 48), "['up']['kernel']": (32, 48),
+              "['down']['kernel']": (48, 32)}
+    full = {"['qkv']['kernel']": (32, 3, 2, 16), "['proj']['kernel']": (2, 16, 32),
+            "['q_norm']['scale']": (32,), "['k_norm']['scale']": (32,)}
+    want = {"['embed']['embedding']": (64, 32), "['final_norm']['scale']": (32,),
+            "['lm_head']['kernel']": (32, 64)}
+    for i in range(4):
+        for k, v in dict(shared, **(full if i == 3 else linear)).items():
+            want[f"['block_{i}']" + k] = v
+    assert got == want
+    spec = hybrid_spec()
+    assert spec.sown_collections() == () and spec.step_hook() is None
+    params = spec.init_params(0)
+    # A_log = log(uniform(1, 16)); softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+    a = np.exp(np.asarray(params["block_0"]["A_log"]))
+    dt = np.asarray(jax.nn.softplus(params["block_0"]["dt_bias"]))
+    assert ((a >= 1) & (a <= 16)).all() and ((dt >= 1e-3 * 0.999) & (dt <= 1e-1 * 1.001)).all()
+    x = jnp.arange(128).reshape(2, 64) % 64
+    out = spec.apply_fn()(params, x)
+    assert out.shape == (2, 64, 64) and out.dtype == jnp.float32
+    # causal: what a later token holds does not reach an earlier one's logits
+    other = spec.apply_fn()(params, x.at[:, 40:].set(0))
+    np.testing.assert_array_equal(np.asarray(out[:, :40]), np.asarray(other[:, :40]))
+    assert np.abs(np.asarray(out[:, 40:] - other[:, 40:])).max() > 1e-3
+
+
+def test_linear_mixer_needs_its_sizes_and_a_whole_number_of_chunks():
+    with pytest.raises(ValueError, match="linear_num_heads"):
+        jax.eval_shape(lambda: hybrid_spec(linear_num_heads=0).init_params(0))
+    with pytest.raises(ValueError, match="no multiple of the chunk"):
+        jax.eval_shape(lambda: ModelSpec(name="transformer_lm", config=hybrid_spec().config,
+                                         input_shape=(40,), input_dtype="int32").init_params(0))
+    with pytest.raises(ValueError, match="unknown kind"):
+        jax.eval_shape(lambda: hybrid_spec(layer_types=("linear_attention",) * 3
+                                           + ("latent_attention",)).init_params(0))
+    with pytest.raises(ValueError, match="qk_norm"):
+        jax.eval_shape(lambda: hybrid_spec(qk_norm="rows").init_params(0))
+
+
+@pytest.mark.parametrize("pre,post", [(False, True), (True, True), (True, False)])
+def test_norm_placement_is_data(pre, post):
+    """``a = x + post(Mixer(pre(x)))``: each norm is there or not by its key,
+    and the post-norm-only block computes what its equation says."""
+    spec = hybrid_spec(layer_types=("full",), num_layers=1, pre_norm=pre, post_norm=post,
+                       qk_norm=False)
+    names = set(jax.eval_shape(lambda: spec.init_params(0))["block_0"])
+    assert ({"attn_norm", "ffn_norm"} <= names) == pre
+    assert ({"attn_post_norm", "ffn_post_norm"} <= names) == post
+    if (pre, post) != (False, True):
+        return
+    p = spec.init_params(1)
+    b = p["block_0"]
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 32))
+    rms = lambda t, g: t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True) + 1e-6) * g
+    q, k, v = (jnp.einsum("ble,ehd->blhd", x, b["qkv"]["kernel"][:, n]) for n in range(3))
+    o = jnp.einsum("blhd,hde->ble", dense_attention(q, k, v), b["proj"]["kernel"])
+    a = x + rms(o, b["attn_post_norm"]["scale"])            # the mixer saw x itself, un-normed
+    f = (jax.nn.silu(a @ b["gate_proj"]["kernel"]) * (a @ b["up"]["kernel"])) @ b["down"]["kernel"]
+    want = a + rms(f, b["ffn_post_norm"]["scale"])
+    from distkeras_tpu.models.transformer import TransformerBlock
+
+    block = TransformerBlock(model_dim=32, num_heads=2, positional="none", norm="rmsnorm",
+                             pre_norm=False, post_norm=True, mlp="swiglu", mlp_dim=48,
+                             compute_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(block.apply({"params": b}, x)), np.asarray(want),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["full", "head", True])
+def test_qk_norm_over_the_whole_projection_or_a_head(kind):
+    """``"full"``: ONE RMSNorm over all heads' channels, a gain a channel;
+    ``"head"`` (and ``True``, which keeps its meaning): one a head vector."""
+    from distkeras_tpu.models.transformer import TransformerBlock
+
+    block = TransformerBlock(model_dim=32, num_heads=2, positional="none", norm="rmsnorm",
+                             qk_norm=kind, compute_dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 32))
+    b = block.init(jax.random.PRNGKey(1), x)["params"]
+    width = 32 if kind == "full" else 16
+    assert b["q_norm"]["scale"].shape == b["k_norm"]["scale"].shape == (width,)
+    b = dict(b, q_norm={"scale": 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (width,))})
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * b["attn_norm"]["scale"]
+    q, k, v = (jnp.einsum("ble,ehd->blhd", y, b["qkv"]["kernel"][:, n]) for n in range(3))
+
+    def norm(t, g):
+        flat = t.reshape(1, 16, -1) if kind == "full" else t
+        out = flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True) + 1e-6) * g
+        return out.reshape(t.shape)
+
+    o = dense_attention(norm(q, b["q_norm"]["scale"]), norm(k, b["k_norm"]["scale"]), v)
+    a = x + jnp.einsum("blhd,hde->ble", o, b["proj"]["kernel"])
+    h = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + 1e-6) * b["ffn_norm"]["scale"]
+    want = a + jax.nn.gelu(h @ b["up"]["kernel"]) @ b["down"]["kernel"]
+    np.testing.assert_allclose(np.asarray(block.apply({"params": b}, x)), np.asarray(want),
+                               atol=2e-5)
+
+
+HYBRID = hybrid_spec()
+PLAIN = {"vocab_size": 64, "model_dim": 32, "num_heads": 2, "num_layers": 2, "max_seq_len": 64}
+NEW_REFUSALS = [
+    (_decode, HYBRID, "layer_types"), (_decode, HYBRID, "linear_num_heads"),
+    (_decode, HYBRID, "pre_norm"), (_pipeline, HYBRID, "linear_key_dim"),
+    (_lm_step, HYBRID, "linear_value_dim"), (_lm_step, HYBRID, "qk_norm"),
+    (_param_specs, HYBRID, "lin_q"), (_param_specs, HYBRID, "A_log"),
+    (_tensor_parallel_block, HYBRID, "pre_norm"),
+    (_tensor_parallel_block, HYBRID, "layer_types"),
+    # one new key at a time
+    (_decode, ModelSpec(name="transformer_lm", config=dict(PLAIN, pre_norm=False),
+                        input_shape=(64,), input_dtype="int32"), "pre_norm"),
+    (_pipeline, ModelSpec(name="transformer_lm", config=dict(PLAIN, qk_norm="full"),
+                          input_shape=(64,), input_dtype="int32"), "qk_norm"),
+    (_lm_step, ModelSpec(name="transformer_lm", config=dict(PLAIN, linear_neg_eigval=True),
+                         input_shape=(64,), input_dtype="int32"), "linear_neg_eigval"),
+    (_decode, ModelSpec(name="transformer_lm", config=dict(PLAIN, linear_conv_width=2),
+                        input_shape=(64,), input_dtype="int32"), "linear_conv_width"),
+]
+
+
+@pytest.mark.parametrize("path,spec,names", NEW_REFUSALS,
+                         ids=[f"{p.__name__.strip('_')}-{n}" for p, _, n in NEW_REFUSALS])
+def test_paths_that_know_the_old_block_refuse_the_new_keys_by_name(path, spec, names):
+    with pytest.raises((ValueError, NotImplementedError), match=names):
+        path(spec)
+
+
+def test_zero_steps_the_hybrid_block():
+    """ZeRO shards the optimizer's state over a flat parameter vector and
+    asks nothing of the block: it refuses a spec with a step hook, which the
+    hybrid block has not, and steps it (the scan's carry inside shard_map)."""
+    from distkeras_tpu.ops.losses import get_loss
+    from distkeras_tpu.parallel.zero import make_zero_train_step, zero_init_state
+
+    spec, mesh = hybrid_spec(), create_nd_mesh((2,), ("dp",))
+    sgd = optax.sgd(0.01)
+    step = make_zero_train_step(spec, get_loss("sparse_categorical_crossentropy"), sgd, mesh,
+                                axis="dp")
+    params = spec.init_params(0)
+    before = jax.tree.map(np.asarray, params)      # the step donates its parameters
+    x = jax.random.randint(jax.random.PRNGKey(0), (4, 64), 0, 64)
+    new, _, loss = step(params, zero_init_state(params, sgd, mesh, axis="dp"), x, x)
+    assert np.isfinite(float(loss))
+    moved = jax.tree.map(lambda a, b: float(np.abs(np.asarray(a) - b).max()), new, before)
+    assert all(v > 0 for v in jax.tree.leaves(moved))
