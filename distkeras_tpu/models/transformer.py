@@ -418,9 +418,15 @@ class TransformerLM(nn.Module):
     tp_axis: Optional[str] = None
     tp_size: int = 1
     attn_impl: Optional[str] = None
-    remat: bool = False  # rematerialize each block in the backward pass:
-                         # activation memory O(layers) -> O(1) blocks, the
-                         # standard FLOPs-for-HBM trade for long sequences
+    remat: bool = False  # rematerialize each block in the backward pass,
+                         # except what the flash forward handed back (its
+                         # output and log-sum-exp, saved by name: O(L) to
+                         # keep, O(L^2) to make again).  Activation memory
+                         # O(layers) blocks -> one block + the block inputs
+                         # + B*L*heads*(2*head_dim+32) bytes a flash layer
+                         # (+512 on the chip, which tiles the log-sum-exp's
+                         # 8 lanes to 128): the FLOPs-for-HBM trade for
+                         # long sequences
     moe_experts: int = 0       # > 0: every block's FFN becomes a Switch MoE
     moe_capacity: int = 0      # (0 = default to 2x the balanced share per
                                # expert; imbalanced routing beyond that
@@ -594,8 +600,15 @@ class TransformerLM(nn.Module):
         x = self.embed_tokens(tokens, pos_offset)
         # pos_offset rides as a DYNAMIC remat arg: under sequence
         # parallelism it is a traced axis_index expression, not a constant
-        run = (nn.remat(lambda m, y, po: m(y, po), prevent_cse=True)
-               if self.remat else (lambda m, y, po: m(y, po)))
+        run = lambda m, y, po: m(y, po)
+        if self.remat:
+            from distkeras_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
+            # everything of a block is made again in the backward pass but
+            # the flash forward's two results: the kernel runs once a layer
+            run = nn.remat(run, prevent_cse=True,
+                           policy=jax.checkpoint_policies.save_only_these_names(
+                               FLASH_OUT_NAME, FLASH_LSE_NAME))
         for blk in self.block:
             x = run(blk, x, pos_offset)
         return x

@@ -47,6 +47,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -623,6 +624,20 @@ def _backward(q, k, v, o, lse, do, cfg: _Config, dlse=None):
     return dq, dk, dv
 
 
+# The forward kernel's two results, as ``jax.checkpoint`` policies know
+# them: a remat with ``save_only_these_names(FLASH_OUT_NAME, FLASH_LSE_NAME)``
+# keeps them from the forward pass, so its backward pass does not run the
+# forward kernel again (``TransformerLM.remat``).  Outside a checkpoint a
+# name is an identity that lowers to nothing.
+FLASH_OUT_NAME = "flash_attention.out"  # o:   [B, H, Lq, D], the inputs' dtype
+FLASH_LSE_NAME = "flash_attention.lse"  # lse: [B, H, Lq, _STAT_LANES] float32
+
+
+def _named_forward(q, k, v, cfg: _Config):
+    o, lse = _forward(q, k, v, cfg)
+    return checkpoint_name(o, FLASH_OUT_NAME), checkpoint_name(lse, FLASH_LSE_NAME)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash(q, k, v, cfg: _Config):
     o, _ = _forward(q, k, v, cfg)
@@ -630,7 +645,7 @@ def _flash(q, k, v, cfg: _Config):
 
 
 def _flash_fwd(q, k, v, cfg: _Config):
-    o, lse = _forward(q, k, v, cfg)
+    o, lse = _named_forward(q, k, v, cfg)
     return o, (q, k, v, o, lse)
 
 
@@ -649,7 +664,7 @@ def _flash_lse(q, k, v, cfg: _Config):
 
 
 def _flash_lse_fwd(q, k, v, cfg: _Config):
-    o, lse = _forward(q, k, v, cfg)
+    o, lse = _named_forward(q, k, v, cfg)
     return (o, lse[..., 0]), (q, k, v, o, lse)
 
 
