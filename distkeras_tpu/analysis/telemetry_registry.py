@@ -101,5 +101,9 @@ TELEMETRY_NAMES = frozenset({
     # its four parts
     "attn.linear", "attn.linear.proj", "attn.linear.conv", "attn.linear.scan",
     "attn.linear.out",
+    # the latent-attention mixer (TransformerBlock._latent_attention) and
+    # its six parts
+    "attn.latent", "attn.latent.q", "attn.latent.down", "attn.latent.up",
+    "attn.latent.rope", "attn.latent.core", "attn.latent.out",
     "punchcard.job",
 })

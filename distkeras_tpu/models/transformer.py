@@ -52,10 +52,13 @@ _BLOCK_DEFAULTS = {
     "tie_word_embeddings": True, "embed_scale": 1.0,
     "pre_norm": True, "linear_num_heads": 0, "linear_key_dim": 0, "linear_value_dim": 0,
     "linear_conv_width": 4, "linear_neg_eigval": False,
+    "kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0, "v_head_dim": 0,
+    "rope_interleave": False, "n_shared_experts": 1,
 }
 LAYER_KINDS = {"full": "full", "full_attention": "full",
                "sliding": "sliding", "sliding_attention": "sliding",
-               "linear": "linear", "linear_attention": "linear"}
+               "linear": "linear", "linear_attention": "linear",
+               "latent": "latent"}
 
 
 def unsupported_features(config) -> list:
@@ -74,6 +77,17 @@ def reject_block_features(config, where: str) -> None:
 
 
 class TransformerBlock(nn.Module):
+    """One decoder block, ``x + Mixer(x)`` then ``x + FFN(x)``, its shape
+    taken from configuration.  The mixer is softmax attention over the whole
+    causal prefix or a sliding window (``_attention``: fused qkv or GQA,
+    QK-norm, RoPE, an output gate), the gated delta rule
+    (``_linear_attention``) or latent attention (``_latent_attention``: keys
+    and values through a shared low-rank latent, one rotary key a token,
+    queries and keys wider than values); the FFN a GELU or SwiGLU layer, the
+    Switch layer or the sigmoid-routed expert layer.  Every default is the
+    GPT-2-style block; tensor and sequence parallelism run that block only
+    (``_check_parallel``)."""
+
     model_dim: int
     num_heads: int            # GLOBAL head count; local = num_heads // tp_size
     num_kv_heads: Optional[int] = None  # grouped-query attention (GQA,
@@ -126,7 +140,9 @@ class TransformerBlock(nn.Module):
     mlp_dim: Optional[int] = None   # the SwiGLU's stated width
     attn_kind: str = "full"    # "full" | "sliding" (causal, sliding_window)
                                # | "linear" (the gated delta rule: no
-                               # softmax, a matrix state a head)
+                               # softmax, a matrix state a head) | "latent"
+                               # (keys and values through a shared low-rank
+                               # latent, one rotary key for all heads)
     sliding_window: Optional[int] = None
     # the "linear" mixer: its heads, the size of a key (and query) and of a
     # value head, the taps of its causal depthwise convolutions, and whether
@@ -137,6 +153,15 @@ class TransformerBlock(nn.Module):
     linear_value_dim: int = 0
     linear_conv_width: int = 4
     linear_neg_eigval: bool = False
+    # the "latent" mixer, under the published names (DeepSeek-V2/V3): the
+    # latent's width, a head's un-rotated and rotary query/key channels
+    # (queries and keys are their sum wide) and its value channels, and
+    # whether the rotary channels pair up as (2i, 2i+1)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
     rope_theta: float = 10000.0
     ffn_kind: str = "dense"    # "dense" | "moe": the sigmoid-routed expert
                                # layer with a shared expert
@@ -146,7 +171,9 @@ class TransformerBlock(nn.Module):
                                # deployment), of which this replica holds
     experts_held: Optional[tuple] = None  # ... [lo, hi); None = all
     routed_top_k: int = 8
-    routed_dim: int = 0        # a routed (and the shared) expert's width
+    routed_dim: int = 0        # a routed expert's width
+    n_shared_experts: int = 1  # the shared expert is ONE SwiGLU this many
+                               # times as wide
     route_scale: float = 1.0
     compute_dtype: jnp.dtype = jnp.bfloat16
 
@@ -192,8 +219,8 @@ class TransformerBlock(nn.Module):
         if self.moe_experts and self.seq_axis is not None:
             raise ValueError("MoE FFN does not compose with sequence parallelism "
                              "(v1); train MoE LMs with make_moe_lm_train_step")
-        if self.attn_kind not in ("full", "sliding", "linear"):
-            raise ValueError(f"attn_kind must be 'full', 'sliding' or 'linear', "
+        if self.attn_kind not in ("full", "sliding", "linear", "latent"):
+            raise ValueError(f"attn_kind must be 'full', 'sliding', 'linear' or 'latent', "
                              f"got {self.attn_kind!r}")
         if self.qk_norm not in (False, True, "head", "full"):
             raise ValueError(f"qk_norm must be False, True, 'head' or 'full', "
@@ -215,6 +242,8 @@ class TransformerBlock(nn.Module):
         with jax.named_scope(f"attn.{self.attn_kind}"):
             if self.attn_kind == "linear":
                 x = x + self._linear_attention(x)
+            elif self.attn_kind == "latent":
+                x = x + self._latent_attention(x, pos_offset)
             else:
                 x = x + self._attention(x, pos_offset, heads_local, head_dim, kv_heads)
         return x + self._ffn(x, ffn_local)
@@ -322,6 +351,50 @@ class TransformerBlock(nn.Module):
         return self._norm("attn_post_norm")(o) if self.post_norm else o
 
     @nn.nowrap
+    def _latent_attention(self, x, pos_offset):
+        """Latent attention (MLA; DeepSeek-V2/V3 without the query's low-rank
+        path): ``q = y W_q`` -> H heads of ``qk_nope_head_dim +
+        qk_rope_head_dim``; ``[c ; k_r] = y W_dkv`` -> ``kv_lora_rank +
+        qk_rope_head_dim``; ``[k_n ; v] = RMSNorm(c) W_ukv`` -> H heads of
+        ``qk_nope_head_dim + v_head_dim``.  ``k_r`` is ONE rotary key a token:
+        it and each head's rotary query channels are rotated, then it is
+        broadcast to the heads and joined to their un-rotated keys.  Queries
+        and keys are wider than values; the scale is the query's width's."""
+        h, r, dn, dr, dv = (self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
+                            self.qk_rope_head_dim, self.v_head_dim)
+        if not (r and dn and dv):
+            raise ValueError("attn_kind 'latent' needs kv_lora_rank, qk_nope_head_dim "
+                             "and v_head_dim")
+        cd = self.compute_dtype
+        y = self._norm("attn_norm")(x) if self.pre_norm else x
+        with jax.named_scope("attn.latent.q"):
+            q = nn.DenseGeneral((h, dn + dr), use_bias=False, dtype=cd, name="q")(y)
+        with jax.named_scope("attn.latent.down"):
+            down = nn.Dense(r + dr, use_bias=False, dtype=cd, name="kv_down")(y)
+            c = nn.RMSNorm(epsilon=self.norm_eps, dtype=cd, name="kv_norm")(down[..., :r])
+            k_r = down[:, :, None, r:]                       # [B, L, 1, dr]
+        with jax.named_scope("attn.latent.up"):
+            kv = nn.DenseGeneral((h, dn + dv), use_bias=False, dtype=cd, name="kv_up")(c)
+        with jax.named_scope("attn.latent.rope"):
+            q_r = q[..., dn:]
+            if self.positional == "rope" and dr:
+                from distkeras_tpu.ops.rotary import rope_rotate
+
+                pos = pos_offset + jnp.arange(x.shape[1])
+                q_r, k_r = (rope_rotate(t, pos, base=self.rope_theta,
+                                        interleaved=self.rope_interleave) for t in (q_r, k_r))
+            q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, dr))], axis=-1)
+            v = kv[..., dn:]
+        with jax.named_scope("attn.latent.core"):
+            o = attention(q, k, v, causal=True, axis_name=self.seq_axis, impl=self.attn_impl)
+        with jax.named_scope("attn.latent.out"):
+            o = nn.DenseGeneral(self.model_dim, axis=(-2, -1), use_bias=False, dtype=cd,
+                                name="proj")(o)
+        return self._norm("attn_post_norm")(o) if self.post_norm else o
+
+    @nn.nowrap
     def _ffn(self, x, ffn_local: int):
         y = self._norm("ffn_norm")(x) if self.pre_norm else x
         if self.ffn_kind == "moe":
@@ -332,6 +405,7 @@ class TransformerBlock(nn.Module):
             y = HeldExpertsMLP(
                 num_experts=self.routed_experts, experts_held=held,
                 model_dim=self.model_dim, hidden_dim=self.routed_dim,
+                shared_dim=self.n_shared_experts * self.routed_dim,
                 top_k=self.routed_top_k, route_scale=self.route_scale,
                 compute_dtype=self.compute_dtype, name="experts")(y.reshape(b * l, e))
             y = y.reshape(b, l, e)
@@ -448,14 +522,19 @@ class TransformerLM(nn.Module):
     mlp_dim: Optional[int] = None
     layer_types: Optional[tuple] = None  # per layer "full" | "sliding" |
                                # "linear" (or the published "full_attention"
-                               # / "sliding_attention" / "linear_attention");
-                               # None = all full
+                               # / "sliding_attention" / "linear_attention")
+                               # | "latent"; None = all full
     sliding_window: Optional[int] = None
     linear_num_heads: int = 0  # the "linear" layers' mixer (TransformerBlock)
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv_width: int = 4
     linear_neg_eigval: bool = False
+    kv_lora_rank: int = 0      # the "latent" layers' mixer (TransformerBlock)
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
     rope_layers: str = "all"   # under positional="rope": "all" | "sliding"
                                # (full-attention layers get no positional
                                # signal at all)
@@ -467,6 +546,7 @@ class TransformerLM(nn.Module):
     experts_held: Optional[tuple] = None
     routed_top_k: int = 8
     routed_dim: int = 0
+    n_shared_experts: int = 1
     route_scale: float = 1.0
     route_balance_coeff: float = 0.0  # the selection bias's step; read by
                                # the training step (``step_hook``), not
@@ -547,11 +627,16 @@ class TransformerLM(nn.Module):
                 linear_value_dim=self.linear_value_dim,
                 linear_conv_width=self.linear_conv_width,
                 linear_neg_eigval=self.linear_neg_eigval,
+                kv_lora_rank=self.kv_lora_rank,
+                qk_nope_head_dim=self.qk_nope_head_dim,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim, rope_interleave=self.rope_interleave,
                 rope_theta=self.rope_theta,
                 ffn_kind=("moe" if self.routed_experts and i >= self.num_dense_layers
                           else "dense"),
                 routed_experts=self.routed_experts, experts_held=self.experts_held,
                 routed_top_k=self.routed_top_k, routed_dim=self.routed_dim,
+                n_shared_experts=self.n_shared_experts,
                 route_scale=self.route_scale,
                 compute_dtype=self.compute_dtype,
             )

@@ -44,7 +44,9 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool
                     q_offset: int = 0, k_offset: int = 0,
                     window: Optional[int] = None) -> jnp.ndarray:
     """Plain softmax attention. Shapes: q [B, Lq, H, D], k/v [B, Lk, H, D]
-    (or [B, Lk, Hkv, D] with grouped KV heads — broadcast up internally).
+    (or [B, Lk, Hkv, D] with grouped KV heads — broadcast up internally);
+    v may have a head size of its own, [B, Lk, H, Dv], which is the output's
+    (the scale is the query/key size's).
 
     ``q_offset``/``k_offset`` are the global positions of the first query /
     key element — needed when the caller holds only a shard of the sequence.
@@ -148,6 +150,10 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, axis_name: st
     sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, l_local, h, d = q.shape
+    if v.shape[-1] != d:
+        raise ValueError(f"ring attention (sequence parallelism) knows one head size: "
+                         f"queries and keys of {d} with values of {v.shape[-1]} (latent "
+                         f"attention) run without a sequence axis")
     if impl is None:
         use_flash = ring_block_impl(l_local, d) == "flash"
     elif impl in ("flash", "dense"):

@@ -36,6 +36,16 @@ outside them (same scheme as jax.experimental.pallas.ops.tpu
 [B, L, H, D]; per-row softmax stats (logsumexp, delta) are stored with a
 trailing 8-lane dim for the same tiling reason.
 
+Two head sizes: queries and keys are [.., D], values [.., Dv] and so are
+the output, its cotangent and dv (latent attention: 192 and 128); q, k,
+dq and dk tiles, the dk scratch and the fused backward's [Lq, D] dq
+scratch are D wide, v, o, do and dv tiles, the forward's accumulator and
+the dv scratch Dv wide, and the scale is D^-1/2.  A block's last dim
+equals the array's, so D need not be a multiple of the 128 lanes (Mosaic
+pads a [block, 192] tile to 256 in VMEM).  With D == Dv the kernels trace
+to the program they always were.  The backward's tiers
+(``_fused_bwd_ok``) reckon the dq scratch at D.
+
 Fully-masked query rows (possible only when ``q_offset < k_offset``) output
 exactly 0 with 0 gradient, matching ``ring_attention``'s convention.
 """
@@ -364,9 +374,10 @@ def _out_struct(shape, dtype, *like):
 
 
 def _forward(q, k, v, cfg: _Config):
-    """q [B, H, Lq, D], k/v [B, H, Lk, D] -> (o like q, lse [B, H, Lq, 8])."""
+    """q [B, H, Lq, D], k [B, H, Lk, D], v [B, H, Lk, Dv] -> (o [B, H, Lq,
+    Dv], lse [B, H, Lq, 8]); the scale is the query/key size's."""
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, d_v = k.shape[2], v.shape[3]
     bq, bk = cfg.block_q, cfg.block_k
     scale = 1.0 / (d ** 0.5)
     nkb = lk // bk
@@ -389,20 +400,20 @@ def _forward(q, k, v, cfg: _Config):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_index),
-            pl.BlockSpec((1, 1, bk, d), kv_index),
+            pl.BlockSpec((1, 1, bk, d_v), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, d_v), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, _STAT_LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _out_struct((b, h, lq, d), q.dtype, q, k, v),
+            _out_struct((b, h, lq, d_v), q.dtype, q, k, v),
             _out_struct((b, h, lq, _STAT_LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),            # output accumulator
+            pltpu.VMEM((bq, d_v), jnp.float32),           # output accumulator
         ],
         interpret=cfg.interpret,
         compiler_params=_COMPILER_PARAMS,
@@ -420,6 +431,10 @@ def _forward(q, k, v, cfg: _Config):
 #     (512, 1024) wherever they fit; OOM when Lk reaches 32k;
 #   (512, 1024) blocks fit through Lq=16k (dq scratch 4.2M) at ANY Lk
 #     (the 32k leg runs them via q-chunking), OOM at unchunked Lq=32k;
+#     queries and keys of 192 against values of 128 (latent attention) at
+#     Lq=8k (dq scratch 6.3M, over the cap) take them in two q-chunks: one
+#     call fits and is no faster inside a window program (39.8 against
+#     40.0 ms a layer; 42.3 against 47.3 ms alone: chip, PR 34);
 #   (512, 512) blocks fit through Lq=32k (dq scratch 8.4M);
 #   above that, fall back to the two-kernel backward with wide blocks.
 # SINGLE-BLOCK tier: when the k block spans the WHOLE sequence (reachable
@@ -440,6 +455,7 @@ _BWD_WIDE_WS_CAP = 44 * 1024 * 1024     # blocks through (2048, 2048)
 
 
 def _fused_bwd_ok(lq: int, d: int, bq_kv: int, bk_kv: int, lk: int) -> bool:
+    """``d``: the query/key head size, the width of the dq scratch."""
     dq_bytes = lq * d * 4
     if bk_kv == lk and 1024 < max(bq_kv, bk_kv) <= 2048:
         # single-k-block wide tier: one (or few) grid passes, sized grant
@@ -471,7 +487,7 @@ def _bwd_compiler_params(bq_kv: int, bk_kv: int) -> pltpu.CompilerParams:
 
 def _fused_backward_call(q, k, v, do, lse, delta, cfg: _Config, scale: float):
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, d_v = k.shape[2], v.shape[3]
     bq_kv, bk_kv = cfg.block_q_bwd, cfg.block_k_bwd
     nqb = lq // bq_kv
     steps, q_index = nqb, lambda b, h, j, i: (b, h, i, 0)
@@ -490,24 +506,24 @@ def _fused_backward_call(q, k, v, do, lse, delta, cfg: _Config, scale: float):
         in_specs=[
             pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # q
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # k
-            pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # v
-            pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # do
+            pl.BlockSpec((1, 1, bk_kv, d_v), lambda b, h, j, i: (b, h, j, 0)),  # v
+            pl.BlockSpec((1, 1, bq_kv, d_v), q_index),                          # do
             pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), q_index),                 # lse
             pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), q_index),                 # delta
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # dk
-            pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # dv
+            pl.BlockSpec((1, 1, bk_kv, d_v), lambda b, h, j, i: (b, h, j, 0)),  # dv
             pl.BlockSpec((1, 1, bq_kv, d), q_index),                           # dq
         ],
         out_shape=[
             _out_struct((b, h, lk, d), k.dtype, q, k, v, do),
-            _out_struct((b, h, lk, d), v.dtype, q, k, v, do),
+            _out_struct((b, h, lk, d_v), v.dtype, q, k, v, do),
             _out_struct((b, h, lq, d), q.dtype, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk_kv, d), jnp.float32),
-            pltpu.VMEM((bk_kv, d), jnp.float32),
+            pltpu.VMEM((bk_kv, d_v), jnp.float32),
             pltpu.VMEM((lq, d), jnp.float32),
         ],
         interpret=cfg.interpret,
@@ -535,7 +551,7 @@ def _fused_q_chunks(lq: int, d: int, bq_kv: int, bk_kv: int, lk: int):
 
 def _backward(q, k, v, o, lse, do, cfg: _Config, dlse=None):
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, d_v = k.shape[2], v.shape[3]
     bq, bk = cfg.block_q_dq, cfg.block_k_dq
     bq_kv, bk_kv = cfg.block_q_bwd, cfg.block_k_bwd
     scale = 1.0 / (d ** 0.5)
@@ -579,8 +595,8 @@ def _backward(q, k, v, o, lse, do, cfg: _Config, dlse=None):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),   # q
             pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),   # k
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),   # v
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),   # do
+            pl.BlockSpec((1, 1, bk, d_v), lambda b, h, i, j: (b, h, j, 0)),  # v
+            pl.BlockSpec((1, 1, bq, d_v), lambda b, h, i, j: (b, h, i, 0)),  # do
             pl.BlockSpec((1, 1, bq, _STAT_LANES), lambda b, h, i, j: (b, h, i, 0)),  # lse
             pl.BlockSpec((1, 1, bq, _STAT_LANES), lambda b, h, i, j: (b, h, i, 0)),  # delta
         ],
@@ -601,22 +617,22 @@ def _backward(q, k, v, o, lse, do, cfg: _Config, dlse=None):
         in_specs=[
             pl.BlockSpec((1, 1, bq_kv, d), lambda b, h, j, i: (b, h, i, 0)),   # q
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # k
-            pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),   # v
-            pl.BlockSpec((1, 1, bq_kv, d), lambda b, h, j, i: (b, h, i, 0)),   # do
+            pl.BlockSpec((1, 1, bk_kv, d_v), lambda b, h, j, i: (b, h, j, 0)),  # v
+            pl.BlockSpec((1, 1, bq_kv, d_v), lambda b, h, j, i: (b, h, i, 0)),  # do
             pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), lambda b, h, j, i: (b, h, i, 0)),  # lse
             pl.BlockSpec((1, 1, bq_kv, _STAT_LANES), lambda b, h, j, i: (b, h, i, 0)),  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk_kv, d), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk_kv, d_v), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             _out_struct((b, h, lk, d), k.dtype, q, k, v, do),
-            _out_struct((b, h, lk, d), v.dtype, q, k, v, do),
+            _out_struct((b, h, lk, d_v), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk_kv, d), jnp.float32),
-            pltpu.VMEM((bk_kv, d), jnp.float32),
+            pltpu.VMEM((bk_kv, d_v), jnp.float32),
         ],
         interpret=cfg.interpret,
         compiler_params=_bwd_compiler_params(bq_kv, bk_kv),
@@ -691,8 +707,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jnp.ndarray:
-    """Flash attention over [B, L, H, D] tensors (same layout/semantics as
-    ``ops.attention.dense_attention``, including the shard offsets).
+    """Flash attention over q, k [B, L, H, D] and v [B, L, H, Dv] -> [B, L, H,
+    Dv] (same layout/semantics as ``ops.attention.dense_attention``,
+    including the shard offsets; Dv may differ from D, the scale is D^-1/2).
 
     ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i - j <
     window``.  The forward and the fused backward do not visit blocks
@@ -706,7 +723,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kernel producing dq, dk and dv from a single score/probability
     recompute (the classic two-kernel backward recomputes them twice),
     preferring (512, 1024) blocks and chunking the q range when its
-    [Lq, D] f32 dq scratch outgrows scoped vmem (``_fused_q_chunks``);
+    [Lq, D] f32 dq scratch outgrows scoped vmem (``_fused_q_chunks``:
+    queries and keys of 192 at 8k run in two chunks);
     the two-kernel path remains as the fallback for shapes that cannot
     chunk.  Small blocks pay per-block overhead many times over: keep
     them wide.  What the kernels reach of their rooflines in the
@@ -767,6 +785,8 @@ def _make_config(q, k, causal, q_offset, k_offset, block_q, block_k,
     if interpret is None:
         interpret = not on_tpu()
     lq, lk = q.shape[1], k.shape[1]
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(f"queries of head size {q.shape[-1]} against keys of {k.shape[-1]}")
     if window is not None and not causal:
         raise ValueError("a sliding window needs causal=True")
     if window is not None and window < 1:

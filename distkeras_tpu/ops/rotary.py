@@ -10,10 +10,15 @@ cos/sin math that XLA fuses into the projection epilogues, no table in
 HBM, and nothing length-bound — the same weights serve any sequence
 length (``max_seq_len`` remains only a cache-sizing bound for decoding).
 
-Convention: NeoX split-half — the head dim splits into two halves that
-rotate as (x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin), with
-frequencies base^(-2i/D).  Rotation runs in float32 (angle precision at
-large positions) and casts back to the input dtype.
+Two conventions, one set of frequencies base^(-2i/D): NeoX split-half (the
+default) — the head dim splits into two halves that rotate as (x1, x2) ->
+(x1 cos - x2 sin, x2 cos + x1 sin), channel i paired with channel i + D/2 —
+and adjacent pairs (``interleaved=True``; GPT-J, the DeepSeek-V3 family's
+``rope_interleave``): channels (2i, 2i+1) rotate together, in place.  One
+is the other under a fixed permutation of the channels, so a score
+<R(p_q)q, R(p_k)k> is the same in both when q and k share the convention.
+Rotation runs in float32 (angle precision at large positions) and casts
+back to the input dtype.
 """
 
 from __future__ import annotations
@@ -22,8 +27,9 @@ import jax.numpy as jnp
 
 
 def rope_rotate(x: jnp.ndarray, positions: jnp.ndarray,
-                base: float = 10000.0) -> jnp.ndarray:
-    """Rotate ``x`` [B, L, H, D] by absolute ``positions`` [L].
+                base: float = 10000.0, interleaved: bool = False) -> jnp.ndarray:
+    """Rotate ``x`` [B, L, H, D] by absolute ``positions`` [L]; ``interleaved``
+    pairs channels (2i, 2i+1) instead of (i, i + D/2).
 
     Works for any head count (queries and grouped GQA keys alike) and any
     even D.  Position 0 is the identity rotation, so un-offset prefixes
@@ -38,7 +44,15 @@ def rope_rotate(x: jnp.ndarray, positions: jnp.ndarray,
     ang = positions.astype(jnp.float32)[:, None] * freq[None, :]   # [L, half]
     cos = jnp.cos(ang)[None, :, None, :]                           # [1, L, 1, half]
     sin = jnp.sin(ang)[None, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    if interleaved:
+        # pairs on an axis of their own, not x[..., 0::2]: XLA:TPU makes
+        # gathers and copies of strided slices of the lanes (PERF.md, PR 34)
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:].astype(jnp.float32)
+    r1, r2 = x1 * cos - x2 * sin, x2 * cos + x1 * sin
+    if interleaved:
+        return jnp.stack([r1, r2], axis=-1).reshape(x.shape).astype(x.dtype)
+    return jnp.concatenate([r1, r2], axis=-1).astype(x.dtype)

@@ -75,7 +75,9 @@ _UNSHARDABLE = frozenset({"gate", "q_norm", "k_norm", "attn_post_norm", "ffn_pos
                           "gate_proj", "experts", "lm_head",
                           # the linear-attention mixer
                           "lin_q", "lin_k", "lin_v", "lin_gate", "lin_a", "lin_b", "lin_norm",
-                          "lin_out", "conv_q", "conv_k", "conv_v", "A_log", "dt_bias"})
+                          "lin_out", "conv_q", "conv_k", "conv_v", "A_log", "dt_bias",
+                          # the latent-attention mixer
+                          "kv_down", "kv_norm", "kv_up"})
 
 
 def lm_param_specs(params: Any, tp_axis: Optional[str]) -> Any:

@@ -527,9 +527,11 @@ class HeldExpertsMLP(nn.Module):
     ``s = sigmoid(float32(x @ W_r))``; ``S = top_k(s + b)`` (the bias ``b``
     selects only and has no gradient); ``w_e = route_scale * s_e / (sum of
     the k selected scores + 1e-20)``; ``out = Shared(x) + sum over e in S and
-    held of w_e * Expert_e(x)``, each expert a SwiGLU of width
-    ``hidden_dim``.  The normaliser runs over all k selected scores, held or
-    not.  The router's matmul, the sigmoid and the selection run in float32
+    held of w_e * Expert_e(x)``, each routed expert a SwiGLU of width
+    ``hidden_dim``, the shared one ONE SwiGLU of width ``shared_dim``
+    (``None``: the routed width; a model with n shared experts has n times
+    it), added unweighted.  The normaliser runs over all k selected scores,
+    held or not.  The router's matmul, the sigmoid and the selection run in float32
     (``Precision.HIGHEST``: a bfloat16 pass seats near-ties differently).
 
     No token is dropped and there is no capacity: all ``T * k`` assignments
@@ -563,11 +565,16 @@ class HeldExpertsMLP(nn.Module):
     top_k: int = 8
     route_scale: float = 1.0
     compute_dtype: jnp.dtype = jnp.bfloat16
+    shared_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         t, d = x.shape
         e, k, f = self.num_experts, self.top_k, self.hidden_dim
+        fs = f if self.shared_dim is None else self.shared_dim
+        if fs < 1:
+            raise ValueError(f"shared_dim {self.shared_dim}: the layer always has its "
+                             "shared expert; a model without one is not built here")
         lo, hi = (int(v) for v in self.experts_held)
         if not 0 <= lo < hi <= e:
             raise ValueError(f"experts_held {self.experts_held} is no range of "
@@ -596,8 +603,8 @@ class HeldExpertsMLP(nn.Module):
         self.sow("moe_counts", "assignments", counts)
 
         with jax.named_scope("moe.shared"):
-            g = nn.Dense(f, use_bias=False, dtype=cd, name="shared_gate")(xc)
-            u = nn.Dense(f, use_bias=False, dtype=cd, name="shared_up")(xc)
+            g = nn.Dense(fs, use_bias=False, dtype=cd, name="shared_gate")(xc)
+            u = nn.Dense(fs, use_bias=False, dtype=cd, name="shared_up")(xc)
             shared = nn.Dense(d, use_bias=False, dtype=cd, name="shared_down")(nn.silu(g) * u)
 
         bound = held_row_bound(t, k, held_n, e)

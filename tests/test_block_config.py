@@ -280,7 +280,7 @@ def _step_text(spec) -> str:
 
     return jax.jit(jax.value_and_grad(loss)).lower(
         jax.eval_shape(lambda: spec.init_params(0)),
-        jax.ShapeDtypeStruct((2, 16), jnp.int32)).as_text()
+        jax.ShapeDtypeStruct((2,) + tuple(spec.input_shape), jnp.int32)).as_text()
 
 
 ACCEPTED = {
@@ -473,3 +473,226 @@ def test_zero_steps_the_hybrid_block():
     assert np.isfinite(float(loss))
     moved = jax.tree.map(lambda a, b: float(np.abs(np.asarray(a) - b).max()), new, before)
     assert all(v > 0 for v in jax.tree.leaves(moved))
+
+
+# -- PR 34: latent attention, the shared expert's own width --------------------
+
+def latent_spec(**over) -> ModelSpec:
+    """The Kanana-2 (DeepSeek-V3 without the query latent) shape of the
+    block, tiny: every layer latent attention with queries and keys of 8 + 4
+    against values of 8, adjacent-pair RoPE, a leading dense SwiGLU layer,
+    then top-2 of 4 experts beside a shared expert of twice their width."""
+    cfg = {"vocab_size": 64, "model_dim": 32, "num_heads": 2, "num_layers": 2,
+           "max_seq_len": 16, "positional": "rope", "rope_theta": 1e6, "rope_interleave": True,
+           "layer_types": ("latent", "latent"), "kv_lora_rank": 12, "qk_nope_head_dim": 8,
+           "qk_rope_head_dim": 4, "v_head_dim": 8, "norm": "rmsnorm", "mlp": "swiglu",
+           "mlp_dim": 48, "num_dense_layers": 1, "routed_experts": 4, "experts_held": (0, 2),
+           "routed_top_k": 2, "routed_dim": 16, "n_shared_experts": 2, "route_scale": 2.448,
+           "route_balance_coeff": 0.001, "tie_word_embeddings": False,
+           "compute_dtype": "float32"}
+    cfg.update(over)
+    return ModelSpec(name="transformer_lm", config=cfg, input_shape=(16,), input_dtype="int32")
+
+
+def test_latent_layers_build_their_own_tree():
+    got = _shapes(latent_spec())
+    mixer = {"['attn_norm']['scale']": (32,), "['ffn_norm']['scale']": (32,),
+             "['q']['kernel']": (32, 2, 12), "['kv_down']['kernel']": (32, 16),
+             "['kv_norm']['scale']": (12,), "['kv_up']['kernel']": (12, 2, 16),
+             "['proj']['kernel']": (2, 8, 32)}
+    dense = {"['gate_proj']['kernel']": (32, 48), "['up']['kernel']": (32, 48),
+             "['down']['kernel']": (48, 32)}
+    experts = {"['experts']['router']": (32, 4), "['experts']['router_bias']": (4,),
+               "['experts']['w_gate']": (2, 32, 16), "['experts']['w_up']": (2, 32, 16),
+               "['experts']['w_down']": (2, 16, 32),
+               "['experts']['shared_gate']['kernel']": (32, 32),
+               "['experts']['shared_up']['kernel']": (32, 32),
+               "['experts']['shared_down']['kernel']": (32, 32)}
+    want = {"['embed']['embedding']": (64, 32), "['final_norm']['scale']": (32,),
+            "['lm_head']['kernel']": (32, 64)}
+    for i, ffn in enumerate((dense, experts)):
+        want.update({f"['block_{i}']" + k: v for k, v in dict(mixer, **ffn).items()})
+    assert got == want
+    spec = latent_spec()
+    assert spec.sown_collections() == ("moe_counts",)
+    params = spec.init_params(0)
+    x = jnp.arange(32).reshape(2, 16) % 64
+    out = spec.apply_fn()(params, x)
+    assert out.shape == (2, 16, 64) and out.dtype == jnp.float32
+    # causal: a later token does not move an earlier logit; and position
+    # reaches it through the rotary channels alone, in either convention
+    other = spec.apply_fn()(params, x.at[:, 10:].set(0))
+    np.testing.assert_allclose(np.asarray(out[:, :10]), np.asarray(other[:, :10]), atol=1e-6)
+    assert np.abs(np.asarray(out[:, 10:] - other[:, 10:])).max() > 1e-3
+    for change in ({"positional": "none"}, {"rope_interleave": False}):
+        moved = latent_spec(**change).apply_fn()(params, x)
+        assert np.abs(np.asarray(out - moved)).max() > 1e-4
+    with pytest.raises(ValueError, match="kv_lora_rank"):
+        jax.eval_shape(lambda: latent_spec(kv_lora_rank=0).init_params(0))
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_latent_mixer_computes_its_equations(interleave):
+    """The block against the equations written out: one rotary key a token
+    for all heads, the latent's norm, scores at (8 + 4)^-1/2, values of 8."""
+    from distkeras_tpu.models.transformer import TransformerBlock
+    from distkeras_tpu.ops.rotary import rope_rotate
+
+    block = TransformerBlock(model_dim=32, num_heads=2, positional="rope", norm="rmsnorm",
+                             attn_kind="latent", kv_lora_rank=12, qk_nope_head_dim=8,
+                             qk_rope_head_dim=4, v_head_dim=8, rope_interleave=interleave,
+                             rope_theta=100.0, mlp="swiglu", mlp_dim=48,
+                             compute_dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32))
+    b = block.init(jax.random.PRNGKey(1), x)["params"]
+    b = dict(b, kv_norm={"scale": 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (12,))})
+    rms = lambda t, g: t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True) + 1e-6) * g
+    u = rms(x, b["attn_norm"]["scale"])
+    q = jnp.einsum("ble,ehd->blhd", u, b["q"]["kernel"])
+    down = u @ b["kv_down"]["kernel"]
+    kv = jnp.einsum("blz,zhd->blhd", rms(down[..., :12], b["kv_norm"]["scale"]),
+                    b["kv_up"]["kernel"])
+    pos = jnp.arange(16)
+    rot = lambda t: rope_rotate(t, pos, base=100.0, interleaved=interleave)
+    q_r, k_r = rot(q[..., 8:]), rot(down[:, :, None, 12:])
+    s = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :8], kv[..., :8])
+         + jnp.einsum("bqhd,bkd->bhqk", q_r, k_r[:, :, 0])) / np.sqrt(12)
+    s = jnp.where(pos[None, :] <= pos[:, None], s, -jnp.inf)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), kv[..., 8:])
+    a = x + jnp.einsum("blhd,hde->ble", o, b["proj"]["kernel"])
+    h = rms(a, b["ffn_norm"]["scale"])
+    want = a + (jax.nn.silu(h @ b["gate_proj"]["kernel"]) * (h @ b["up"]["kernel"])) \
+        @ b["down"]["kernel"]
+    np.testing.assert_allclose(np.asarray(block.apply({"params": b}, x)), np.asarray(want),
+                               atol=2e-5)
+
+
+PR34_KEYS = {"kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0, "v_head_dim": 0,
+             "rope_interleave": False, "n_shared_experts": 1}
+# the four configurations the benchmark had before PR 34, small, with the
+# sha256 of the text the PARENT of PR 34 (e7dd97e) lowers them to
+ACCEPTED_34 = dict(ACCEPTED, **{"olmo-hybrid-7b": (
+    hybrid_spec(remat=True, compute_dtype="bfloat16"), "e32081f2ab08b0499fe492a11adc73f32404c590d78266ff461d4565b1491db5")})
+
+
+@pytest.mark.parametrize("name", sorted(ACCEPTED_34))
+def test_the_four_accepted_configurations_do_not_move_under_pr34s_keys(name):
+    """The same parameter tree and the same StableHLO text as the parent
+    lowered (pinned), and with every key of PR 34 spelt out at its default."""
+    import hashlib
+
+    spec, parent_sha = ACCEPTED_34[name]
+    text = _step_text(spec)
+    spelt = ModelSpec(name=spec.name, config=dict(spec.config, **PR34_KEYS),
+                      input_shape=spec.input_shape, input_dtype=spec.input_dtype)
+    assert unsupported_features(spelt.config) == unsupported_features(spec.config)
+    assert _shapes(spelt) == _shapes(spec)
+    assert _step_text(spelt) == text
+    assert hashlib.sha256(text.encode()).hexdigest() == parent_sha
+
+
+LATENT = latent_spec()
+LATENT_DENSE = latent_spec(routed_experts=0, num_dense_layers=0)     # no step hook
+LATENT_REFUSALS = [
+    (_decode, LATENT, "kv_lora_rank"), (_decode, LATENT, "qk_nope_head_dim"),
+    (_decode, LATENT, "rope_interleave"), (_pipeline, LATENT, "qk_rope_head_dim"),
+    (_pipeline, LATENT, "n_shared_experts"), (_lm_step, LATENT, "v_head_dim"),
+    (_lm_step, LATENT, "kv_lora_rank"), (_param_specs, LATENT, "kv_down"),
+    (_tensor_parallel_block, LATENT, "layer_types"), (_zero, LATENT, "selection bias"),
+    (_single_trainer, LATENT, "selection bias"),
+    # one new key at a time
+    (_decode, ModelSpec(name="transformer_lm", config=dict(PLAIN, kv_lora_rank=8),
+                        input_shape=(64,), input_dtype="int32"), "kv_lora_rank"),
+    (_pipeline, ModelSpec(name="transformer_lm", config=dict(PLAIN, qk_nope_head_dim=8),
+                          input_shape=(64,), input_dtype="int32"), "qk_nope_head_dim"),
+    (_lm_step, ModelSpec(name="transformer_lm", config=dict(PLAIN, qk_rope_head_dim=4),
+                         input_shape=(64,), input_dtype="int32"), "qk_rope_head_dim"),
+    (_decode, ModelSpec(name="transformer_lm", config=dict(PLAIN, v_head_dim=8),
+                        input_shape=(64,), input_dtype="int32"), "v_head_dim"),
+    (_pipeline, ModelSpec(name="transformer_lm", config=dict(PLAIN, rope_interleave=True),
+                          input_shape=(64,), input_dtype="int32"), "rope_interleave"),
+    (_lm_step, ModelSpec(name="transformer_lm", config=dict(PLAIN, n_shared_experts=2),
+                         input_shape=(64,), input_dtype="int32"), "n_shared_experts"),
+]
+
+
+@pytest.mark.parametrize("path,spec,names", LATENT_REFUSALS,
+                         ids=[f"{p.__name__.strip('_')}-{n}" for p, _, n in LATENT_REFUSALS])
+def test_paths_that_know_the_old_block_refuse_latent_attention_by_name(path, spec, names):
+    with pytest.raises((ValueError, NotImplementedError), match=names):
+        path(spec)
+
+
+def test_latent_block_under_a_sequence_axis_is_refused():
+    module = ModelSpec(name="transformer_lm", config=dict(LATENT_DENSE.config, seq_axis="sp"),
+                       input_shape=(16,), input_dtype="int32")
+    with pytest.raises(ValueError, match="layer_types"):
+        jax.eval_shape(lambda: module.init_params(0))
+
+
+def test_zero_steps_the_latent_block_without_experts():
+    """ZeRO asks nothing of the block: it refuses the step hook (the expert
+    layer's bias, above) and steps latent attention itself."""
+    from distkeras_tpu.ops.losses import get_loss
+    from distkeras_tpu.parallel.zero import make_zero_train_step, zero_init_state
+
+    mesh, sgd = create_nd_mesh((2,), ("dp",)), optax.sgd(0.01)
+    step = make_zero_train_step(LATENT_DENSE, get_loss("sparse_categorical_crossentropy"), sgd,
+                                mesh, axis="dp")
+    params = LATENT_DENSE.init_params(0)
+    before = jax.tree.map(np.asarray, params)
+    x = jax.random.randint(jax.random.PRNGKey(0), (4, 16), 0, 64)
+    new, _, loss = step(params, zero_init_state(params, sgd, mesh, axis="dp"), x, x)
+    assert np.isfinite(float(loss))
+    moved = jax.tree.map(lambda a, b: float(np.abs(np.asarray(a) - b).max()), new, before)
+    assert all(v > 0 for v in jax.tree.leaves(moved))
+
+
+def test_shared_expert_of_its_own_width_against_a_hand_written_sum():
+    """``HeldExpertsMLP(shared_dim=)``: ONE SwiGLU of that width beside the
+    routed sum; the default is the routed width, the layer as it was."""
+    from distkeras_tpu.parallel.moe import HeldExpertsMLP
+
+    kw = dict(num_experts=4, experts_held=(0, 4), model_dim=32, hidden_dim=16, top_k=2,
+              route_scale=2.448, compute_dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(0), (24, 32), jnp.float32)
+    wide = HeldExpertsMLP(shared_dim=40, **kw)
+    p = wide.init(jax.random.PRNGKey(1), u)["params"]
+    assert p["shared_gate"]["kernel"].shape == p["shared_up"]["kernel"].shape == (32, 40)
+    assert p["shared_down"]["kernel"].shape == (40, 32) and p["w_gate"].shape == (4, 32, 16)
+    p = dict(p, router_bias=0.05 * jax.random.normal(jax.random.PRNGKey(2), (4,)))
+    swiglu = lambda g, up, down: (jax.nn.silu(u @ g) * (u @ up)) @ down
+    s = jax.nn.sigmoid(u @ p["router"])
+    _, pick = jax.lax.top_k(s + p["router_bias"], 2)
+    chosen = jnp.any(pick[:, :, None] == jnp.arange(4), axis=1)
+    w = jnp.where(chosen, s, 0.0)
+    w = 2.448 * w / (w.sum(-1, keepdims=True) + 1e-20)
+    want = swiglu(p["shared_gate"]["kernel"], p["shared_up"]["kernel"],
+                  p["shared_down"]["kernel"])
+    for e in range(4):
+        want = want + w[:, e:e + 1] * swiglu(p["w_gate"][e], p["w_up"][e], p["w_down"][e])
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(np.asarray(wide.apply({"params": p}, u)), np.asarray(want),
+                                   atol=2e-5)
+    # the default: the parent's layer, tree and values bit for bit
+    plain, spelt = HeldExpertsMLP(**kw), HeldExpertsMLP(shared_dim=16, **kw)
+    tree = plain.init(jax.random.PRNGKey(3), u)["params"]
+    assert jax.tree.map(jnp.shape, tree) == jax.tree.map(
+        jnp.shape, spelt.init(jax.random.PRNGKey(3), u)["params"])
+    assert tree["shared_gate"]["kernel"].shape == (32, 16)
+    np.testing.assert_array_equal(np.asarray(plain.apply({"params": tree}, u)),
+                                  np.asarray(spelt.apply({"params": tree}, u)))
+    lower = lambda m: jax.jit(lambda t, x: m.apply({"params": t}, x)).lower(tree, u).as_text()
+    assert lower(plain) == lower(spelt)
+
+
+def test_a_shared_expert_of_no_width_is_refused_by_name():
+    """``shared_dim=0`` (a block with ``n_shared_experts=0``) is refused: it
+    must not quietly build one shared expert of the routed width."""
+    from distkeras_tpu.parallel.moe import HeldExpertsMLP
+
+    u = jnp.zeros((8, 32), jnp.float32)
+    layer = HeldExpertsMLP(num_experts=4, experts_held=(0, 4), model_dim=32, hidden_dim=16,
+                           top_k=2, shared_dim=0)
+    with pytest.raises(ValueError, match="shared_dim 0"):
+        layer.init(jax.random.PRNGKey(0), u)

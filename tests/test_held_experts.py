@@ -100,7 +100,7 @@ def test_the_bound_is_twice_the_balanced_share_in_whole_row_tiles(t, k, held_n, 
 def test_the_bound_is_no_option_of_the_layer():
     assert [f.name for f in dataclasses.fields(HeldExpertsMLP) if f.name not in ("parent", "name")] \
         == ["num_experts", "experts_held", "model_dim", "hidden_dim", "top_k", "route_scale",
-            "compute_dtype"]
+            "compute_dtype", "shared_dim"]      # PR 34: the shared expert's width, a shape
 
 
 # -- (a) the bounded path against the full-size one ------------------------------------

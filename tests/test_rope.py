@@ -229,3 +229,44 @@ def test_fused_step_refuses_rope():
     cfg = dict(spec.config)
     assert not fused_step_supported(cfg, 1, 256)
     assert resolve_step_impl(cfg, 1, 256, None) == "xla"
+
+
+# -- PR 34: the adjacent-pair convention (``interleaved=True``) ----------------
+
+def test_adjacent_pairs_rotate_by_an_explicit_two_by_two_rotation():
+    """Channels (2i, 2i+1) of a head turn together by pos * base^(-2i/D)."""
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(2, 5, 3, 8))
+    pos, base = np.asarray([0, 1, 7, 100, 5000]), 1e6
+    want = np.empty_like(x)
+    for t, p in enumerate(pos):
+        for i in range(4):
+            a = p * base ** (-2.0 * i / 8)
+            rot = np.array([[np.cos(a), -np.sin(a)], [np.sin(a), np.cos(a)]])
+            want[:, t, :, 2 * i:2 * i + 2] = x[:, t, :, 2 * i:2 * i + 2] @ rot.T
+    got = rope_rotate(jnp.asarray(x, jnp.float32), jnp.asarray(pos), base=base, interleaved=True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    # one shared key a token (a single head) turns like any head's
+    one = rope_rotate(jnp.asarray(x[:, :, :1], jnp.float32), jnp.asarray(pos), base=base,
+                      interleaved=True)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got[:, :, :1]))
+
+
+@pytest.mark.parametrize("dim", [8, 64])
+def test_adjacent_pairs_are_split_half_under_the_channel_permutation(dim):
+    """Reordering channels (0, 2, 4, ..., 1, 3, 5, ...) and rotating halves is
+    the adjacent-pair rotation reordered the same way (what the published
+    DeepSeek-V3 code does), so scores are the same in both conventions."""
+    rng = np.random.default_rng(dim)
+    q, k = (jnp.asarray(rng.normal(size=(1, 6, 2, dim)), jnp.float32) for _ in range(2))
+    pos = jnp.asarray([0, 2, 3, 50, 999, 8191])
+    perm = np.concatenate([np.arange(0, dim, 2), np.arange(1, dim, 2)])
+    pairs = lambda t: rope_rotate(t, pos, base=1e6, interleaved=True)
+    halves = lambda t: rope_rotate(t[..., perm], pos, base=1e6)
+    np.testing.assert_allclose(np.asarray(pairs(q)[..., perm]), np.asarray(halves(q)), atol=1e-6)
+    score = lambda a, b: np.asarray(jnp.einsum("bqhd,bkhd->bhqk", a, b))
+    np.testing.assert_allclose(score(pairs(q), pairs(k)), score(halves(q), halves(k)), atol=1e-4)
+    # the default is today's split-half, bit for bit
+    np.testing.assert_array_equal(np.asarray(rope_rotate(q, pos)),
+                                  np.asarray(rope_rotate(q, pos, interleaved=False)))
+    assert np.abs(np.asarray(pairs(q) - rope_rotate(q, pos, base=1e6))).max() > 1e-2
