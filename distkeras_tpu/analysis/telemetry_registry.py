@@ -27,6 +27,9 @@ TELEMETRY_NAMES = frozenset({
     # client: prefetched pull replies claimed by land_weights, beside the
     # window program (not by the commit's guard, not by wait_weights)
     "ps_pulls_landed_early_total",
+    # client: dense commits that left leaf by leaf, no frame packed
+    # (FlatFrameCodec.send_streamed); int8 and row-sparse ones do not count
+    "ps_commits_streamed_total",
     "ps_fenced_commits_total", "ps_idle_evictions_total",
     "ps_live_workers", "ps_staleness", "ps_commit_staleness",
     "ps_snapshots_total", "ps_snapshot_sets_total",
@@ -66,8 +69,9 @@ TELEMETRY_NAMES = frozenset({
     "health.event",
     # -- transport -------------------------------------------------------------
     "net_tx_frames_total", "net_tx_bytes_total",
-    # zero-copy shm transport + batched receive (ISSUE 18): frames moved
-    # over shared-memory rings, producer parks on a full ring, and the
+    # zero-copy shm transport + batched receive (ISSUE 18): writes to the
+    # shared-memory rings (a packed frame is one, a streamed commit one a
+    # piece), producer parks on a full ring, and the
     # frames-per-syscall-batch histogram of the hub's batched receive
     "ps.shm_frames_total", "ps.shm_ring_full_waits", "ps_recv_batch_depth",
     # -- trainer / engine / data planes ----------------------------------------

@@ -18,8 +18,9 @@ Differences from the reference's execution (same semantics, new substrate):
 - the PS hub may be the C++ one (``native/ps_server.cpp``) — commits then
   apply outside the GIL, so in-process worker threads genuinely overlap;
 - weights travel as raw float32 frames, not pickles — through the
-  zero-copy flat framing path (one preallocated frame per direction,
-  ``recv_into`` scatter receives; ``networking.FlatFrameCodec``);
+  zero-copy flat framing path (``recv_into`` scatter receives; a dense
+  commit streamed leaf by leaf from the device to the socket, its
+  copy-out issued at dispatch; ``networking.FlatFrameCodec``);
 - the exchange is PIPELINED by default (``pipeline=True``): the pull for
   window k+1 is requested right after window k's program is dispatched
   and its reply is received on the worker's thread while that program
@@ -882,6 +883,11 @@ class AsyncDistributedTrainer(Trainer):
             sparse_on = bool(sparse_idx)
             sparse_fields = getattr(self, "_sparse_fields", None)
             cache_on = sparse_on and self.sparse_cache_rows is not None
+            # a dense float32 commit leaves the device leaf by leaf: its
+            # copy-out is issued at dispatch and the client sends each leaf
+            # as it lands.  Row-sparse and int8 commits are gathered or
+            # quantised as whole arrays, so they are fetched whole
+            commit_streams = not sparse_on and self.compress_commits is None
 
             def rows_of(window_x) -> List[np.ndarray]:
                 x = np.asarray(window_x)
@@ -1103,6 +1109,12 @@ class AsyncDistributedTrainer(Trainer):
                             with obs.phase("async.dispatch"):
                                 params, opt_state, commit, mloss = window_fn(
                                     params, opt_state, pulled, wx, wy)
+                                if commit_streams:
+                                    # the runtime queues each copy behind
+                                    # the program: D2H starts the moment it
+                                    # ends, whatever this thread is doing
+                                    for leaf in jax.tree.leaves(commit):
+                                        leaf.copy_to_host_async()
                                 # prefetch the NEXT window's pull while this
                                 # window's program runs: the request leaves
                                 # now (jax dispatch is async) and the
@@ -1130,6 +1142,12 @@ class AsyncDistributedTrainer(Trainer):
                                     else:
                                         client.pull_nowait()
                                         pull_pending = True
+                                # the previous commit's leaves, and with
+                                # them a commit's worth of host copies, go
+                                # here, beside the program: released where
+                                # the next commit is handed over, freeing
+                                # them is tens of ms on the chain
+                                payload = None
                             # this thread has nothing to do until the
                             # program is done, and the hub's send of the
                             # prefetched reply moves only while this end
@@ -1144,16 +1162,24 @@ class AsyncDistributedTrainer(Trainer):
                                 # (async_window_device_seconds, which the
                                 # benchmark's async_exchange_share reads)
                                 # from the commit's copy-out.  It moves no
-                                # time: the device_get below serialises on
-                                # the program anyway
+                                # time: the commit's first leaf serialises
+                                # on the program anyway
                                 with obs.phase("async.device_wait"):
                                     jax.block_until_ready(mloss)
                                 m_dev.observe(time.perf_counter() - t_dev)
-                            # one batched D2H for the payload; leaf order is
-                            # the same tree.flatten order as the templates
+                            # leaf order is the same tree.flatten order
+                            # as the templates.  A streamed commit goes to
+                            # the client as device leaves (their copies are
+                            # on the way; the client's send waits for each
+                            # where its bytes are due), so what is left of
+                            # the phase is the hand-over; the others take
+                            # one batched D2H here
                             with obs.phase("async.commit_d2h"):
-                                payload = jax.tree.leaves(
-                                    jax.device_get(commit))
+                                if commit_streams:
+                                    payload = jax.tree.leaves(commit)
+                                else:
+                                    payload = jax.tree.leaves(
+                                        jax.device_get(commit))
                             # fire-and-forget when pipelined: the ack
                             # coalesces into the next window's weights
                             # receive; else it is waited for here

@@ -679,61 +679,96 @@ class FlatFrameCodec:
     Both directions of the pull/commit exchange move frames whose layout
     is fully determined by the tensor templates; only the action byte and
     the tensor payloads vary per message.  So the codec derives all
-    offsets once at construction:
+    lengths once at construction:
 
-    - **send** (:meth:`pack` + :meth:`send_packed`, or :meth:`send`): one
-      frame buffer holds the prewritten frame length, tensor count, and
-      per-tensor length prefixes; per message the action byte is stamped
-      and each tensor is memcpy'd into its slot through a writable numpy
-      view, then the whole frame leaves in a single
-      ``sendall(memoryview)``.  Zero allocations, zero intermediate
-      ``bytes`` — this replaces the per-tensor ``tobytes()`` + ``join``
-      of the generic encoder.
+    - **send, packed** (:meth:`pack` + :meth:`send_packed`, or
+      :meth:`send`): one frame buffer (made on the first ``pack``) holds
+      the prewritten frame length, tensor count, and per-tensor length
+      prefixes; per message the action byte is stamped and each tensor is
+      memcpy'd into its slot through a writable numpy view, then the whole
+      frame leaves in a single ``sendall(memoryview)``.  For the sender
+      that must fix the frame's content at one instant and send it at
+      another: the hub packs a reply under its center lock and sends it
+      after releasing it.
+    - **send, streamed** (:meth:`send_streamed`): no frame is made.  The
+      frame has no checksum and nothing that depends on its whole body,
+      and every length is the schema's, so the bytes leave in wire order
+      straight out of each tensor's own buffer, one tensor at a time.
+      For the sender whose tensors are still ARRIVING (a ``jax.Array``
+      whose copy to the host was issued: ``np.asarray`` waits for that
+      one leaf and returns the landed buffer): the commit's copy-out, the
+      copy into a frame and the send become one stretch that the wire
+      bounds.  A codec that only streams never allocates the frame
+      buffer.
     - **recv_into**: the frame is scatter-read with ``recv_into``
       directly into caller-provided preallocated arrays; prefixes land in
       a small reusable scratch and are validated against the schema.
 
-    Wire bytes are IDENTICAL to :func:`encode_tensors`, so either end may
-    be a generic peer (including the C++ hub).  Not thread-safe: one
-    codec per connection/direction owner.  After any mid-frame exception
-    the stream is desynchronized — drop the connection."""
+    Wire bytes are IDENTICAL to :func:`encode_tensors` on either send
+    path, so either end may be a generic peer (including the C++ hub).
+    Not thread-safe: one codec per connection/direction owner.  After any
+    mid-frame exception the stream is desynchronized — drop the
+    connection."""
+
+    # streamed send: a piece shorter than this (the header, a prefix, a
+    # LayerNorm scale between two matrices) rides a scratch buffer with
+    # its neighbours and leaves with them in one write, so that
+    # TCP_NODELAY makes no packet of 8 bytes; a body at least this long
+    # leaves straight from its tensor's buffer
+    _STREAM_DIRECT = 1 << 18
 
     def __init__(self, templates: Sequence[np.ndarray]):
         self.templates = [np.asarray(t) for t in templates]
         self.payload_len = 5 + sum(8 + t.nbytes for t in self.templates)
         self.frame_len = 8 + self.payload_len
-        self._tx = bytearray(self.frame_len)
-        mv = memoryview(self._tx)
-        struct.pack_into(">Q", self._tx, 0, self.payload_len)
-        struct.pack_into(">I", self._tx, 9, len(self.templates))
+        # frame length, action byte (stamped per message), tensor count
+        self._head = bytearray(13)
+        struct.pack_into(">Q", self._head, 0, self.payload_len)
+        struct.pack_into(">I", self._head, 9, len(self.templates))
+        self._prefixes = [struct.pack(">Q", t.nbytes)
+                          for t in self.templates]
+        self._tx: Optional[bytearray] = None  # the packed frame, on demand
         self._tx_slots: List[np.ndarray] = []
-        pos = 13
-        for t in self.templates:
-            struct.pack_into(">Q", self._tx, pos, t.nbytes)
-            pos += 8
-            self._tx_slots.append(np.frombuffer(mv[pos:pos + t.nbytes],
-                                                dtype=t.dtype))
-            pos += t.nbytes
-        self._tx_mv = mv
+        self._small = memoryview(bytearray(self._STREAM_DIRECT))
         self._scratch = memoryview(bytearray(13))
+
+    def _check(self, arrays: Sequence[Any]) -> None:
+        """Count, dtype and size of every tensor against the schema, read
+        off the attributes alone: a device array is not waited for."""
+        if len(arrays) != len(self.templates):
+            raise ValueError(f"got {len(arrays)} tensors, schema has "
+                             f"{len(self.templates)}")
+        for tmpl, a in zip(self.templates, arrays):
+            if a.dtype != tmpl.dtype or a.size != tmpl.size:
+                raise ValueError(f"tensor {a.dtype}[{a.size}] does not match "
+                                 f"schema {tmpl.dtype}[{tmpl.size}]")
 
     def pack(self, action: bytes, arrays: Sequence[np.ndarray]) -> None:
         """Stamp ``action`` and memcpy each tensor into its frame slot.
         Split from :meth:`send_packed` so a server can pack under its
         center lock and send after releasing it."""
-        if len(arrays) != len(self.templates):
-            raise ValueError(f"got {len(arrays)} tensors, schema has "
-                             f"{len(self.templates)}")
+        arrays = [np.asarray(a) for a in arrays]
+        self._check(arrays)
+        if self._tx is None:
+            self._tx = bytearray(self.frame_len)
+            self._tx[:13] = self._head
+            mv = memoryview(self._tx)
+            pos = 13
+            for t, prefix in zip(self.templates, self._prefixes):
+                mv[pos:pos + 8] = prefix
+                pos += 8
+                self._tx_slots.append(np.frombuffer(mv[pos:pos + t.nbytes],
+                                                    dtype=t.dtype))
+                pos += t.nbytes
         self._tx[8:9] = action
-        for slot, tmpl, a in zip(self._tx_slots, self.templates, arrays):
-            a = np.asarray(a)
-            if a.dtype != tmpl.dtype or a.size != tmpl.size:
-                raise ValueError(f"tensor {a.dtype}[{a.size}] does not match "
-                                 f"schema {tmpl.dtype}[{tmpl.size}]")
+        for slot, a in zip(self._tx_slots, arrays):
             slot[...] = a.reshape(-1)
 
     def send_packed(self, sock: socket.socket) -> None:
-        sock.sendall(self._tx_mv)
+        sock.sendall(memoryview(self._tx))
+        self._count_frame()
+
+    def _count_frame(self) -> None:
         if obs.enabled():
             obs.counter("net_tx_frames_total").inc()
             obs.counter("net_tx_bytes_total").inc(self.frame_len)
@@ -742,6 +777,46 @@ class FlatFrameCodec:
              arrays: Sequence[np.ndarray]) -> None:
         self.pack(action, arrays)
         self.send_packed(sock)
+
+    def send_streamed(self, sock: socket.socket, action: bytes,
+                      arrays: Sequence[Any]) -> None:
+        """Send one frame of this schema WITHOUT packing it: header, then
+        per tensor its length prefix and its bytes, straight from the
+        tensor's buffer.  ``arrays`` are numpy arrays or device arrays
+        (anything with ``dtype`` / ``size`` that ``np.asarray`` turns into
+        its host value); each is turned into one only when its bytes are
+        due, so a device array whose copy-out is still in flight holds up
+        the bytes behind it and nothing before it.  A mismatch with the
+        schema raises before the first byte leaves; any later failure
+        leaves the stream mid-frame, as a failed ``sendall`` does."""
+        self._check(arrays)
+        self._head[8:9] = action
+
+        def pieces():
+            yield self._head
+            for prefix, a in zip(self._prefixes, arrays):
+                yield prefix
+                # C order is the wire's; a host copy that came back
+                # strided (seen from the TPU for a [256, 10] leaf) is
+                # made so here
+                a = np.ascontiguousarray(a)
+                if a.nbytes:
+                    yield memoryview(a.reshape(-1).view(np.uint8))
+
+        small, n = self._small, 0
+        for piece in pieces():
+            k = len(piece)
+            if n and n + k > len(small):
+                sock.sendall(small[:n])
+                n = 0
+            if k >= len(small):
+                sock.sendall(piece)
+            else:
+                small[n:n + k] = piece
+                n += k
+        if n:
+            sock.sendall(small[:n])
+        self._count_frame()
 
     def recv_into(self, sock: socket.socket,
                   out: Sequence[np.ndarray]) -> bytes:

@@ -3368,11 +3368,15 @@ class PSClient(_HotTierCacheSurface):
     ``commit_nowait`` / ``drain``).
 
     Framing is the zero-copy flat path (:class:`~.networking.FlatFrameCodec`):
-    commits leave through one preallocated frame buffer (one memcpy per
-    tensor, single ``sendall``), pulls scatter-receive with ``recv_into``
-    into one of two reusable landing buffers — double-buffered because the
-    caller may still be consuming pull *k* while the prefetched pull *k+1*
-    streams in.  Arrays returned by ``pull``/``wait_weights`` therefore
+    a dense float32 commit is STREAMED — no frame is packed, each leaf's
+    bytes leave from the leaf's own buffer in wire order, and a leaf handed
+    over as a device array whose copy-out was issued is waited for only
+    when its bytes are due (``send_streamed``); int8 and row-sparse commits,
+    which need whole arrays first, leave through a packed frame buffer
+    (one memcpy per tensor, single ``sendall``).  Pulls scatter-receive
+    with ``recv_into`` into one of two reusable landing buffers —
+    double-buffered because the caller may still be consuming pull *k*
+    while the prefetched pull *k+1* streams in.  Arrays returned by ``pull``/``wait_weights`` therefore
     alias client-owned storage that is REUSED two pulls later; copy
     anything that must outlive that.
 
@@ -3428,6 +3432,8 @@ class PSClient(_HotTierCacheSurface):
     weights (in ``wait_weights`` and ``commit_nowait``'s guard the stall the
     trainer pays; in ``land_weights`` it lies beside the device's compute),
     ``ps_pulls_landed_early_total`` replies claimed by ``land_weights``,
+    ``ps_commits_streamed_total`` commits sent without a packed frame
+    (beside the hub's ``ps_commits_total``),
     ``ps.serialize_ms`` frame-pack time, ``ps.inflight_depth`` unacked
     commits, ``ps.reconnects`` successful reconnections and
     ``ps.reconnect_ms`` fault-to-reconnected recovery time."""
@@ -4237,6 +4243,16 @@ class PSClient(_HotTierCacheSurface):
                 obs.gauge("ps.inflight_depth",
                           **self._mlabels).set(self._unacked())
             return
+        # a dense float32 commit needs no whole arrays before its first
+        # byte, so it is STREAMED: no frame is packed, each leaf's bytes
+        # leave from the leaf's own buffer (FlatFrameCodec.send_streamed)
+        # — for a device leaf whose copy-out was issued, as it lands.  A
+        # retry after a failure mid-stream sends the whole commit again
+        # from the same leaves (a landed device array keeps its host
+        # value), on a fresh connection: the hub applies a commit only
+        # once its last byte is in, so the cut one never counts.  int8
+        # quantises whole arrays first and keeps pack + send_packed
+        streamed = self.compress is None
         with obs.phase("ps.commit_pack"):
             if self.compress == "int8":
                 codec, action = self._q_codec, net.ACTION_QCOMMIT
@@ -4245,20 +4261,29 @@ class PSClient(_HotTierCacheSurface):
                 # after a failed (never-applied) send still lands the
                 # delta once
                 arrays = _quantize_commit(delta, self._residual)
+                codec.pack(action, arrays)
             else:
                 codec, action = self._codec, net.ACTION_COMMIT
-                arrays = [np.asarray(d, np.float32) for d in delta]
-            codec.pack(action, arrays)
+                # a float32 leaf goes as it is, device arrays included
+                # (converting one here would wait for its copy-out)
+                arrays = [d if getattr(d, "dtype", None) == np.float32
+                          else np.asarray(d, np.float32) for d in delta]
         if telemetry:
             obs.histogram("ps.serialize_ms", **self._mlabels).observe(
                 (time.perf_counter() - t0) * 1e3)
             obs.counter("ps.commit_bytes", **self._mlabels).inc(codec.frame_len)
         with obs.phase("ps.commit_send"):
             with self._io_lock:
-                codec.send_packed(self.sock)
+                if streamed:
+                    codec.send_streamed(self.sock, action, arrays)
+                else:
+                    codec.send_packed(self.sock)
                 self._pending.append((net.ACTION_ACK, time.perf_counter()))
                 self._last_io = time.monotonic()
         if telemetry:
+            if streamed:
+                obs.counter("ps_commits_streamed_total",
+                            **self._mlabels).inc()
             obs.gauge("ps.inflight_depth", **self._mlabels).set(self._unacked())
 
     def _cached_commit_arrays(self, delta: Sequence[np.ndarray],

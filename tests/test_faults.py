@@ -198,6 +198,58 @@ def test_fault_mid_land_restores_the_pull_and_reissues_it(kind, keep_bytes):
         ps.stop()
 
 
+@pytest.mark.parametrize("leaves", ["numpy", "device"])
+def test_cut_mid_streamed_commit_is_retried_and_applied_once(leaves):
+    """A dense commit leaves leaf by leaf with no packed frame.  The
+    connection dies 5 MB into a 24 MB commit — inside the second of three
+    leaves, far past what the kernel buffers — so the client's send fails
+    MID-STREAM: it reconnects and sends the whole commit again from the same
+    leaves (a landed device array keeps its host value), the hub never
+    applies the cut one (its last byte never came), and the center moves by
+    the commit exactly once."""
+    import jax
+
+    from distkeras_tpu import observability as obs
+
+    n = 1 << 21
+    tmpl = [np.zeros((n,), np.float32) for _ in range(3)]
+    rng = np.random.default_rng(4)
+    delta = [rng.standard_normal(n).astype(np.float32) for _ in tmpl]
+    ps = DeltaParameterServer(tmpl)
+    ps.start()
+    # c2s frames: 0 the pull's request, 1 the commit
+    plan = FaultPlan([Fault(conn=0, direction="c2s", frame=1,
+                            kind="truncate", keep_bytes=5 << 20)])
+    obs.reset()
+    obs.enable()
+    try:
+        with ChaosProxy("127.0.0.1", ps.port, plan) as proxy:
+            # the severed proxy socket answers the bytes still coming with a
+            # reset, or (unread bytes queued at the sever) stops taking them:
+            # then the send's timeout is the fault, as for any wedged peer
+            with PSClient("127.0.0.1", proxy.port, templates=tmpl,
+                          max_reconnects=5, reconnect_backoff=0.02,
+                          timeout=2.0) as c:
+                assert all(np.all(w == 0) for w in c.pull())
+                payload = delta
+                if leaves == "device":
+                    payload = [jax.device_put(d) for d in delta]
+                    for leaf in payload:
+                        leaf.copy_to_host_async()
+                c.commit(payload)
+                assert len(proxy.faults_fired) == 1
+                assert c.reconnects_used == 1
+                for w, d in zip(c.pull(), delta):
+                    np.testing.assert_array_equal(w, d)
+        assert ps.num_updates == 1
+        # the cut attempt is not counted: one commit went out whole
+        assert obs.snapshot()["counters"]["ps_commits_streamed_total"] == 1
+    finally:
+        obs.disable()
+        obs.reset()
+        ps.stop()
+
+
 # -- reconnect/backoff bounds --------------------------------------------------
 
 def test_reconnect_storm_bounded_by_budget_and_backoff():

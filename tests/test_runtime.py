@@ -514,9 +514,9 @@ def test_land_weights_with_nothing_pending_and_the_guard_stands(telemetry):
 
             c.pull_nowait()
             sent = []
-            real = c._codec.send_packed
-            c._codec.send_packed = lambda sock: (
-                sent.append([kind for kind, _ in c._pending]), real(sock))
+            real = c._codec.send_streamed
+            c._codec.send_streamed = lambda *a: (
+                sent.append([kind for kind, _ in c._pending]), real(*a))
             stalls = _stall_samples()
             c.commit_nowait(one)
             # when the commit's bytes left, the weights reply had been claimed

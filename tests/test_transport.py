@@ -48,6 +48,11 @@ def _train(trainer_name, toy_dataset, *, transport, pipeline, num_workers=1,
     return trainer, model
 
 
+def _total(series, name, of=lambda v: v):
+    """One telemetry series summed over its label sets (every shard)."""
+    return sum(of(v) for k, v in series.items() if k.split("{", 1)[0] == name)
+
+
 def _assert_bit_identical(run_a, run_b):
     import jax
 
@@ -113,17 +118,133 @@ def test_pulls_landed_early_counts_every_prefetch(extra, per_run, toy_dataset,
     kw = {"transport": "socket", "pipeline": True, **extra}
     trainer, _ = _train("AsyncADAG", toy_dataset, **kw)
     snap = telemetry.snapshot()
-
-    def total(series, name, of=lambda v: v):    # every shard label added up
-        return sum(of(v) for k, v in snap[series].items()
-                   if k.split("{", 1)[0] == name)
-
-    landed = total("counters", "ps_pulls_landed_early_total")
+    landed = _total(snap["counters"], "ps_pulls_landed_early_total")
     assert landed == per_run(len(trainer.history), 2)
     if kw["transport"] != "inproc":
         # one sample a land and one a (socket-free) wait_weights, none a guard
-        assert total("histograms", "ps.pull_stall_ms", lambda h: h["count"]) \
-            == total("counters", "ps_pulls_total") + landed
+        assert _total(snap["histograms"], "ps.pull_stall_ms",
+                      lambda h: h["count"]) \
+            == _total(snap["counters"], "ps_pulls_total") + landed
+
+
+# -- the streamed dense commit (ISSUE 35) ---------------------------------------
+# A dense float32 commit leaves without a packed frame, each leaf from its
+# own buffer; the worker loop hands the client DEVICE leaves whose copies it
+# issued at dispatch.  The trajectory cases above run that path on socket and
+# shm and compare it, bit for bit, with inproc, which has no codec at all.
+
+def _streamed_templates():
+    """Leaves on both sides of the codec's direct-send threshold, one of
+    them empty, over more bytes than a loopback socket buffers."""
+    return [np.zeros(s, np.float32)
+            for s in [(3,), (700, 1024), (0, 5), (17, 9), (1 << 19,), (1,)]]
+
+
+@pytest.mark.parametrize("wire", ["socket", "shm", "shards2"])
+def test_device_leaf_commit_lands_equal_to_numpy_commit(wire, tmp_path,
+                                                        telemetry):
+    """The same commit as numpy arrays and as ``jax.Array`` leaves with
+    ``copy_to_host_async()`` issued: the hub's center moves by the same
+    bits, and both commits (and a stripe's every part) count as streamed."""
+    import jax
+
+    from distkeras_tpu.runtime.parameter_server import (
+        DeltaParameterServer, PSClient, ShardedParameterServer,
+        ShardedPSClient, shard_plan)
+
+    tmpl = _streamed_templates()
+    rng = np.random.default_rng(35)
+    delta = [rng.standard_normal(t.shape).astype(np.float32) for t in tmpl]
+    shards = 2 if wire == "shards2" else 1
+    if shards == 2:
+        plan = shard_plan(tmpl, 2)
+        ps = ShardedParameterServer(
+            tmpl, plan, lambda w, sid: DeltaParameterServer(
+                w, shard_id=sid, idle_timeout=None))
+    else:
+        ps = DeltaParameterServer(
+            [t.copy() for t in tmpl], idle_timeout=None,
+            shm_dir=str(tmp_path) if wire == "shm" else None)
+    ps.start()
+    try:
+        if shards == 2:
+            client = ShardedPSClient([("127.0.0.1", p) for p in ps.ports],
+                                     tmpl, plan)
+        else:
+            client = PSClient("127.0.0.1", ps.port, templates=tmpl,
+                              shm=wire == "shm")
+            assert client.transport == ("shm" if wire == "shm" else "tcp")
+        with client:
+            client.commit(delta)
+            after_numpy = [w.copy() for w in client.pull()]
+            leaves = [jax.device_put(d) for d in delta]
+            for leaf in leaves:
+                leaf.copy_to_host_async()
+            client.commit(leaves)
+            after_device = [w.copy() for w in client.pull()]
+        for d, one, two in zip(delta, after_numpy, after_device):
+            np.testing.assert_array_equal(one, d)
+            np.testing.assert_array_equal(two, d + d)   # exact in float32
+        assert _total(telemetry.snapshot()["counters"],
+                      "ps_commits_streamed_total") == 2 * shards
+    finally:
+        ps.stop()
+
+
+@pytest.mark.parametrize("extra,streams", [
+    ({}, True),
+    ({"transport": "shm"}, True),
+    ({"pipeline": False}, True),
+    ({"num_shards": 2}, True),  # per stripe: both counters count its parts
+    pytest.param({"native_ps": True}, True, marks=native_mark()),
+    # whole arrays are needed first: quantised, or gathered by row
+    ({"compress_commits": "int8"}, False),
+    ({"sparse_tables": "auto"}, False),
+    # no wire, no frame
+    ({"transport": "inproc"}, False),
+])
+def test_commits_streamed_counts_every_dense_commit(extra, streams,
+                                                    toy_dataset, telemetry):
+    """``ps_commits_streamed_total`` beside the hub's ``ps_commits_total``:
+    equal for a dense float32 run on a wire, zero where the commit is
+    packed (int8, row-sparse) or never framed (inproc).  The C++ hub keeps
+    its count to itself: there the client's equals the windows."""
+    kw = {"transport": "socket", "pipeline": True, **extra}
+    trainer, _ = _train("AsyncADAG", toy_dataset, **kw)
+    counters = telemetry.snapshot()["counters"]
+    commits = _total(counters, "ps_commits_total") or len(trainer.history)
+    assert commits >= len(trainer.history) > 0
+    assert _total(counters, "ps_commits_streamed_total") \
+        == (commits if streams else 0)
+
+
+@pytest.mark.parametrize("extra,device_leaves", [
+    ({}, True),
+    ({"pipeline": False}, True),
+    ({"compress_commits": "int8"}, False),
+    ({"sparse_tables": "auto"}, False),
+])
+def test_worker_loop_hands_device_leaves_only_for_a_dense_commit(
+        extra, device_leaves, toy_dataset, monkeypatch):
+    """The worker loop gives the client the commit's ``jax.Array`` leaves
+    (copies issued at dispatch) exactly where the client streams; a commit
+    that is quantised or gathered by row is fetched whole, as numpy."""
+    import jax
+
+    from distkeras_tpu.runtime.parameter_server import PSClient
+
+    seen = []
+    real = PSClient.commit_nowait
+
+    def spy(self, delta, sparse_rows=None):
+        seen.append({isinstance(d, jax.Array) for d in delta})
+        return real(self, delta, sparse_rows=sparse_rows)
+
+    monkeypatch.setattr(PSClient, "commit_nowait", spy)
+    trainer, _ = _train("AsyncADAG", toy_dataset, transport="socket",
+                        **{"pipeline": True, **extra})
+    assert len(seen) == len(trainer.history)
+    assert all(kinds == {device_leaves} for kinds in seen)
 
 
 def test_inproc_matches_socket_with_int8_commits(toy_dataset):
