@@ -109,5 +109,10 @@ TELEMETRY_NAMES = frozenset({
     # its six parts
     "attn.latent", "attn.latent.q", "attn.latent.down", "attn.latent.up",
     "attn.latent.rope", "attn.latent.core", "attn.latent.out",
+    # the parts of the step no mixer owns, for the device-time account
+    # (obs.device_account): the dense MLP (TransformerBlock._dense_ffn), the
+    # embedding and the head (TransformerLM), and the step's own loss, update
+    # and window commit (parallel/engine.py)
+    "ffn.dense", "lm.embed", "lm.head", "step.loss", "step.update", "step.commit",
     "punchcard.job",
 })

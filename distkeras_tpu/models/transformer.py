@@ -396,6 +396,11 @@ class TransformerBlock(nn.Module):
 
     @nn.nowrap
     def _ffn(self, x, ffn_local: int):
+        if self.ffn_kind == "dense" and not self.moe_experts:
+            # the dense MLP with its norms is one part of the device account
+            # (obs.device_account); the routed layers keep their moe.* scopes
+            with jax.named_scope("ffn.dense"):
+                return self._dense_ffn(x, ffn_local)
         y = self._norm("ffn_norm")(x) if self.pre_norm else x
         if self.ffn_kind == "moe":
             from distkeras_tpu.parallel.moe import HeldExpertsMLP
@@ -412,23 +417,27 @@ class TransformerBlock(nn.Module):
             return self._norm("ffn_post_norm")(y) if self.post_norm else y
         if self.ffn_kind != "dense":
             raise ValueError(f"ffn_kind must be 'dense' or 'moe', got {self.ffn_kind!r}")
-        if self.moe_experts:
-            from distkeras_tpu.parallel.moe import MoEMLP
+        # what is left: the capacity-dispatch MoE MLP (moe_experts)
+        from distkeras_tpu.parallel.moe import MoEMLP
 
-            b, l, e = y.shape
-            # default capacity: factor-2 over the balanced share per expert
-            # (capacity T would make dispatch [T, E, T] — O(T^2) memory)
-            cap = self.moe_capacity or -(-2 * b * l // self.moe_experts)
-            moe_out, aux = MoEMLP(
-                num_experts=self.moe_experts, model_dim=self.model_dim,
-                hidden_dim=self.mlp_ratio * self.model_dim,
-                capacity=cap,
-                ep_axis=self.ep_axis, ep_size=self.ep_size,
-                router_top_k=self.moe_top_k,
-                dispatch_impl=self.moe_dispatch,
-                compute_dtype=self.compute_dtype, name="moe")(y.reshape(b * l, e))
-            self.sow("aux_loss", "load_balance", aux)
-            return moe_out.reshape(b, l, e)
+        b, l, e = y.shape
+        # default capacity: factor-2 over the balanced share per expert
+        # (capacity T would make dispatch [T, E, T] — O(T^2) memory)
+        cap = self.moe_capacity or -(-2 * b * l // self.moe_experts)
+        moe_out, aux = MoEMLP(
+            num_experts=self.moe_experts, model_dim=self.model_dim,
+            hidden_dim=self.mlp_ratio * self.model_dim,
+            capacity=cap,
+            ep_axis=self.ep_axis, ep_size=self.ep_size,
+            router_top_k=self.moe_top_k,
+            dispatch_impl=self.moe_dispatch,
+            compute_dtype=self.compute_dtype, name="moe")(y.reshape(b * l, e))
+        self.sow("aux_loss", "load_balance", aux)
+        return moe_out.reshape(b, l, e)
+
+    @nn.nowrap
+    def _dense_ffn(self, x, ffn_local: int):
+        y = self._norm("ffn_norm")(x) if self.pre_norm else x
         if self.mlp == "swiglu":
             if not self.mlp_dim:
                 raise ValueError("mlp 'swiglu' needs its width, mlp_dim")
@@ -662,23 +671,25 @@ class TransformerLM(nn.Module):
         Under ``positional="rope"`` there is no table — position enters
         through the per-block q/k rotation instead.
         """
-        x = self.embed(tokens)
-        if self.embed_scale != 1.0:
-            x = x * jnp.asarray(self.embed_scale, x.dtype)
-        if self.positional != "learned":
-            return x
-        pos = jnp.arange(tokens.shape[1]) + pos_offset
-        return x + self.pos_embed[pos].astype(self.compute_dtype)
+        with jax.named_scope("lm.embed"):
+            x = self.embed(tokens)
+            if self.embed_scale != 1.0:
+                x = x * jnp.asarray(self.embed_scale, x.dtype)
+            if self.positional != "learned":
+                return x
+            pos = jnp.arange(tokens.shape[1]) + pos_offset
+            return x + self.pos_embed[pos].astype(self.compute_dtype)
 
     def head(self, x: jnp.ndarray) -> jnp.ndarray:
         """Final norm + unembedding (tied to the embedding unless
         ``tie_word_embeddings`` is off): [B, L, E] -> [B, L, vocab] logits."""
-        x = self.final_norm(x)
-        if not self.tie_word_embeddings:
-            # float32 for the loss: a softmax and a mean taken in bfloat16
-            # hand back a loss near 10 in steps of 0.0625
-            return self.lm_head(x).astype(jnp.float32)
-        return self.embed.attend(x.astype(jnp.float32))
+        with jax.named_scope("lm.head"):
+            x = self.final_norm(x)
+            if not self.tie_word_embeddings:
+                # float32 for the loss: a softmax and a mean taken in bfloat16
+                # hand back a loss near 10 in steps of 0.0625
+                return self.lm_head(x).astype(jnp.float32)
+            return self.embed.attend(x.astype(jnp.float32))
 
     def _trunk(self, tokens: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
         """Embedding + blocks, BEFORE the final norm: [B, L] -> [B, L, E]."""
@@ -707,7 +718,9 @@ class TransformerLM(nn.Module):
         materialized by ``head``'s float32 ``attend`` — kills the
         half-rate f32 unembed matmul and O(B*L*V) activation memory.
         """
-        return self.final_norm(self._trunk(tokens, pos_offset))
+        x = self._trunk(tokens, pos_offset)
+        with jax.named_scope("lm.head"):
+            return self.final_norm(x)
 
     def __call__(self, tokens: jnp.ndarray, pos_offset: int = 0) -> jnp.ndarray:
         return self.head(self._trunk(tokens, pos_offset))

@@ -64,7 +64,7 @@ __all__ = [
     "phase", "NULL_SPAN",
     "snapshot", "chrome_trace", "render_prometheus", "reset",
     "track", "untrack", "series", "tracked_snapshot",
-    "note_program", "device_scopes",
+    "note_program", "device_scopes", "device_account",
 ]
 
 
@@ -152,6 +152,7 @@ def reset() -> None:
 # compiled text; ``device_scopes`` turns it into {instruction: op_name}, so
 # that a reader of the trace can tell which scope a device event ran under.
 _PROGRAMS: dict = {}
+_ACCOUNTS: dict = {}
 _OP_NAME = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"', re.M)
 
 
@@ -159,17 +160,37 @@ def note_program(name: str, compiled_text) -> None:
     """``compiled_text()`` -> the compiled HLO text of the program whose XLA
     module is called ``name`` (``jit_<fn>``); kept lazily, the last one wins."""
     _PROGRAMS[name] = compiled_text
+    _ACCOUNTS.pop(name, None)
+
+
+def _program_text(name: str):
+    text = _PROGRAMS.get(name)
+    if callable(text):
+        text = _PROGRAMS[name] = text()    # compiled once, on first asking
+    return text
 
 
 def device_scopes(name: str):
     """{HLO instruction name: its ``op_name``} of the program noted under
     ``name``, or ``None`` where none was (telemetry off, another plane)."""
-    text = _PROGRAMS.get(name)
-    if text is None:
-        return None
-    if callable(text):
-        text = _PROGRAMS[name] = text()    # compiled once, on first asking
-    return dict(_OP_NAME.findall(text))
+    text = _program_text(name)
+    return None if text is None else dict(_OP_NAME.findall(text))
+
+
+def device_account(name: str):
+    """Every instruction of the program noted under ``name`` sorted by the
+    part of the step it belongs to and the pass it runs in: an
+    :class:`~.account.DeviceAccount` read off the same compiled text as
+    :func:`device_scopes` (kept until the next ``note_program``), or ``None``
+    where no program was noted.  The rules are :mod:`.account`'s."""
+    if name not in _ACCOUNTS:
+        from distkeras_tpu.observability.account import account
+
+        text = _program_text(name)
+        if text is None:
+            return None
+        _ACCOUNTS[name] = account(text)
+    return _ACCOUNTS[name]
 
 
 # lazy access to the distributed-tracing layer (PEP 562): obs.TraceContext,

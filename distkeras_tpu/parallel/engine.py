@@ -18,6 +18,8 @@ host round-trips, the commit is an ICI allreduce fused into the step.
 
 from __future__ import annotations
 
+import functools
+import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -54,6 +56,39 @@ def _ensure_varying(x, axis_name: str):
     return lax.pcast(x, (axis_name,), to="varying")
 
 
+# a dotted lower-case name on an op's path: a ``jax.named_scope`` of the
+# program's (no flax module, transform or file is named so)
+_SCOPE_NAME = re.compile(r'[/("]([a-z]+(?:\.[a-z_]+)+)(?<!\.py)(?=[/")])')
+
+
+def _compiled_text(fn, avals, **jit_kw) -> str:
+    """The compiled HLO text of the jitted ``fn`` at ``avals`` with THIS
+    program's metadata.  JAX's persistent compilation cache leaves metadata
+    out of its key, so an executable cached before a ``jax.named_scope`` was
+    added (by another commit that shares the cache directory) is served
+    again with its old ``op_name``s, and every new scope reads empty with no
+    error.  Where a scope of the lowered program is missing from the compiled
+    text, the function is traced anew and compiled with the metadata in the
+    key (the flag is the process's for the length of that one compile; only
+    an ask with telemetry on reaches it); the instruction names are those of
+    the executable that ran (the optimiser does not read metadata)."""
+    lowered = fn.lower(*avals)
+    text = lowered.compile().as_text()
+    scopes = set(_SCOPE_NAME.findall(lowered.as_text(debug_info=True)))
+    if all(name in text for name in scopes):
+        return text
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        # a new function object: the old one's lowering and executable are
+        # cached in memory under it
+        again = functools.wraps(fn.__wrapped__)(lambda *args: fn.__wrapped__(*args))
+        return jax.jit(again, **jit_kw).lower(*avals).compile().as_text()
+    finally:
+        jax.config.update(key, before)
+
+
 def make_minibatch_step(apply_fn: Callable, loss: Callable,
                         optimizer: optax.GradientTransformation,
                         with_rng: bool = False, hook=None) -> Callable:
@@ -70,35 +105,43 @@ def make_minibatch_step(apply_fn: Callable, loss: Callable,
     ``hook.update(params, stats)`` moves its leaves, and the step's output
     is ``(loss, stats)`` instead of the loss.
     """
+    def scoped_loss(out, labels):
+        # step.loss / step.update (and step.commit in the window program) name
+        # the step's own parts for the device account (obs.device_account)
+        with jax.named_scope("step.loss"):
+            return loss(out, labels)
+
     if hook is not None:
         if with_rng:
             raise ValueError("a step hook and a dropout key stream do not compose (v1)")
 
         def hooked_loss(params, batch):
             out, stats = hook.apply(params, batch[0])
-            return loss(out, batch[1]), stats
+            return scoped_loss(out, batch[1]), stats
 
         def hooked_step(carry, batch):
             params, opt_state = carry
             (loss_val, stats), grads = jax.value_and_grad(
                 hooked_loss, has_aux=True)(params, batch)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = hook.update(optax.apply_updates(params, updates), stats)
+            with jax.named_scope("step.update"):
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = hook.update(optax.apply_updates(params, updates), stats)
             return (params, opt_state), (loss_val, stats)
 
         return hooked_step
     if with_rng:
         def loss_of(params, batch):
-            return loss(apply_fn(params, batch[0], batch[2]), batch[1])
+            return scoped_loss(apply_fn(params, batch[0], batch[2]), batch[1])
     else:
         def loss_of(params, batch):
-            return loss(apply_fn(params, batch[0]), batch[1])
+            return scoped_loss(apply_fn(params, batch[0]), batch[1])
 
     def step(carry, batch):
         params, opt_state = carry
         loss_val, grads = jax.value_and_grad(loss_of)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("step.update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, opt_state), loss_val
 
     return step
@@ -265,16 +308,18 @@ class WindowEngine:
                     wx, wy = window_batches
                     batches = (wx, wy)
                 (local, opt_state), losses = lax.scan(mini, (local, opt_state), batches)
-                if hook is not None:
-                    # the window's stats: summed over its steps and replicas
-                    losses, stats = losses
-                    stats = jax.tree.map(lambda a: lax.psum(jnp.sum(a, axis=0), axis), stats)
-                center, local, extra = algo.window_commit(center, local, extra, axis)
-                # commit rules that reset local to the (mesh-invariant) center
-                # change the carry's varying-axes type; cast it back
-                local = jax.tree.map(lambda x: _ensure_varying(x, axis), local)
-                extra = jax.tree.map(lambda x: _ensure_varying(x, axis), extra)
-                mean_loss = lax.pmean(jnp.mean(losses), axis)
+                with jax.named_scope("step.commit"):
+                    if hook is not None:
+                        # the window's stats: summed over its steps and replicas
+                        losses, stats = losses
+                        stats = jax.tree.map(
+                            lambda a: lax.psum(jnp.sum(a, axis=0), axis), stats)
+                    center, local, extra = algo.window_commit(center, local, extra, axis)
+                    # commit rules that reset local to the (mesh-invariant) center
+                    # change the carry's varying-axes type; cast it back
+                    local = jax.tree.map(lambda x: _ensure_varying(x, axis), local)
+                    extra = jax.tree.map(lambda x: _ensure_varying(x, axis), extra)
+                    mean_loss = lax.pmean(jnp.mean(losses), axis)
                 if hook is not None:
                     return (center, local, opt_state, extra), (mean_loss, stats)
                 return (center, local, opt_state, extra), mean_loss
@@ -386,8 +431,8 @@ class WindowEngine:
                 fn, avals = self._epoch_fns[1], jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
                     (state, xs_d, ys_d, keys_d))
-                obs.note_program("jit_shard_fn",
-                                 lambda: fn.lower(*avals).compile().as_text())
+                obs.note_program(
+                    "jit_shard_fn", lambda: _compiled_text(fn, avals, donate_argnums=(0,)))
             with obs.phase("engine.dispatch"):
                 state, losses = self._epoch_fns[1](state, xs_d, ys_d, keys_d)
             with obs.phase("engine.device_wait"):
