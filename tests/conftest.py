@@ -17,11 +17,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # -- tier-1 budget tripwire ----------------------------------------------------
 # The driver runs the 'not slow' subset on six xdist workers under a hard
 # 1,470 s timeout, and a run it cuts counts only as far as it got.  Warn
-# LOUDLY at 700 s (460-570 s here since PR 30) so the margin erodes in
-# plain sight instead of flaking first.
+# LOUDLY at 700 s (539 s here at PR 37) so the margin erodes in plain
+# sight instead of flaking first.  The tripwire only warns after the run:
+# what ends a test that hangs is the per-test limit (tests/time_limit.py).
 # DK_TIER1_WARN_S overrides the threshold (testing the tripwire itself).
 TIER1_WARN_S = float(os.environ.get("DK_TIER1_WARN_S", "700"))
 _session_t0 = time.monotonic()
+
+
+def pytest_configure(config):
+    from time_limit import TimeLimit
+
+    config.pluginmanager.register(TimeLimit(config), "dk-time-limit")
 
 
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
@@ -50,6 +57,18 @@ def require_tool(*names):
     for name in names:
         if shutil.which(name) is None:
             _pytest.skip(f"no {name} in this container")
+
+
+def join_all(threads, what, timeout=60.0):
+    """Join ``threads`` within one ``timeout`` for the lot, below the
+    per-test soft limit (``tests/time_limit.py``), and fail naming ``what``
+    they were doing if one is still alive: ``join`` returns None either
+    way."""
+    deadline = time.monotonic() + timeout
+    for t in threads:
+        t.join(timeout=max(deadline - time.monotonic(), 0.0))
+    alive = [t.name for t in threads if t.is_alive()]
+    assert not alive, f"{what}: {alive} still running after {timeout:g} s"
 
 
 def native_mark():

@@ -25,6 +25,7 @@ import time
 
 import pytest
 
+from conftest import join_all
 from distkeras_tpu.analysis import (blocking, cli, guarded_by, lock_manifest,
                                     lock_order, lockset, protocol_model,
                                     telemetry)
@@ -1179,15 +1180,14 @@ def _run_together(fns):
     gate = threading.Barrier(len(fns))
 
     def body(fn):
-        gate.wait()
+        gate.wait(timeout=60)
         for _ in range(50):
             fn()
 
     ts = [threading.Thread(target=body, args=(fn,)) for fn in fns]
     for t in ts:
         t.start()
-    for t in ts:
-        t.join()
+    join_all(ts, "the lockset writers")
 
 
 def test_lockset_declared_guard_violation_detected():
@@ -1262,8 +1262,7 @@ def test_lockset_consistent_locking_and_handoff_are_clean():
         ts.append(threading.Thread(target=c.loop))
         for t in ts:
             t.start()
-        for t in ts:
-            t.join()
+        join_all(ts, "the lockset writers")
     assert not chk.findings, [str(f) for f in chk.findings]
     assert chk.writes_checked > 0
 

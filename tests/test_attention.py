@@ -88,6 +88,7 @@ def test_ring_dead_steps_are_predicated():
     assert causal_conds - noncausal_conds == 3, (causal_conds, noncausal_conds)
 
 
+@pytest.mark.slow  # 36 s alone: the flash ring's backward in interpret mode on 4 devices
 def test_ring_gradients_match_dense():
     """Gradients through the flash-backed ring (incl. the lse cotangent
     path through the online merge) == dense attention gradients."""

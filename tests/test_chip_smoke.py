@@ -13,6 +13,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -62,8 +63,15 @@ def fresh_interpreters(tmp_path_factory):
                                     stdout=subprocess.PIPE,
                                     stderr=subprocess.PIPE)
              for name, (cmd, env) in cmds.items()}
-    out = {name: (p.communicate(timeout=120), p.returncode)
-           for name, p in procs.items()}
+    deadline = time.monotonic() + 120  # for the four together
+    try:
+        out = {name: (p.communicate(timeout=max(deadline - time.monotonic(), 1.0)),
+                      p.returncode)
+               for name, p in procs.items()}
+    finally:
+        for p in procs.values():
+            if p.poll() is None:
+                p.kill()
     out["given_dir"] = given_dir
     return out
 

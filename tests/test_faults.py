@@ -525,8 +525,9 @@ def test_restore_racing_save_loop_never_loses_a_step(tmp_path):
             assert ps.snapshotter.restore_latest()
     finally:
         stop.set()
-        t.join()
+        t.join(timeout=60)
         ps.kill()
+    assert not t.is_alive(), "the snapshot saver"
     assert not errors, errors
     steps = sorted(int(d.split("_")[-1]) for d in os.listdir(snap_dir))
     assert steps and ps.snapshotter._next_step > max(steps)

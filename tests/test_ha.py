@@ -1081,7 +1081,7 @@ def _kill_primary_drill(trainer_name, pipeline=True, after_commits=8):
                                             **kwargs)
         kill_plan.start(primary)
         model = trainer.train(_tiny_dataset())
-        kill_plan.join()
+        kill_plan.join(timeout=30)
         assert kill_plan.fired.is_set(), "primary was never killed"
         assert replica.promoted, "standby never promoted"
         assert trainer.worker_errors == []

@@ -17,6 +17,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import join_all
 from distkeras_tpu import observability as obs
 from distkeras_tpu.observability import health as health_mod
 from distkeras_tpu.observability.health import (
@@ -413,8 +414,7 @@ def test_tracked_counter_concurrent_incs_stay_monotonic():
         for _ in range(4)]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads, "the counter writers")
     s = counter.series
     values = [v for _, v in s.samples()]
     assert all(b >= a for a, b in zip(values, values[1:])), "out-of-order"
@@ -1445,10 +1445,8 @@ def test_live_drill_straggler_and_failover_events_visible_mid_run(
             "proxied worker landed no delayed reports"
         for th in threads[:3]:
             th.start()
-        for th in threads:
-            th.join(timeout=120)
-        assert not any(th.is_alive() for th in threads)
-        kill_plan.join()
+        join_all(threads, "the drill's workers", timeout=120)
+        kill_plan.join(timeout=30)
         assert kill_plan.fired.is_set(), "primary never killed"
         assert _wait_until(lambda: replica.promoted, timeout=10)
         # give the poller one more detector cadence to observe the tail

@@ -12,6 +12,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -37,20 +38,28 @@ def _child_env():
     return env
 
 
-def _run_children(cmds, timeout=240):
+def _run_children(cmds, timeout=150):
+    """Run the children together within one ``timeout`` for the lot, below
+    the per-test soft limit: a child still running then is killed (rc -9)
+    and the failure shows what it printed."""
     procs = [subprocess.Popen(c, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
                               text=True, env=_child_env()) for c in cmds]
+    deadline = time.monotonic() + timeout
     outs = []
     try:
         for p in procs:
-            out, _ = p.communicate(timeout=timeout)
+            try:
+                out, _ = p.communicate(timeout=max(deadline - time.monotonic(), 1.0))
+            except subprocess.TimeoutExpired:
+                p.kill()
+                out, _ = p.communicate(timeout=30)
             outs.append(out)
     finally:
         for p in procs:
             if p.poll() is None:
                 p.kill()
     for p, out in zip(procs, outs):
-        assert p.returncode == 0, f"child {p.args} failed:\n{out}"
+        assert p.returncode == 0, f"child {p.args} failed (rc {p.returncode}):\n{out}"
     return outs
 
 

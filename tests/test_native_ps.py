@@ -7,6 +7,7 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import join_all
 from distkeras_tpu.runtime.native import (
     MODE_ADAG,
     MODE_DELTA,
@@ -112,8 +113,7 @@ def test_native_concurrent_commits_all_land():
         threads = [threading.Thread(target=work, args=(i,)) for i in range(n_workers)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
+        join_all(threads, "the committing workers")
         np.testing.assert_allclose(ps.get_weights()[0], np.full((64,), n_workers * n_commits))
         assert ps.num_updates == n_workers * n_commits
     finally:
@@ -423,8 +423,7 @@ def test_native_adaptive_concurrent_commits_merge_and_advance_clock():
                    for i in range(n_workers)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
+        join_all(threads, "the committing workers")
         st = ps.stats()
         assert ps.num_updates == n_workers * n_commits
         assert st["clock"] == n_workers * n_commits
@@ -774,7 +773,7 @@ def test_cross_language_ring_byte_identical(tmp_path):
         n = lib.dk_shm_ring_read(chandle, cbuf, 512, 2000)
         assert n > 0
         got2 += cbuf.raw[:n]
-    writer.join()
+    join_all([writer], "the Python ring producer")
     assert bytes(got2) == payload * 4
     py_prod.close()  # EOF crosses the language boundary too
     assert lib.dk_shm_ring_read(chandle, cbuf, 512, 2000) == 0

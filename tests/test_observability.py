@@ -18,6 +18,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import join_all
 from distkeras_tpu import observability as obs
 from distkeras_tpu.observability import (
     DEFAULT_BUCKETS,
@@ -107,8 +108,7 @@ def test_thread_safety_under_concurrent_writers():
     threads = [threading.Thread(target=writer, args=(i,)) for i in range(8)]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads, "the metric writers")
     assert c.value == 8000
     assert h.count == 8000
 

@@ -10,7 +10,7 @@ import tracemalloc
 import numpy as np
 import pytest
 
-from conftest import native_mark
+from conftest import join_all, native_mark
 from distkeras_tpu import observability as obs
 from distkeras_tpu.runtime import networking as net
 from distkeras_tpu.runtime.parameter_server import (
@@ -340,8 +340,7 @@ def test_concurrent_commits_all_land():
         threads = [threading.Thread(target=work, args=(i,)) for i in range(n_workers)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
+        join_all(threads, "the committing workers")
         np.testing.assert_allclose(ps.get_weights()[0], np.full((16,), n_workers * n_commits))
         assert ps.num_updates == n_workers * n_commits
     finally:
@@ -552,8 +551,9 @@ def test_ps_killed_mid_run_surfaces_clean_error_no_hang():
                 c.wait_weights()
         assert _time.monotonic() < deadline, "client hung on a dead hub"
     finally:
-        stopper.join()
+        stopper.join(timeout=60)
         c.sock.close()
+    assert not stopper.is_alive(), "the hub's stop()"
     # the center survived to the last APPLIED commit — an interrupted
     # frame must never half-apply
     applied = ps.get_weights()[0]

@@ -14,6 +14,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import join_all
 from distkeras_tpu import observability as obs
 from distkeras_tpu.runtime import networking as net
 from distkeras_tpu.runtime.parameter_server import (
@@ -85,7 +86,7 @@ def test_shm_ring_full_parks_then_unblocks_and_times_out(tmp_path):
     t = threading.Thread(target=drain)
     t.start()
     prod.write(b"z" * 8, timeout=2.0)  # unblocks once the drain lands
-    t.join()
+    join_all([t], "the ring consumer")
     prod.close()
     cons.close()
 

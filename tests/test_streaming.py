@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+from conftest import join_all
 from distkeras_tpu.models.base import Model, ModelSpec
 from distkeras_tpu.runtime.streaming import (
     StreamingClient, StreamingInferenceServer, stream_predict)
@@ -88,8 +89,7 @@ def test_concurrent_clients(served_model):
     threads = [threading.Thread(target=go, args=(i,)) for i in range(4)]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    join_all(threads, "the streaming clients")
     assert not errs
     for i in range(4):
         np.testing.assert_allclose(outs[i], model.predict(xs[i]), rtol=1e-5, atol=1e-6)
