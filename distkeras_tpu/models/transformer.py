@@ -29,10 +29,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distkeras_tpu import observability as obs
 from distkeras_tpu.models.base import ModelSpec, StepHook, register_model
 from distkeras_tpu.ops.attention import attention
+
+
+# The dense MLP's matmul outputs, as ``jax.checkpoint`` policies know them
+# (the flash forward's two are ``ops/flash_attention.py``'s): a remat'd block
+# keeps them from the forward pass, so its backward pass runs no matmul of the
+# MLP again (``TransformerLM.remat``).  A policy saves a named value only where
+# the backward reads it; outside a checkpoint a name lowers to nothing.
+FFN_GATE_NAME = "ffn.gate"  # gate_proj's output, SwiGLU: [B, L, mlp_dim], compute dtype
+FFN_UP_NAME = "ffn.up"      # up's output, before the activation: [B, L, mlp width]
+FFN_OUT_NAME = "ffn.out"    # down's output, after the tp psum: [B, L, model_dim]
 
 
 def _maybe_psum(x: jnp.ndarray, axis_name: Optional[str]) -> jnp.ndarray:
@@ -441,17 +452,20 @@ class TransformerBlock(nn.Module):
         if self.mlp == "swiglu":
             if not self.mlp_dim:
                 raise ValueError("mlp 'swiglu' needs its width, mlp_dim")
-            gate = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.compute_dtype,
-                            name="gate_proj")(y)
-            up = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.compute_dtype, name="up")(y)
+            gate = checkpoint_name(nn.Dense(self.mlp_dim, use_bias=False,
+                                            dtype=self.compute_dtype, name="gate_proj")(y),
+                                   FFN_GATE_NAME)
+            up = checkpoint_name(nn.Dense(self.mlp_dim, use_bias=False,
+                                          dtype=self.compute_dtype, name="up")(y), FFN_UP_NAME)
             y = nn.silu(gate) * up
         elif self.mlp == "gelu":
-            y = nn.Dense(ffn_local, use_bias=False, dtype=self.compute_dtype, name="up")(y)
+            y = checkpoint_name(nn.Dense(ffn_local, use_bias=False, dtype=self.compute_dtype,
+                                         name="up")(y), FFN_UP_NAME)
             y = nn.gelu(y)
         else:
             raise ValueError(f"mlp must be 'gelu' or 'swiglu', got {self.mlp!r}")
         y = nn.Dense(self.model_dim, use_bias=False, dtype=self.compute_dtype, name="down")(y)
-        y = _maybe_psum(y, self.tp_axis)
+        y = checkpoint_name(_maybe_psum(y, self.tp_axis), FFN_OUT_NAME)
         return self._norm("ffn_post_norm")(y) if self.post_norm else y
 
 
@@ -504,12 +518,16 @@ class TransformerLM(nn.Module):
     remat: bool = False  # rematerialize each block in the backward pass,
                          # except what the flash forward handed back (its
                          # output and log-sum-exp, saved by name: O(L) to
-                         # keep, O(L^2) to make again).  Activation memory
-                         # O(layers) blocks -> one block + the block inputs
+                         # keep, O(L^2) to make again) and the dense MLP's
+                         # matmul outputs (saved by name: no MLP matmul runs
+                         # twice).  Activation memory O(layers)
+                         # blocks -> one block + the block inputs
                          # + B*L*heads*(2*head_dim+32) bytes a flash layer
                          # (+512 on the chip, which tiles the log-sum-exp's
-                         # 8 lanes to 128): the FLOPs-for-HBM trade for
-                         # long sequences
+                         # 8 lanes to 128) + B*L*(2*mlp_dim+model_dim)*itemsize
+                         # a SwiGLU layer (one mlp_dim under GELU; model_dim
+                         # only where a post-norm reads down's output): the
+                         # FLOPs-for-HBM trade for long sequences
     moe_experts: int = 0       # > 0: every block's FFN becomes a Switch MoE
     moe_capacity: int = 0      # (0 = default to 2x the balanced share per
                                # expert; imbalanced routing beyond that
@@ -701,10 +719,12 @@ class TransformerLM(nn.Module):
             from distkeras_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
 
             # everything of a block is made again in the backward pass but
-            # the flash forward's two results: the kernel runs once a layer
+            # the flash forward's two results and the dense MLP's matmul
+            # outputs: no kernel and no MLP matmul runs twice a layer
             run = nn.remat(run, prevent_cse=True,
                            policy=jax.checkpoint_policies.save_only_these_names(
-                               FLASH_OUT_NAME, FLASH_LSE_NAME))
+                               FLASH_OUT_NAME, FLASH_LSE_NAME,
+                               FFN_GATE_NAME, FFN_UP_NAME, FFN_OUT_NAME))
         for blk in self.block:
             x = run(blk, x, pos_offset)
         return x

@@ -1,7 +1,9 @@
-"""``TransformerLM(remat=True)`` keeps what the flash forward handed back:
-its output and log-sum-exp are saved by name, so the gradient program holds
-ONE forward and one backward kernel a flash layer, remat on or off, and
-the same loss and gradients.  Kernels in interpret mode, tiny shapes."""
+"""``TransformerLM(remat=True)`` keeps what the flash forward handed back
+and what the dense MLP's matmuls made: the flash output and log-sum-exp
+and the MLP's gate, up and down outputs are saved by name, so the gradient
+program holds ONE forward and one backward kernel a flash layer, remat on
+or off, no MLP matmul in the recomputation, and the same loss and
+gradients.  Kernels in interpret mode, tiny shapes."""
 
 import collections
 
@@ -10,7 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import distkeras_tpu.models.transformer as transformer
 from distkeras_tpu.models.base import ModelSpec
+from distkeras_tpu.models.transformer import FFN_GATE_NAME, FFN_OUT_NAME, FFN_UP_NAME
 from distkeras_tpu.ops.flash_attention import (FLASH_LSE_NAME, FLASH_OUT_NAME, _STAT_LANES,
                                                flash_attention_with_lse)
 
@@ -52,6 +56,13 @@ def olmo_like(**over) -> ModelSpec:
 # builder, flash layers, [B, heads, L, head size] of a flash layer's output
 MODELS = {"gpt": (gpt, 2, (2, 2, 32, 16)), "trinity_like": (trinity_like, 2, (2, 4, 32, 8)),
           "olmo_like": (olmo_like, 1, (2, 2, 64, 16))}
+# the dense MLP's named values, name -> [B, L, width]: GELU names up (4 x
+# model_dim wide) and down, SwiGLU gate, up (mlp_dim) and down
+FFN_NAMED = {"gpt": {FFN_UP_NAME: (2, 32, 128), FFN_OUT_NAME: (2, 32, 32)},
+             "trinity_like": {FFN_GATE_NAME: (2, 32, 48), FFN_UP_NAME: (2, 32, 48),
+                              FFN_OUT_NAME: (2, 32, 32)},
+             "olmo_like": {FFN_GATE_NAME: (2, 64, 48), FFN_UP_NAME: (2, 64, 48),
+                           FFN_OUT_NAME: (2, 64, 32)}}
 
 
 def _equations(jaxpr, out):
@@ -83,6 +94,26 @@ def named_values(fn, *args) -> dict:
     return dict(found)
 
 
+def mlp_matmuls(fn, *args) -> collections.Counter:
+    """The dense MLP's ``dot_general``s in the jaxpr of ``fn(*args)``, by
+    where they run: ``"forward"`` at the top, ``"remat"`` inside a
+    checkpoint (the recomputation and the backward pass of a remat'd block)."""
+    def walk(jaxpr, where, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and "ffn.dense" in str(
+                    eqn.source_info.name_stack):
+                out[where] += 1
+            inner = "remat" if eqn.primitive.name in ("checkpoint", "remat2") else where
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, inner, out)
+        return out
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, "forward", collections.Counter())
+
+
 def _param_shapes(spec):
     return jax.eval_shape(lambda: spec.init_params(0))
 
@@ -110,16 +141,29 @@ def test_one_forward_and_one_backward_kernel_a_flash_layer(model, remat):
     assert calls == {"_fwd_kernel": flash_layers, "_bwd_fused_kernel": flash_layers}
 
 
-@pytest.mark.parametrize("model", sorted(MODELS))
-def test_remat_changes_neither_loss_nor_gradients(model):
+@pytest.mark.parametrize("model,dtype", [
+    *(pytest.param(m, "float32", id=m) for m in sorted(MODELS)),
+    *(pytest.param(m, "bfloat16", id=f"{m}-bfloat16") for m in sorted(MODELS))])
+def test_remat_changes_neither_loss_nor_gradients(model, dtype):
+    """The forward pass is the same program either way, so the loss is
+    the same number.  In bfloat16 the backward's sums are grouped as XLA
+    fuses each program, so a gradient may move by a few of the dtype's
+    steps (2**-7) at its leaf's scale: 8 of them are allowed."""
     build = MODELS[model][0]
-    params = jax.jit(lambda: build().init_params(0))()
-    plain, plain_g = jax.jit(jax.value_and_grad(_loss(build(remat=False))))(params)
-    remat, remat_g = jax.jit(jax.value_and_grad(_loss(build(remat=True))))(params)
+    params = jax.jit(lambda: build(compute_dtype=dtype).init_params(0))()
+    plain, plain_g = jax.jit(jax.value_and_grad(_loss(build(remat=False, compute_dtype=dtype))))(
+        params)
+    remat, remat_g = jax.jit(jax.value_and_grad(_loss(build(remat=True, compute_dtype=dtype))))(
+        params)
     np.testing.assert_allclose(float(plain), float(remat), rtol=1e-6)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(plain_g), jax.tree.leaves(remat_g)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6,
-                                   err_msg=jax.tree_util.keystr(path))
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                       err_msg=jax.tree_util.keystr(path))
+        else:
+            np.testing.assert_allclose(a, b, rtol=0, atol=8 * 2.0**-7 * float(np.abs(a).max()),
+                                       err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -132,11 +176,45 @@ def test_what_a_flash_layer_keeps_is_its_output_and_eight_lanes_of_lse(model, dt
     build, _, (b, h, l, d) = MODELS[model]
     spec = build(remat=True, compute_dtype=dtype)
     named = named_values(jax.grad(_loss(spec)), _param_shapes(spec))
-    assert named == {FLASH_OUT_NAME: {((b, h, l, d), jnp.dtype(dtype))},
+    flash = {name: values for name, values in named.items() if name.startswith("flash_")}
+    assert flash == {FLASH_OUT_NAME: {((b, h, l, d), jnp.dtype(dtype))},
                      FLASH_LSE_NAME: {((b, h, l, _STAT_LANES), jnp.dtype("float32"))}}
     kept = sum(int(np.prod(shape)) * dt.itemsize
-               for values in named.values() for shape, dt in values)
+               for values in flash.values() for shape, dt in values)
     assert kept == b * l * h * (d * jnp.dtype(dtype).itemsize + 32)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_what_a_dense_mlp_names_is_its_matmul_outputs_in_the_compute_dtype(model, dtype):
+    """``[B, L, width]`` a name: SwiGLU's gate and up at ``mlp_dim`` and
+    down's at ``model_dim``; GELU's up before the activation and down's.
+    Nothing else of the block is named (PERF.md section 4 sizes the remat
+    cells' programs by ``B*L*(2*mlp_dim + model_dim)*itemsize`` a layer)."""
+    spec = MODELS[model][0](remat=True, compute_dtype=dtype)
+    named = named_values(jax.grad(_loss(spec)), _param_shapes(spec))
+    assert set(named) - {FLASH_OUT_NAME, FLASH_LSE_NAME} == set(FFN_NAMED[model])
+    for name, shape in FFN_NAMED[model].items():
+        assert named[name] == {(shape, jnp.dtype(dtype))}, name
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_the_recomputation_runs_no_matmul_of_the_dense_mlp(model, monkeypatch):
+    """With the MLP's outputs saved, a remat'd block's checkpoint holds only
+    the two transposes of each MLP matmul (the input's and the weight's
+    gradient); with the flash names alone (the names stripped) it also
+    holds the matmuls made again: all of them under a post-norm, which
+    reads down's output, and only GELU's up without one.  The forward runs
+    each matmul once."""
+    spec = MODELS[model][0](remat=True)
+    # matmuls of the dense MLPs (GELU 2, SwiGLU 3 a layer; gpt 2 layers,
+    # trinity_like 1, olmo_like 4), and of them the flash names alone make again
+    mlps, again = {"gpt": (2 * 2, 1 * 2), "trinity_like": (3, 3), "olmo_like": (3 * 4, 3 * 4)}[model]
+    saved = mlp_matmuls(jax.grad(_loss(spec)), _param_shapes(spec))
+    assert saved == {"forward": mlps, "remat": 2 * mlps}
+    monkeypatch.setattr(transformer, "checkpoint_name", lambda x, name: x)
+    flash_only = mlp_matmuls(jax.grad(_loss(spec)), _param_shapes(spec))
+    assert flash_only == {"forward": mlps, "remat": 2 * mlps + again}
 
 
 def _ring_entry(q, k, v, wo, wl):
@@ -183,4 +261,5 @@ def test_a_name_outside_a_checkpoint_lowers_to_nothing(monkeypatch):
 
     named = lowered()
     monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    monkeypatch.setattr(transformer, "checkpoint_name", lambda x, name: x)
     assert lowered() == named
